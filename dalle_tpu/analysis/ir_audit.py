@@ -99,12 +99,12 @@ def _aval_bytes(aval) -> int:
 
 def _sub_jaxprs(params: dict):
     """Nested (Closed)Jaxprs hiding in an eqn's params, recursively."""
-    import jax.core as core
+    import jax.extend.core as jex_core
 
     def walk(val):
-        if isinstance(val, core.ClosedJaxpr):
+        if isinstance(val, jex_core.ClosedJaxpr):
             yield val.jaxpr
-        elif isinstance(val, core.Jaxpr):
+        elif isinstance(val, jex_core.Jaxpr):
             yield val
         elif isinstance(val, (list, tuple)):
             for v in val:
@@ -147,24 +147,25 @@ def _site_of(eqn) -> Tuple[str, int]:
     """("relpath::function", line) of the user frame that emitted ``eqn`` —
     the contract keys on file::function only, so unrelated edits that shift
     line numbers don't read as drift."""
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return "<unknown>", 0
-        path = frame.file_name
+    from jax._src import source_info_util
+
+    def rel(path):
         try:
-            rel = os.path.relpath(path, REPO_ROOT)
-            if not rel.startswith(".."):
-                path = rel.replace(os.sep, "/")
-            else:
-                path = os.path.basename(path)
+            r = os.path.relpath(path, REPO_ROOT)
         except ValueError:
-            path = os.path.basename(path)
-        line = getattr(frame, "start_line", 0) or 0
-        return f"{path}::{frame.function_name}", int(line)
-    except Exception:  # noqa: BLE001 - provenance is best-effort (private API)
+            return None
+        return None if r.startswith("..") else r.replace(os.sep, "/")
+
+    # innermost frame inside this repo: library frames between it and the
+    # primitive (flax's LayerNorm, optax's losses) name no site we can edit
+    frames = list(source_info_util.user_frames(eqn.source_info.traceback))
+    if not frames:
         return "<unknown>", 0
+    frame = next((f for f in frames if rel(f.file_name)), frames[0])
+    path = rel(frame.file_name) or os.path.basename(frame.file_name)
+    # function_name is the qualified name; contracts key on the bare one
+    func = frame.function_name.rsplit(".", 1)[-1]
+    return f"{path}::{func}", int(frame.start_line or 0)
 
 
 def promotion_events(closed) -> List[dict]:
@@ -212,17 +213,18 @@ def peak_memory_estimate(closed) -> dict:
     jaxprs. An ESTIMATE — XLA fuses and rematerializes — but deterministic
     for a given program, which is what a drift check needs."""
     import jax.core as core
+    import jax.extend.core as jex_core
 
     def scan(jaxpr) -> Tuple[int, int]:
         """(peak_bytes, resident_in_out_bytes) for one jaxpr."""
         last_use: Dict = {}
         for i, eqn in enumerate(jaxpr.eqns):
             for v in eqn.invars:
-                if isinstance(v, core.Var):
+                if isinstance(v, jex_core.Var):
                     last_use[v] = i
         n = len(jaxpr.eqns)
         for v in jaxpr.outvars:
-            if isinstance(v, core.Var):
+            if isinstance(v, jex_core.Var):
                 last_use[v] = n
         live: Dict = {}
         for v in list(jaxpr.invars) + list(jaxpr.constvars):
@@ -350,6 +352,12 @@ def collective_inventory(hlo_text: str, mesh=None) -> List[dict]:
     ``collective-permute`` instead carries ``source_target_pairs``, from
     which :func:`axes_for_pairs` recovers the crossed mesh axes."""
     agg: Dict[Tuple[str, int, str], dict] = {}
+    # operands print by name only (``all-reduce(%dot)``), so their bytes
+    # come from the defining instruction's result shape; names are unique
+    # per computation, and an operand is always defined above its use
+    def_re = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+)\s*=\s*"
+                        r"(\([^)]*\)|\S+)\s")
+    defined: Dict[str, int] = {}
     op_re = re.compile(
         r"=\s*(?:\([^)]*\)|\S+)\s+(" + "|".join(_COLLECTIVE_OPS) +
         r")(-start)?\((.*?)\)(?:,|\s)")
@@ -357,11 +365,17 @@ def collective_inventory(hlo_text: str, mesh=None) -> List[dict]:
                        r"[\d,]+\](?:T\([\d,]+\))?)")
     stp_re = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}")
     for line in hlo_text.splitlines():
+        if line.rstrip().endswith("{"):     # a new computation's scope
+            defined = {}
+        d = def_re.match(line)
+        if d:
+            defined[d.group(1)] = _parse_hlo_shapes(d.group(2))
         m = op_re.search(line)
         if not m or f"{m.group(1)}-done" in line:
             continue
         kind = m.group(1)
-        nbytes = _parse_hlo_shapes(m.group(3))
+        nbytes = sum(defined.get(name, 0)
+                     for name in re.findall(r"%[\w.\-]+", m.group(3)))
         axes = "unknown"
         rg = rg_re.search(line)
         stp = stp_re.search(line)

@@ -296,8 +296,8 @@ def _join(infos: List[VInfo], varies=None) -> VInfo:
 # --------------------------------------------------------------------------
 
 def _info_of(env, v) -> VInfo:
-    import jax.core as core
-    if isinstance(v, core.Literal) or not hasattr(v, "count"):
+    import jax.extend.core as jex_core
+    if isinstance(v, jex_core.Literal) or not hasattr(v, "count"):
         quant = ""
         dt = getattr(getattr(v, "aval", None), "dtype", None)
         if dt is not None and _is_int8(dt):

@@ -180,7 +180,14 @@ def save_engine_aot(engine, out_dir: str) -> dict:
         fns["cow_copy"] = engine._cow_copy_fn
     bundle = {}
     for name in programs:
-        compiled = fns[name].lower(*args[name]).compile()
+        # The export gets executables of its own. Compiled with no options,
+        # jit's in-memory cache hands back the very executable this process
+        # may already have run, and one that has run does not always
+        # serialize (XLA:CPU swaps in sort comparators that cannot:
+        # "UNIMPLEMENTED: `LessThan` is not serializable"). Naming an option
+        # — here at its default value — keys a separate compile.
+        compiled = fns[name].lower(*args[name]).compile(
+            compiler_options={"xla_embed_ir_in_executable": False})
         payload, in_tree, out_tree = serialize(compiled)
         bundle[name] = (payload, in_tree, out_tree)
     manifest = {"fingerprint": engine_fingerprint(engine),
@@ -218,6 +225,7 @@ def load_engine_aot(engine, aot_dir: str, *, strict: bool = False) -> bool:
     ``strict``. Loading performs NO trace and NO backend compile — the
     gateway smoke pins that with a compile-counter delta of zero across a
     served request."""
+    import jax
     from jax.experimental.serialize_executable import deserialize_and_load
     from ..obs import counter_add
     reason = fingerprint_mismatch(engine, aot_dir)
@@ -249,7 +257,14 @@ def load_engine_aot(engine, aot_dir: str, *, strict: bool = False) -> bool:
                       "falling back to jit", stacklevel=2)
         counter_add("gateway.aot_miss_total", 1.0)
         return False
-    loaded = {name: deserialize_and_load(*bundle[name]) for name in programs}
+    # onto the engine's own device(s): left to its default the loader
+    # spreads a one-device program over every local device, and the first
+    # dispatch fails on the shard count
+    leaf = jax.tree.leaves(engine.params)[0]
+    devices = sorted(leaf.sharding.device_set, key=lambda d: d.id)
+    loaded = {name: deserialize_and_load(*bundle[name],
+                                         execution_devices=devices)
+              for name in programs}
     chunks = {w: loaded[f"refill_chunk_w{w}"]
               for w in engine.chunk_widths()} or None
     engine.install_executables(step=loaded["step"], refill=loaded["refill"],

@@ -290,10 +290,9 @@ class DALLE(nn.Module):
         TPU's approximate top-k unit (ops/sampling.top_k_filter) — the sort
         is ~17% of decode wall time at batch 64. ``use_kernel`` pins the
         Pallas decode-kernel selection (None = shape-gated auto on TPU,
-        always dense elsewhere); pin False here AND on a serve engine for
-        strict bitwise parity between the two — the single-token and
-        windowed kernels are distinct implementations, so auto mode may
-        pick different attends per path on TPU.
+        always dense elsewhere). Bitwise parity with a serve engine is a
+        CPU-mesh property; on the TPU it does not hold for either setting
+        (docs/SERVING.md "The exactness contract on the chip").
         (reference generate_images :490-557 minus vae decode/CLIP, which live in
         DalleWithVae)"""
         c = self.cfg

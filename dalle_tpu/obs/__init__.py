@@ -42,7 +42,7 @@ from .trace import (DEFAULT_BUCKETS, MAX_HISTOGRAM_BUCKETS, Tracer,
 from .watchdog import StallReport, StallWatchdog
 
 _DEVICE_NAMES = ("CompileCounter", "DeviceTelemetry", "device_memory_stats",
-                 "device_memory_headroom", "install_compile_counter")
+                 "install_compile_counter")
 
 # graftpulse in-jit taps (obs/health.py) import jax; resolved lazily like
 # obs.device so the host-side anomaly/report layers stay jax-free

@@ -26,10 +26,8 @@ from typing import Optional
 
 import jax
 
-try:
-    from jax._src.dispatch import BACKEND_COMPILE_EVENT
-except ImportError:  # event key is stable across recent jax; private import is not
-    BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# private import; CompileCounter's self-test fails loudly if the event moves
+from jax._src.dispatch import BACKEND_COMPILE_EVENT
 
 
 class CompileCounter:
@@ -91,22 +89,14 @@ def device_memory_stats(device: Optional[jax.Device] = None) -> dict:
         limit = stats.get("bytes_limit")
         if limit:
             out["hbm_bytes_limit"] = int(limit)
+        # what loaded programs reserve for their temporaries sits outside
+        # bytes_in_use on the TPU allocator
+        reserved = stats.get("peak_bytes_reserved")
+        if reserved is not None:
+            out["hbm_peak_reserved_bytes"] = int(reserved)
         return out
     live = sum(int(x.nbytes) for x in jax.live_arrays())
     return {"hbm_bytes_in_use": live}
-
-
-def device_memory_headroom(device: Optional[jax.Device] = None
-                           ) -> Optional[int]:
-    """Free HBM bytes on one device (``bytes_limit - bytes_in_use``), or
-    ``None`` when the backend reports no allocator limit (CPU — effectively
-    unbounded host RAM). The gate behind ``rollback_snapshot="auto"``: an
-    on-device snapshot is only taken when it fits this headroom."""
-    stats = device_memory_stats(device)
-    limit = stats.get("hbm_bytes_limit")
-    if limit is None:
-        return None
-    return max(int(limit) - int(stats.get("hbm_bytes_in_use", 0)), 0)
 
 
 class DeviceTelemetry:

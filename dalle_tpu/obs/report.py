@@ -695,9 +695,6 @@ def format_report(rows: List[dict], *, topk: int = 10) -> str:
         if rec and rec[-1] > 0:
             lines.append(f"== WARNING: still compiling — "
                          f"{rec[-1]:.1f} recompiles/100 steps at last poll")
-        if any(r.get("mfu_estimated") for r in metrics):
-            lines.append("== NOTE: mfu is ESTIMATED (unknown accelerator "
-                         "peak-flops — see train/metrics.py PEAK_TFLOPS)")
         gw = gateway_accounting(metrics, spans)
         if gw is not None:
             lines.append(

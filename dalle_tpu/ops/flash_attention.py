@@ -507,19 +507,25 @@ def resolve_use_pallas(setting, seq_len: int, backend: Optional[str] = None,
         if fused_fits(seq_len, dim_head, heads):
             return "fused"
         return False
-    if s == "fused":
+    if s in ("fused", "persist"):
         if backend is None:
             backend = jax.default_backend()
-        # explicit request also admits the fwd-kernel/XLA-bwd tier
+        if backend != "tpu":
+            return False
+        # explicit "fused" also admits the fwd-kernel/XLA-bwd tier
         # (Attention picks the concrete variant from the runtime shape)
-        return ("fused" if backend == "tpu"
-                and fused_fwd_fits(seq_len, dim_head, heads)
-                else False)
-    if s == "persist":
-        if backend is None:
-            backend = jax.default_backend()
-        return ("persist" if backend == "tpu"
-                and persistent_fits(seq_len, dim_head) else False)
+        gate, fits = (("fused_fwd_fits",
+                       fused_fwd_fits(seq_len, dim_head, heads))
+                      if s == "fused" else
+                      ("persistent_fits", persistent_fits(seq_len, dim_head)))
+        if not fits:
+            # an explicit tier is a request, not a hint: running dense in
+            # its place would report the kernel's name over XLA's numbers
+            raise ValueError(
+                f"use_pallas={s!r} cannot be honoured on the TPU: {gate} "
+                f"rejects seq_len={seq_len}, heads={heads}, "
+                f"dim_head={dim_head} — use \"auto\" to let the code choose")
+        return s
     if s in ("1", "true", "on", "yes"):
         return "flash"
     if s in ("0", "false", "off", "no", "none"):

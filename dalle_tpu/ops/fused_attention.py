@@ -81,16 +81,13 @@ def _compiler_params(bytes_estimate: int):
     a shape whose true demand busts it with no dense fallback. Small
     shapes keep the default pipeline headroom."""
     from jax.experimental.pallas import tpu as pltpu
-    # renamed TPUCompilerParams → CompilerParams across jax releases
-    params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
     if bytes_estimate <= _VMEM_BUDGET:
         return None
     need = bytes_estimate + bytes_estimate // 4
     for _, limit in _VMEM_RAISED_LIMITS:
         if need <= limit:
-            return params_cls(vmem_limit_bytes=limit)
-    return params_cls(vmem_limit_bytes=_VMEM_RAISED_LIMITS[-1][1])
+            return pltpu.CompilerParams(vmem_limit_bytes=limit)
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_RAISED_LIMITS[-1][1])
 
 
 def use_spec(mask_spec) -> bool:

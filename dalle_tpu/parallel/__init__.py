@@ -1,21 +1,3 @@
-import os as _os
-
-import jax as _jax
-
-# Shard-invariant rng: jax<0.5 defaults to the non-partitionable threefry,
-# under which a random draw INSIDE a jitted program can produce different
-# bits depending on the output sharding GSPMD picks — CFG text dropout then
-# nulls different rows on a dp/fsdp/tp mesh than on one device, breaking the
-# "sharding changes the schedule, never the math" equivalence this package
-# guarantees (and that dryrun_multichip asserts to rtol 2e-4). The
-# partitionable generator computes each element from its index, so values
-# are identical under any sharding. A JAX_THREEFRY_PARTITIONABLE env
-# setting wins; to opt out programmatically, flip the flag AFTER importing
-# this package (a pre-import jax.config.update is indistinguishable from
-# the jax default and gets overridden here).
-if "JAX_THREEFRY_PARTITIONABLE" not in _os.environ:
-    _jax.config.update("jax_threefry_partitionable", True)
-
 from .mesh import (build_mesh, single_device_mesh, shard_batch,
                    shard_stacked_batch, batch_spec, replicated,
                    local_batch_size, use_mesh)

@@ -161,25 +161,9 @@ class JaxBackend(DistributedBackend):
             if pid is None:
                 env_pid = os.environ.get("JAX_PROCESS_ID")
                 pid = int(env_pid) if env_pid is not None else None
-            # CPU fleets (the DCN tests / local multi-process dev) need an
-            # explicit collectives implementation — jax's CPU backend has no
-            # default one and multi-process programs fail at the first
-            # collective with "Multiprocess computations aren't implemented".
-            # Read the *configured* platform, not default_backend(): the
-            # latter would instantiate the client before distributed init.
-            # An explicit user/env choice (e.g. mpi) wins — only the "none"
-            # default is upgraded.
-            platforms = (jax.config.jax_platforms or "").lower()
-            try:
-                # config.read, not an attribute: jax 0.4.x doesn't expose
-                # this option as a jax.config attr even after an update
-                current = jax.config.read("jax_cpu_collectives_implementation")
-            except Exception:  # noqa: BLE001 - option absent on this jax
-                current = None
-            if ("cpu" in platforms.split(",")
-                    and "JAX_CPU_COLLECTIVES_IMPLEMENTATION" not in os.environ
-                    and current in (None, "none")):
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
+            # CPU fleets (the DCN tests / local multi-process dev) run their
+            # cross-process collectives over gloo, jax's default
+            # jax_cpu_collectives_implementation — nothing to configure.
             # pid None → jax.distributed.initialize infers it from platform
             # metadata (the TPU-pod norm); forcing 0 would collide across
             # hosts. The connect is retried with jittered backoff

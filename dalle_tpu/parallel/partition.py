@@ -46,7 +46,14 @@ DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     # (feature-sharded tables force an involuntary full-remat reshard in the
     # SPMD partitioner: dim-over-fsdp gather output vs batch-over-(dp,fsdp)
     # block inputs).
-    (r".*(tok_emb|text_emb|image_emb|embedding)/embedding$", P(("tp", "fsdp"),)),
+    # The image-token table (8,192 x dim, <60 MB in f32) is replicated
+    # instead: sharded four ways at dim 1792 its 2,048-row bf16 shard falls
+    # in a size window where the TPU compiler (libtpu 0.0.34) keeps the
+    # gather's operand in VMEM and then fails the whole step's compile
+    # ("Ran out of memory in memory space vmem ... exceeded scoped vmem
+    # limit by 396.0K") — 1,024- and 4,096-row shards compile.
+    (r".*image_emb/embedding$",                              P()),
+    (r".*(tok_emb|text_emb|embedding)/embedding$",           P(("tp", "fsdp"),)),
     (r".*(to_logits|logits|head)/kernel$",                   P("fsdp", "tp")),
     # conv kernels (dVAE/VQGAN): shard output channels over fsdp only
     (r".*conv.*/kernel$",                                    P(None, None, None, "fsdp")),

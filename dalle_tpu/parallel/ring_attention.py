@@ -49,15 +49,6 @@ from ..ops.chunk_attention import (chunk_flash_dkv, chunk_flash_dq,
                                    chunk_flash_fwd, merge_chunk, pick_block)
 from ..ops.flash_attention import elem_fn_from_spec
 
-# jax moved shard_map out of experimental (and renamed check_rep→check_vma)
-# in 0.6; support both so the ring runs on every jax the repo targets
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SM_NO_CHECK = {"check_vma": False}
-else:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_NO_CHECK = {"check_rep": False}
-
 NEG_INF = -1e9
 
 
@@ -327,8 +318,8 @@ def _make_ring_fn(mesh: Mesh, axis: str, causal: bool, nper: int, scale: float,
                                      block, interpret, mask_spec, zigzag)
         # pallas_call out_shapes carry no varying-manual-axes metadata;
         # correctness is covered by the numerics tests against the dense body
-        return _shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, **_SM_NO_CHECK)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)
     if zigzag:
         body = functools.partial(_ring_body_zigzag, axis=axis, nper=nper,
                                  scale=scale, n_valid=n_valid,
@@ -337,8 +328,8 @@ def _make_ring_fn(mesh: Mesh, axis: str, causal: bool, nper: int, scale: float,
         body = functools.partial(_ring_body, axis=axis, nper=nper,
                                  causal=causal, scale=scale, n_valid=n_valid,
                                  elem_fn=elem_fn_from_spec(mask_spec))
-    return _shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)
 
 
 def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,

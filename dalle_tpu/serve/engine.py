@@ -219,14 +219,13 @@ class DecodeEngine:
 
     ``use_kernel`` pins Pallas attend-kernel selection for the engine's
     decode and refill programs (None = shape-gated auto on TPU, dense
-    elsewhere). Bitwise token parity with ``generate_images_tokens`` is
-    guaranteed when both paths resolve to the same attend implementation —
-    always true on the CPU mesh (CI enforces it there). On TPU the windowed
-    and single-token kernels are DISTINCT implementations (each within
-    ~2e-2 of dense, not bitwise), and auto-selection is shape-dependent per
-    path; for strict parity runs pin ``use_kernel=False`` here and on the
-    reference ``generate_images_tokens`` call. Auto mode trades that strict
-    guarantee for kernel throughput.
+    elsewhere). Bitwise token parity with ``generate_images_tokens`` holds
+    on the CPU mesh (CI enforces it there). On the TPU it does not — and
+    pinning ``use_kernel=False`` here and on the reference does not restore
+    it (chip run, PR 21, 1.4B int8w): the engine's B-row programs and the
+    reference's 1-row program are different XLA programs and round
+    differently. docs/SERVING.md "The exactness contract on the chip" says
+    what the chip is held to instead.
     """
 
     def __init__(self, model: DALLE, params, *, slots: int,

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -11,10 +12,17 @@ from typing import List, Optional
 
 _HERE = Path(__file__).resolve().parent
 _SRC = _HERE / "bpe_core.cpp"
-_LIB = _HERE / "libbpe_core.so"
 _lock = threading.Lock()
 _lib = None
 _build_failed = False
+
+
+def _lib_path() -> Path:
+    """The binary's name carries its source's digest: a binary copied along
+    with the tree (file times do not survive a copy) loads only if it was
+    built from exactly the committed source, otherwise it is rebuilt."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _HERE / f"libbpe_core.{digest}.so"
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -24,11 +32,18 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _build_failed:
             return _lib
         try:
-            if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
+            lib_path = _lib_path()
+            if not lib_path.exists():
+                for stale in _HERE.glob("libbpe_core*.so"):
+                    stale.unlink(missing_ok=True)
+                # build beside the target and rename: concurrent processes
+                # (xdist workers) never dlopen a half-written file
+                tmp = lib_path.with_suffix(f".{os.getpid()}.tmp")
                 subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", str(_SRC), "-o", str(_LIB)],
+                    ["g++", "-O2", "-shared", "-fPIC", str(_SRC), "-o", str(tmp)],
                     check=True, capture_output=True, timeout=120)
-            lib = ctypes.CDLL(str(_LIB))
+                os.replace(tmp, lib_path)
+            lib = ctypes.CDLL(str(lib_path))
             lib.bpe_new.restype = ctypes.c_void_p
             lib.bpe_new.argtypes = [ctypes.c_char_p]
             lib.bpe_free.argtypes = [ctypes.c_void_p]

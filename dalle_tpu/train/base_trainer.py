@@ -66,6 +66,10 @@ class BaseTrainer:
     preempted = False
     _preemptive_good = None
     _preemptive_good_device = None
+    # rollback_snapshot="auto" gate (see _snapshot_mode): has this trainer
+    # run a step, and the allocator peak those steps reached
+    _stepped = False
+    _step_peak_bytes = None
 
     def __init__(self, train_cfg: TrainConfig, mesh=None, backend=None):
         self.train_cfg = train_cfg
@@ -564,19 +568,37 @@ class BaseTrainer:
     def _snapshot_mode(self, live) -> str:
         """Resolve ``rollback_snapshot`` ("auto" → "device"/"host"): the
         on-device copy doubles the (params, opt_state) HBM footprint, so auto
-        only takes it when the allocator reports enough headroom (backends
-        without a limit — CPU — always fit: "device" there is host RAM)."""
+        only takes it when it fits beside a running step. What a step needs
+        (activations, gradients) is invisible to the allocator until one
+        has run, and on the TPU it never shows in ``bytes_in_use`` at all:
+        a program's temporaries are a reservation made when it is loaded.
+        At 1.4B on a 16 GB chip the state is 5.4 GB and the step reserves
+        8.4 GB more, so "free HBM now" admits a copy that the next step
+        then cannot load around (RESOURCE_EXHAUSTED "Attempting to reserve
+        8.41G at the bottom of memory", chip run, PR 21). Auto therefore
+        keeps the snapshot on the host until this trainer has stepped,
+        then gates on the high-water marks of those steps — buffers plus
+        program reservations; an allocator that reports a limit but no
+        reservation peak stays on the host. Backends without a limit — CPU
+        — always fit: "device" there is host RAM."""
         mode = getattr(self.train_cfg, "rollback_snapshot", "host")
         if mode != "auto":
             return mode
-        from ..obs import device_memory_headroom
+        from ..obs import device_memory_stats
         d0 = self.mesh.devices.flat[0]
-        try:
-            headroom = device_memory_headroom(d0)
-        except Exception:  # noqa: BLE001 - stats API varies per backend;
-            return "host"  # an unreadable gauge must not break training
-        if headroom is None:
+        stats = device_memory_stats(d0)
+        limit = stats.get("hbm_bytes_limit")
+        if limit is None:
             return "device"
+        if self._step_peak_bytes is None:
+            if not self._stepped or not {
+                    "hbm_peak_bytes", "hbm_peak_reserved_bytes"} <= set(stats):
+                return "host"
+            # no device snapshot has been resident yet, so these peaks are
+            # the steps' own; later ones would include the snapshot itself
+            self._step_peak_bytes = (stats["hbm_peak_bytes"]
+                                     + stats["hbm_peak_reserved_bytes"])
+        headroom = limit - self._step_peak_bytes
 
         # per-device snapshot bytes = what ONE device actually holds — the
         # sum of its shards. global/mesh_size would undercount replicated
@@ -686,6 +708,7 @@ class BaseTrainer:
         (batch-wait/dispatch/sync splits, data-starvation ratio) and — at
         ``obs.device_poll_every`` cadence — the HBM and recompile gauges."""
         self._host_step += 1
+        self._stepped = True
         self._pending_metrics = metrics   # fit() fetches these on demand at
                                           # save boundaries (NaN-check gate)
         every = max(getattr(self.train_cfg, "metrics_every", 1), 1)

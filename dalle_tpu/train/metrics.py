@@ -22,32 +22,19 @@ PEAK_TFLOPS = {
 }
 
 
-_warned_unknown_peak = False
-
-
-def device_peak_tflops_info(device: Optional[jax.Device] = None
-                            ) -> tuple[float, bool]:
-    """(peak bf16 TFLOP/s, estimated?) — ``estimated`` is True when the
-    device kind has no entry in PEAK_TFLOPS and the 100.0 placeholder is in
-    play, so MFU consumers can tag the number as fiction instead of fact."""
-    global _warned_unknown_peak
+def device_peak_tflops(device: Optional[jax.Device] = None) -> float:
+    """Peak bf16 TFLOP/s of ``device`` (default: the first one). A device
+    kind with no row in PEAK_TFLOPS is an error: an MFU over a guessed
+    denominator is not a measurement."""
     d = device or jax.devices()[0]
     kind = getattr(d, "device_kind", "cpu")
     for k, v in PEAK_TFLOPS.items():
         if kind.lower().startswith(k.lower()):
-            return v, False
-    if not _warned_unknown_peak:
-        import warnings
-        warnings.warn(
-            f"unknown accelerator {kind!r}: MFU uses a 100 TFLOP/s guess and "
-            "reports are tagged mfu_estimated — add the chip's peak to "
-            "train/metrics.PEAK_TFLOPS for a real number")
-        _warned_unknown_peak = True
-    return 100.0, True
-
-
-def device_peak_tflops(device: Optional[jax.Device] = None) -> float:
-    return device_peak_tflops_info(device)[0]
+            return v
+    raise ValueError(
+        f"unknown accelerator {kind!r}: no peak FLOP/s to compute MFU "
+        "against — add the chip's published peak to "
+        "train/metrics.PEAK_TFLOPS")
 
 
 class ThroughputMeter:
@@ -83,12 +70,8 @@ class ThroughputMeter:
             rep["tokens_per_sec_per_chip"] = sps * self.tokens_per_sample / self.num_chips
         if self.flops_per_step:
             achieved = self.flops_per_step * n_steps / dt
-            peak_tflops, estimated = device_peak_tflops_info()
-            rep["mfu"] = achieved / (peak_tflops * 1e12 * self.num_chips)
-            if estimated:
-                # unknown chip → the denominator is a guess; without the tag
-                # the report would present a made-up MFU as authoritative
-                rep["mfu_estimated"] = True
+            rep["mfu"] = achieved / (
+                device_peak_tflops() * 1e12 * self.num_chips)
         self._last_report = rep
         return rep
 

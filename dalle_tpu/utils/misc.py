@@ -3,6 +3,8 @@
 from __future__ import annotations
 
 import math
+import os
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -98,21 +100,38 @@ def instantiate_from_config(config: dict):
     return get_obj_from_str(config["target"])(**config.get("params", {}))
 
 
-def enable_compilation_cache(cache_dir: str) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` so every
-    compile in this process is written through to disk and every later
-    process (a rejoining trainer, a scaled-up serving replica) reads it
-    back instead of recompiling. The min-time/min-size thresholds are
-    dropped to zero: cold-start cares about the long tail of small
-    programs too, and the cache is content-addressed so over-writing is
-    idempotent. Provider-neutral jax plumbing — shared by every train and
-    serve CLI (scripts/_common.add_compile_cache_args) and re-exported by
-    dalle_tpu.gateway.aot for the serving cold-start story
-    (docs/SERVING.md)."""
-    import os
-    cache_dir = os.path.expanduser(cache_dir)
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+# the one in-checkout home of the persistent compilation cache (git-ignored)
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
+
+
+def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on jax's persistent compilation cache so every compile in this
+    process is written through to disk and every later process (a rejoining
+    trainer, a scaled-up serving replica, the next chip_smoke phase) reads
+    it back instead of recompiling. Returns the directory in use.
+
+    Where it lives — one rule for every entry point: if
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax already points there and this
+    function sets no other directory (``cache_dir`` is ignored); otherwise
+    ``cache_dir``, defaulting to the fixed ``COMPILE_CACHE_DIR`` inside the
+    checkout. The path is part of the cache key, so it never carries a
+    home directory, a temporary name, a pid or a time.
+
+    The min-time/min-size thresholds are dropped to zero: cold-start cares
+    about the long tail of small programs too, and the cache is
+    content-addressed so over-writing is idempotent. Shared by every train
+    and serve CLI (scripts/_common.add_compile_cache_args), bench.py and
+    chip_smoke.py, and re-exported by dalle_tpu.gateway.aot for the serving
+    cold-start story (docs/SERVING.md)."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        cache_dir = env_dir
+    else:
+        cache_dir = os.path.abspath(cache_dir or COMPILE_CACHE_DIR)
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir

@@ -150,11 +150,12 @@ def add_compile_cache_args(parser):
     trace is still paid — gateway AOT bundles skip that too, see
     docs/SERVING.md)."""
     grp = parser.add_argument_group("compilation cache (docs/SERVING.md)")
-    grp.add_argument("--compile_cache_dir", type=str,
-                     default="~/.cache/dalle_tpu/xla_cache",
+    grp.add_argument("--compile_cache_dir", type=str, default=None,
                      help="persistent XLA compilation cache directory "
                           "(content-addressed; safe to share across "
-                          "processes and runs)")
+                          "processes and runs). Default: .xla_cache/ in "
+                          "the checkout. JAX_COMPILATION_CACHE_DIR, when "
+                          "set, wins over this flag")
     grp.add_argument("--no_compile_cache", action="store_true",
                      help="disable the persistent compilation cache "
                           "(every process recompiles from scratch)")

@@ -77,7 +77,8 @@ def main():
     ap.add_argument("--n", type=int, default=2048)
     ap.add_argument("--image_size", type=int, default=128)
     ap.add_argument("--consumption_tok_s", type=float, default=13622.0,
-                    help="flagship chip consumption (BENCH_r04)")
+                    help="flagship chip consumption (driver bench r04, "
+                    "2026-07-31; see ROADMAP.md 'State of the records')")
     ap.add_argument("--seq_len", type=int, default=513)
     args = ap.parse_args()
 

@@ -76,7 +76,8 @@ def main():
                     "async path is the production config)")
     ap.add_argument("--compile_cache", default="",
                     help="persistent XLA compile cache dir (shared across "
-                    "the pod; makes a rejoin near-zero-compile)")
+                    "the pod; makes a rejoin near-zero-compile). "
+                    "JAX_COMPILATION_CACHE_DIR, when set, wins over it")
     args = ap.parse_args()
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")

@@ -1,0 +1,159 @@
+"""The main path's Pallas kernels compile for the chip — asked of the TPU
+compiler itself, against a *described* v5e (no chip attached, nothing runs).
+
+Interpret mode (every other kernel test here) cannot see what Mosaic refuses:
+a slice off the tiling, more scoped VMEM than a kernel may use. These cases
+lower each kernel with ``interpret=False`` at the widths chip_smoke.py runs
+and compile it for ``TPU v5 lite``; the compiled text must hold the Mosaic
+custom call. A compile that passes is not a chip run — results and times
+come from chip_smoke.py.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load the TPU library, and every xdist worker imports
+every test file), compiles happen in the test's own process, and the
+persistent compilation cache is off around them (an entry compiled for a
+described device cannot be read back without one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dalle_tpu.ops.attention import KVCache
+from dalle_tpu.ops.decode_attention import (decode_attend_kernel,
+                                            decode_attend_window_kernel,
+                                            decode_attend_window_paged)
+from dalle_tpu.ops.flash_attention import flash_attention
+from dalle_tpu.ops.fused_attention import fused_qkv_attention
+from dalle_tpu.ops.paged_kv import PagedKVCache
+
+# DALL·E-1.4B decode shapes as the serve engine builds them: 8 slots,
+# 14 heads x 128, cache max_seq == total_seq_len == 512
+B, H, D, S = 8, 14, 128, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot ask"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on the described chip; around the module's compiles the
+    persistent cache is off and matmul precision is the chip default (the
+    harness's float32 setting is a CPU-numerics choice)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_was = jax.config.jax_enable_compilation_cache
+    precision_was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    jax.config.update("jax_default_matmul_precision", None)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_default_matmul_precision", precision_was)
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+def _mosaic_text(fn, *shapes) -> str:
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic custom call in the program"
+    return text
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _cache(sharding, dtype):
+    scale = (_sds(sharding, (B, 2 * H, S), jnp.float32)
+             if dtype == jnp.int8 else None)
+    return KVCache(_sds(sharding, (B, S, 2 * H * D), dtype), scale, heads=H)
+
+
+def test_fused_fwd_bwd_small(one_chip):
+    # DALL·E-small train step's attention: b8 of the 64, n=513, 8 x 64
+    def loss(qkv):
+        return jnp.sum(fused_qkv_attention(qkv, heads=8, interpret=False)
+                       .astype(jnp.float32))
+
+    _mosaic_text(jax.grad(loss), _sds(one_chip, (8, 513, 3 * 512),
+                                      jnp.bfloat16))
+
+
+def test_fused_fwd_medium(one_chip):
+    # DALL·E-medium width, 16 x 64 (its backward alone compiles ~26 s)
+    _mosaic_text(lambda qkv: fused_qkv_attention(qkv, heads=16,
+                                                 interpret=False),
+                 _sds(one_chip, (4, 513, 3 * 1024), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_decode_kernel_1p4b(one_chip, dtype):
+    _mosaic_text(
+        lambda q, cache, n: decode_attend_kernel(q, cache, n,
+                                                 interpret=False),
+        _sds(one_chip, (B, H, 1, D), jnp.bfloat16), _cache(one_chip, dtype),
+        _sds(one_chip, (), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("w", [1, 8])
+def test_decode_window_kernel_1p4b(one_chip, dtype, w):
+    _mosaic_text(
+        lambda q, cache, starts: decode_attend_window_kernel(
+            q, cache, starts, interpret=False),
+        _sds(one_chip, (B, H, w, D), jnp.bfloat16), _cache(one_chip, dtype),
+        _sds(one_chip, (B,), jnp.int32))
+
+
+def test_decode_window_paged_1p4b(one_chip):
+    # the paged engine's decode attend: page-table gather + the same kernel
+    bt = 64
+    blocks = B * S // bt
+    cache = PagedKVCache(
+        _sds(one_chip, (blocks, bt, 2 * H * D), jnp.int8),
+        _sds(one_chip, (blocks, bt, 2 * H), jnp.float32),
+        _sds(one_chip, (B, S // bt), jnp.int32),
+        heads=H, block_tokens=bt, max_seq=S)
+    _mosaic_text(
+        lambda q, cache, starts: decode_attend_window_paged(
+            q, cache, starts, interpret=False),
+        _sds(one_chip, (B, H, 1, D), jnp.bfloat16), cache,
+        _sds(one_chip, (B,), jnp.int32))
+
+
+def _flash_loss(mask):
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, mask=mask, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def test_flash_fwd_bwd_long(one_chip):
+    # above the "auto" crossover (seq >= 2048): fmap-48 image grid
+    qkv = [_sds(one_chip, (1, 8, 2304, 64), jnp.bfloat16)] * 3
+    _mosaic_text(_flash_loss(None), *qkv)
+
+
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["mask_free", "axial_masked"])
+def test_flash_fwd_bwd_512(one_chip, masked):
+    # full-causal mask-free variant and a block-sparse masked variant
+    from dalle_tpu.ops.attn_masks import axial_mask
+    n, fmap = 256 + 16 * 16, 16
+    mask = (np.asarray(axial_mask(256, fmap, axis=0))[:n, :n]
+            if masked else None)
+    qkv = [_sds(one_chip, (2, 2, n, 64), jnp.bfloat16)] * 3
+    _mosaic_text(_flash_loss(mask), *qkv)

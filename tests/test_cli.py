@@ -167,3 +167,46 @@ def test_bench_check_empty_newest_round_is_new_not_missing(tmp_path, capsys):
     rc = bench_check.main(["--root", str(tmp_path), "--strict"])
     out = capsys.readouterr().out
     assert rc == 1 and "REGRESSED" in out
+
+
+# -- the one compile-cache rule (utils/misc.enable_compilation_cache) --------
+
+@pytest.fixture
+def _restore_cache_config():
+    import jax
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in was.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_env_dir_is_left_alone(tmp_path, monkeypatch,
+                                             _restore_cache_config):
+    import jax
+    from dalle_tpu.utils.misc import enable_compilation_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    got = enable_compilation_cache(str(tmp_path / "flag"))
+    assert got == str(tmp_path / "env")
+    # no directory is set in code and none is created for the flag
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "flag").exists()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+
+def test_compile_cache_defaults_into_the_checkout(tmp_path, monkeypatch,
+                                                  _restore_cache_config):
+    import jax
+    from dalle_tpu.utils import misc
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert misc.COMPILE_CACHE_DIR == os.path.join(repo, ".xla_cache")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(misc, "COMPILE_CACHE_DIR", str(tmp_path / "fixed"))
+    assert misc.enable_compilation_cache() == str(tmp_path / "fixed")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "fixed")
+    # an explicit directory wins only when the variable is unset
+    assert misc.enable_compilation_cache(str(tmp_path / "flag")) == \
+        str(tmp_path / "flag")

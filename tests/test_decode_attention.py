@@ -1,6 +1,6 @@
 """Pallas decode-attention kernel ≡ the dense cached_attend path (interpret
-mode on CPU; the on-chip Mosaic build is exercised by the TPU bench and
-DALLE_TPU_TESTS=1 runs)."""
+mode on CPU; the Mosaic build is compiled for a described v5e in
+tests/test_chip_compile.py and run on the chip by chip_smoke.py)."""
 
 import jax
 import jax.numpy as jnp

@@ -1,11 +1,13 @@
 """Fused-boundary attention (ops/fused_attention.py): forward and custom_vjp
 backward ≡ split + dense attend + autodiff, straight off the (b, n, 3·h·d)
-qkv layout (interpret mode on CPU; the on-chip build is exercised by the TPU
-bench)."""
+qkv layout (interpret mode on CPU; the Mosaic build is compiled for a
+described v5e in tests/test_chip_compile.py and run on the chip by
+chip_smoke.py)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dalle_tpu.ops.attention import attend
 from dalle_tpu.ops.fused_attention import fused_fits, fused_qkv_attention
@@ -142,7 +144,9 @@ def test_xbwd_matches_autodiff():
 def test_resolve_tiers():
     from dalle_tpu.ops.flash_attention import resolve_use_pallas
     assert resolve_use_pallas("fused", 513, backend="tpu") == "fused"
-    assert resolve_use_pallas("fused", 2048, backend="tpu") is False
+    # on the TPU an explicit tier that cannot be honoured names its gate
+    with pytest.raises(ValueError, match="fused_fwd_fits"):
+        resolve_use_pallas("fused", 2048, backend="tpu")
     assert resolve_use_pallas("fused", 513, backend="cpu") is False
     # auto selects fused where the merged kernel fits under the RAISED
     # Mosaic vmem ceiling and measured a win: small (0.458 vs 0.391 MFU)

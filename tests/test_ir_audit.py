@@ -51,7 +51,6 @@ def _mesh8():
 
 
 def _psum_fn(n_psums):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = _mesh8()
 
@@ -60,7 +59,7 @@ def _psum_fn(n_psums):
             x = jax.lax.psum(x, "dp")
         return x
 
-    return shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P())
+    return jax.shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P())
 
 
 # ---------------------------------------------------------------------------
@@ -219,11 +218,17 @@ def test_axes_for_pairs_names_crossed_axes():
 
 
 def test_collective_inventory_parses_and_aggregates():
+    # operands print by name: their bytes come from the defining lines
     hlo = """
-  %ar1 = f32[8,16]{1,0} all-reduce(f32[8,16]{1,0} %p0), replica_groups={{0,1,2,3}}, to_apply=%sum
-  %ar2 = f32[8,16]{1,0} all-reduce(f32[8,16]{1,0} %p1), replica_groups={{0,1,2,3}}, to_apply=%sum
-  %ag = (f32[4]{0}) all-gather-start(f32[2]{0} %p2), replica_groups=[2,2]<=[4]
-  %agd = f32[4]{0} all-gather-done((f32[4]{0}) %ag)
+ENTRY %main (p0: f32[8,16], p1: f32[8,16], p2: f32[2]) -> f32[4] {
+  %p0 = f32[8,16]{1,0} parameter(0)
+  %p1 = f32[8,16]{1,0} parameter(1)
+  %p2 = f32[2]{0} parameter(2)
+  %ar1 = f32[8,16]{1,0} all-reduce(%p0), replica_groups={{0,1,2,3}}, to_apply=%sum
+  %ar2 = f32[8,16]{1,0} all-reduce(%p1), replica_groups={{0,1,2,3}}, to_apply=%sum
+  %ag = (f32[2]{0}, f32[4]{0}) all-gather-start(%p2), replica_groups=[2,2]<=[4]
+  ROOT %agd = f32[4]{0} all-gather-done(%ag)
+}
 """
     inv = A.collective_inventory(hlo)
     by_kind = {e["kind"]: e for e in inv}
@@ -437,10 +442,11 @@ def test_registry_entries_have_goldens_and_valid_schema():
         assert golden["entry"] == name
         assert golden["primitives"], name
     # and no orphaned goldens for entries that no longer exist (sync.json
-    # is the graftsync lock-graph golden, not a graftir entry contract —
-    # tests/test_sync_flow.py owns its schema)
+    # and wire.json are the graftsync lock-graph and graftwire protocol
+    # goldens, not graftir entry contracts — tests/test_sync_flow.py and
+    # tests/test_wire_flow.py own their schemas)
     for fname in os.listdir(cdir):
-        if fname == "sync.json":
+        if fname in ("sync.json", "wire.json"):
             continue
         assert fname.removesuffix(".json") in C.ENTRIES, fname
 

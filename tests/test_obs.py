@@ -314,41 +314,38 @@ def test_metrics_logger_merges_obs_snapshot(tmp_path, tracer):
     assert rec["obs.decode_tokens_total"] == 9
 
 
-# -- satellite: estimated-MFU tagging ----------------------------------------
+# -- satellite: MFU only against a known peak ---------------------------------
 
-def test_device_peak_tflops_unknown_is_tagged():
+def test_device_peak_tflops_unknown_kind_raises():
     from dalle_tpu.train import metrics as tm
 
     class FakeDevice:
         device_kind = "QuantumChip 9000"
 
-    tm._warned_unknown_peak = False
-    with pytest.warns(UserWarning, match="mfu_estimated"):
-        peak, estimated = tm.device_peak_tflops_info(FakeDevice())
-    assert peak == 100.0 and estimated
-    # warn-once: the second lookup is silent
-    peak2, est2 = tm.device_peak_tflops_info(FakeDevice())
-    assert (peak2, est2) == (100.0, True)
+    with pytest.raises(ValueError, match="QuantumChip 9000"):
+        tm.device_peak_tflops(FakeDevice())
 
 
-def test_throughput_meter_tags_estimated_mfu(monkeypatch):
+@pytest.mark.parametrize("kind,peak", [("TPU v5 lite", 197.0),
+                                       ("TPU v5e", 197.0),
+                                       ("TPU v5p", 459.0),
+                                       ("TPU v4", 275.0)])
+def test_device_peak_tflops_known_kind_gives_its_row(kind, peak):
     from dalle_tpu.train import metrics as tm
-    monkeypatch.setattr(tm, "device_peak_tflops_info",
-                        lambda device=None: (100.0, True))
+
+    class FakeDevice:
+        device_kind = kind
+
+    assert tm.device_peak_tflops(FakeDevice()) == peak
+
+
+def test_throughput_meter_reports_mfu_for_known_kind(monkeypatch):
+    from dalle_tpu.train import metrics as tm
+    monkeypatch.setattr(tm, "device_peak_tflops", lambda device=None: 123.0)
     meter = tm.ThroughputMeter(8, interval=1, flops_per_step=1e9)
     time.sleep(0.01)
     rep = meter.step(2)
-    assert rep["mfu_estimated"] is True and rep["mfu"] > 0
-
-
-def test_throughput_meter_known_chip_untagged(monkeypatch):
-    from dalle_tpu.train import metrics as tm
-    monkeypatch.setattr(tm, "device_peak_tflops_info",
-                        lambda device=None: (123.0, False))
-    meter = tm.ThroughputMeter(8, interval=1, flops_per_step=1e9)
-    time.sleep(0.01)
-    rep = meter.step(2)
-    assert "mfu_estimated" not in rep
+    assert 0 < rep["mfu"] < 1 and "mfu_estimated" not in rep
 
 
 # -- graftscope: trace context (obs/context.py) ------------------------------

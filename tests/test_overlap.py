@@ -180,6 +180,38 @@ def test_snapshot_modes_survive_donation_and_restore_bit_exact(tmp_path, rng):
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("stepped,stats,want", [
+    # no allocator limit (CPU): the copy is host RAM either way
+    (False, {"hbm_bytes_in_use": 1}, "device"),
+    # before the first step the step's footprint is unknown: host
+    (False, {"hbm_bytes_in_use": 1, "hbm_bytes_limit": 1 << 40,
+             "hbm_peak_bytes": 1, "hbm_peak_reserved_bytes": 1}, "host"),
+    # a limit but no reservation peak: the step cannot be sized, host
+    (True, {"hbm_bytes_in_use": 1, "hbm_bytes_limit": 1 << 40,
+            "hbm_peak_bytes": 1}, "host"),
+    # stepped, and buffers + program reservations leave room: device
+    (True, {"hbm_bytes_in_use": 1, "hbm_bytes_limit": 1 << 40,
+            "hbm_peak_bytes": 1 << 20, "hbm_peak_reserved_bytes": 1 << 20},
+     "device"),
+    # stepped, but the program's reservation fills the chip: host
+    (True, {"hbm_bytes_in_use": 1, "hbm_bytes_limit": 1 << 30,
+            "hbm_peak_bytes": 1 << 20,
+            "hbm_peak_reserved_bytes": (1 << 30) - (1 << 20)}, "host"),
+], ids=["no_limit", "before_first_step", "no_reservation_peak",
+        "fits_beside_step", "step_fills_chip"])
+def test_auto_snapshot_gate_sizes_the_step(tmp_path, monkeypatch, stepped,
+                                           stats, want):
+    """rollback_snapshot="auto" takes the device copy only when it fits
+    beside a step that has already run — buffers plus what the step's
+    program reserves (invisible to bytes_in_use on the TPU)."""
+    from dalle_tpu import obs
+    tr = DalleTrainer(TINY, _tc(tmp_path, rollback_snapshot="auto"))
+    tr._stepped = stepped
+    monkeypatch.setattr(obs, "device_memory_stats", lambda d=None: stats)
+    live = (tr.state.params, tr.state.opt_state)
+    assert tr._snapshot_mode(live) == want
+
+
 def test_fit_nan_rollback_from_device_snapshot(tmp_path, rng):
     """End-to-end: a NaN loss mid-fit rolls the live state back to the last
     device snapshot bit-exact (inject by corrupting params so the real loss

@@ -1,6 +1,6 @@
 """VMEM-persistent whole-sequence attention (ops/persistent_attention.py):
 forward and custom_vjp backward ≡ dense attend + autodiff (interpret mode on
-CPU; the on-chip build is exercised by the TPU bench)."""
+CPU)."""
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +66,9 @@ def test_auto_policy_tiers():
     assert resolve_use_pallas("auto", 513, backend="tpu") == "fused"
     assert resolve_use_pallas("auto", 128, backend="tpu") == "fused"
     assert resolve_use_pallas("persist", 513, backend="tpu") == "persist"
-    assert resolve_use_pallas("persist", 1280, backend="tpu") is False
+    # on the TPU an explicit tier that cannot be honoured names its gate
+    with pytest.raises(ValueError, match="persistent_fits"):
+        resolve_use_pallas("persist", 1280, backend="tpu")
     assert resolve_use_pallas("persist", 513, backend="cpu") is False
     assert resolve_use_pallas("on", 128, backend="cpu") == "flash"
     assert resolve_use_pallas(False, 4096, backend="tpu") is False

@@ -148,3 +148,15 @@ class TestChineseTokenizer:
         assert len(ids) == 4 and all(i > 4 for i in ids)   # no [UNK] (id 1)
         round_trip = tok.decode(ids).replace(" ", "")
         assert round_trip == "红色圆形"
+
+
+def test_native_core_binary_is_named_by_its_source_digest():
+    """A binary copied along with the tree (file times do not survive a
+    copy) loads only if it was built from exactly the committed source."""
+    import hashlib
+    from dalle_tpu.text import native
+    digest = hashlib.sha256(native._SRC.read_bytes()).hexdigest()[:16]
+    assert native._lib_path().name == f"libbpe_core.{digest}.so"
+    if native.NativeBPE.available():
+        built = sorted(p.name for p in native._HERE.glob("libbpe_core*.so"))
+        assert built == [native._lib_path().name]
