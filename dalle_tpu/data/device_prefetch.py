@@ -29,7 +29,6 @@ default put imports lazily.
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Callable, Iterable, Iterator, Optional
 
@@ -69,10 +68,9 @@ class DevicePrefetcher:
                 self._err = e
                 return
             try:
-                t0 = time.perf_counter()
-                with span("data/h2d"):
+                with span("data/h2d") as h2d:
                     placed = self._put(item)
-                self._buf.append((placed, time.perf_counter() - t0))
+                self._buf.append((placed, h2d.duration))
             except Exception as e:  # noqa: BLE001 - held, raised in order
                 self._err = e
                 return

@@ -227,23 +227,26 @@ class DALLE(nn.Module):
                 f"loss_chunk={c.loss_chunk} must divide the sequence length "
                 f"{n} — a silent fall-back would rematerialize the full "
                 f"(b, n, vocab) logits the option exists to avoid")
-        if c.loss_chunk > 0 and not self.is_initializing():
-            # chunked head+CE under remat: full (b, n, vocab) logits never hit
-            # HBM — each chunk's logits are recomputed in backward
-            parts = []
-            for i in range(0, n, c.loss_chunk):
-                body = nn.remat(_ce_chunk_body, prevent_cse=False,
-                                static_argnums=(3,))
-                parts.append(body(self, out[:, i:i + c.loss_chunk],
-                                  labels[:, i:i + c.loss_chunk], i))
-            ce = jnp.concatenate(parts, axis=1)
-        else:
-            logits = self._finish(out, (0, n))
-            logits32 = logits.astype(jnp.float32)
-            ce = optax.softmax_cross_entropy_with_integer_labels(logits32, labels)
-        loss_text = ce[:, :c.text_seq_len].mean()
-        loss_img = ce[:, c.text_seq_len:].mean()
-        loss = (loss_text + c.loss_img_weight * loss_img) / (c.loss_img_weight + 1)
+        with jax.named_scope("loss"):   # the vocabulary head and the CE
+            if c.loss_chunk > 0 and not self.is_initializing():
+                # chunked head+CE under remat: full (b, n, vocab) logits never
+                # hit HBM — each chunk's logits are recomputed in backward
+                parts = []
+                for i in range(0, n, c.loss_chunk):
+                    body = nn.remat(_ce_chunk_body, prevent_cse=False,
+                                    static_argnums=(3,))
+                    parts.append(body(self, out[:, i:i + c.loss_chunk],
+                                      labels[:, i:i + c.loss_chunk], i))
+                ce = jnp.concatenate(parts, axis=1)
+            else:
+                logits = self._finish(out, (0, n))
+                logits32 = logits.astype(jnp.float32)
+                ce = optax.softmax_cross_entropy_with_integer_labels(
+                    logits32, labels)
+            loss_text = ce[:, :c.text_seq_len].mean()
+            loss_img = ce[:, c.text_seq_len:].mean()
+            loss = ((loss_text + c.loss_img_weight * loss_img)
+                    / (c.loss_img_weight + 1))
         return loss, {"loss_text": loss_text, "loss_img": loss_img}
 
     # -- generation --------------------------------------------------------

@@ -210,9 +210,10 @@ class Attention(nn.Module):
                                   causal=self.causal)
         else:
             static = None if np_mask is None else jnp.asarray(np_mask)
-            out = attend(q, k, v, causal=self.causal, key_mask=key_mask,
-                         static_mask=static, stable=self.stable,
-                         softmax_f32=self.softmax_f32)
+            with jax.named_scope("attn_core"):
+                out = attend(q, k, v, causal=self.causal, key_mask=key_mask,
+                             static_mask=static, stable=self.stable,
+                             softmax_f32=self.softmax_f32)
         out = out.transpose(0, 2, 1, 3).reshape(b, n, -1)
         return self.drop(self.to_out(out), deterministic=deterministic)
 

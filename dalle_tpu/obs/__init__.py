@@ -6,9 +6,11 @@ counters/gauges that merge into ``MetricsLogger`` records and a Prometheus
 textfile, device telemetry (HBM + live recompile rate), and a stall
 watchdog. See docs/OBSERVABILITY.md for the operator guide.
 
-Everything is off by default and near-free when off: ``span`` costs one
-global ``None`` check until ``configure()`` enables tracing
-(``TrainConfig.obs.trace`` / ``--obs.trace true`` from the CLIs).
+The ring, counters and exports are off by default (``configure()`` turns
+them on: ``TrainConfig.obs.trace`` / ``--obs.trace true`` from the CLIs). A
+``span`` always times itself (``sp.duration``, ``phase_totals()``: a couple
+of microseconds) and, while a jax profiler session is live, lands on its host
+plane under the same name.
 
 Two submodules are the runtime halves of static analysis layers and are
 imported explicitly by the smokes (never re-exported here):
@@ -19,8 +21,7 @@ contract (``contracts/wire.json``).
 """
 
 from .anomaly import (Breach, CodebookCollapseDetector, GradExplosionDetector,
-                      HealthSentry, LossSpikeDetector, NaNPrecursorDetector,
-                      split_health_key)
+                      HealthSentry, LossSpikeDetector, NaNPrecursorDetector)
 from .collect import (ClockOffsetEstimator, TelemetryCollector,
                       TelemetryExporter, UsageLedger, read_telemetry_dir,
                       telemetry_payload)
@@ -33,28 +34,25 @@ from .recorder import (FlightRecorder, collect_state, configure_recorder,
 from .report import (format_request_timeline, request_timeline,
                      span_overhead_s, summarize_run)
 from .slo import BurnRateSentry
-from .trace import (DEFAULT_BUCKETS, MAX_HISTOGRAM_BUCKETS, Tracer,
-                    configure, counter_add, disable, enabled,
+from .trace import (DEFAULT_BUCKETS, configure, counter_add, disable, enabled,
                     exemplars_snapshot, export_chrome_trace,
-                    export_spans_jsonl, gauge_set, get_tracer,
-                    histogram_observe, labeled_name, metrics_snapshot,
-                    open_spans, record_span, span)
-from .watchdog import StallReport, StallWatchdog
+                    export_spans_jsonl, gauge_set, histogram_observe,
+                    metrics_snapshot, open_spans, phase_totals, record_span,
+                    reset_phase_totals, span)
+from .watchdog import StallWatchdog
 
 _DEVICE_NAMES = ("CompileCounter", "DeviceTelemetry", "device_memory_stats",
                  "install_compile_counter")
 
 # graftpulse in-jit taps (obs/health.py) import jax; resolved lazily like
 # obs.device so the host-side anomaly/report layers stay jax-free
-_HEALTH_NAMES = ("layer_groups", "group_norms", "nonfinite_fractions",
-                 "tree_health", "codebook_health", "gumbel_health",
-                 "decode_quality")
+_HEALTH_NAMES = ("layer_groups", "tree_health", "codebook_health",
+                 "gumbel_health", "decode_quality")
 
 __all__ = [
     *_DEVICE_NAMES, *_HEALTH_NAMES,
     "Breach", "CodebookCollapseDetector", "GradExplosionDetector",
     "HealthSentry", "LossSpikeDetector", "NaNPrecursorDetector",
-    "split_health_key",
     "ClockOffsetEstimator", "TelemetryCollector", "TelemetryExporter",
     "UsageLedger", "read_telemetry_dir", "telemetry_payload",
     "current_trace_id", "new_trace_id", "trace_context",
@@ -64,11 +62,11 @@ __all__ = [
     "install_signal_dump", "record_event", "register_state_provider",
     "unregister_state_provider", "format_request_timeline",
     "request_timeline", "span_overhead_s", "summarize_run",
-    "BurnRateSentry", "DEFAULT_BUCKETS", "MAX_HISTOGRAM_BUCKETS", "Tracer",
+    "BurnRateSentry", "DEFAULT_BUCKETS",
     "configure", "counter_add", "disable", "enabled", "exemplars_snapshot",
     "export_chrome_trace", "export_spans_jsonl", "gauge_set",
-    "get_tracer", "histogram_observe", "labeled_name", "metrics_snapshot",
-    "open_spans", "record_span", "span", "StallReport", "StallWatchdog",
+    "histogram_observe", "metrics_snapshot", "open_spans", "phase_totals",
+    "record_span", "reset_phase_totals", "span", "StallWatchdog",
 ]
 
 

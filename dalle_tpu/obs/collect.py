@@ -80,14 +80,8 @@ def _merge_label(key: str, label: str, value: str) -> str:
 
 
 def _span_rows_to_json(tracer, rows) -> List[dict]:
-    out = []
-    for name, rel, dur, tid, depth, args in rows:
-        rec = {"name": name, "ts": tracer.epoch_origin + rel, "rel_s": rel,
-               "dur_s": dur, "tid": tid, "depth": depth}
-        if args:
-            rec["args"] = args
-        out.append(rec)
-    return out
+    from .trace import span_row_json
+    return [span_row_json(tracer, row) for row in rows]
 
 
 def telemetry_payload(since_seq: int = 0, *, events_limit: int = 512) -> dict:

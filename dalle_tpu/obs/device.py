@@ -17,6 +17,11 @@ recompiling?* — both answerable in-process without a profiler attach:
 
 ``DeviceTelemetry`` bundles both into a poller the trainers call at metrics
 boundaries: HBM used/peak plus a sliding-window recompile rate.
+
+This is the one jax-importing module of ``obs``, so importing it is also what
+bridges ``obs.span`` to the jax profiler: every span becomes a
+``jax.profiler.TraceAnnotation`` of the same name while a profiler session
+is live (obs/trace.py stays importable without jax).
 """
 
 from __future__ import annotations
@@ -28,6 +33,10 @@ import jax
 
 # private import; CompileCounter's self-test fails loudly if the event moves
 from jax._src.dispatch import BACKEND_COMPILE_EVENT
+
+from .trace import set_profiler_annotation
+
+set_profiler_annotation(jax.profiler.TraceAnnotation)
 
 
 class CompileCounter:

@@ -5,10 +5,15 @@ print and a one-shot profiler capture (train/metrics.py) — enough to know a
 run is slow, never enough to know *why*. grafttrace adds the missing layer:
 
   * ``span(name)`` — a context manager / decorator timing a named region,
-    with thread-local nesting. When tracing is disabled (the default) the
-    cost is a single global ``None`` check; when enabled, two
-    ``perf_counter`` calls and one deque append (~1µs), so spans can live on
-    per-step hot paths without moving the numbers they measure.
+    with thread-local nesting. One measurement (two ``perf_counter`` reads)
+    feeds three sinks: always, ``sp.duration`` and the process-wide totals
+    per name (``phase_totals()``); with the ring on (``configure()``), a
+    record with ``id`` and ``parent``; while a jax profiler session is
+    live, a ``TraceAnnotation`` of the same name on the profiler's own
+    clock (the annotation class is installed by ``obs/device.py``, the
+    jax-importing side: this module imports no jax). A couple of
+    microseconds with the ring off, so spans can live on per-step hot paths
+    without moving the numbers they measure.
   * an in-process ring buffer of completed spans (bounded; overflow is
     *counted*, never silent) that exports both JSONL (one span per line,
     greppable, ``scripts/obs_report.py``'s input) and Chrome ``trace_event``
@@ -26,6 +31,7 @@ exposes the live per-thread stacks for the stall watchdog's reports.
 from __future__ import annotations
 
 import functools
+import itertools
 import json
 import os
 import threading
@@ -42,7 +48,14 @@ from .context import current_trace_id
 # ---------------------------------------------------------------------------
 
 _TLS = threading.local()
-_STACKS: dict = {}          # thread ident -> (thread name, open-span stack)
+# thread ident -> (thread name, open-span stack, totals by span name); a
+# thread only ever writes its own entry's stack and totals, so the hot path
+# takes no lock
+_STACKS: dict = {}
+_STACKS_LOCK = threading.Lock()   # registering a thread, folding a dead one
+_RETIRED: dict = {}         # totals of threads whose ident was reused
+_SPAN_IDS = itertools.count(1)
+_annotation = None          # jax.profiler.TraceAnnotation once obs.device loads
 _tracer: Optional["Tracer"] = None
 
 # Native histogram discipline (graftlens): bucket boundaries are declared at
@@ -85,21 +98,63 @@ class _Histogram:
         self.exemplars: dict = {}                     # bucket idx -> exemplar
 
 
-def _stack() -> list:
-    s = getattr(_TLS, "stack", None)
-    if s is None:
-        s = []
-        _TLS.stack = s
-        _STACKS[threading.get_ident()] = (threading.current_thread().name, s)
-    return s
+def _thread_state() -> tuple:
+    """(open-span stack, {name: [count, seconds]}) of the calling thread."""
+    st = getattr(_TLS, "state", None)
+    if st is None:
+        st = _TLS.state = ([], {})
+        ident = threading.get_ident()
+        with _STACKS_LOCK:
+            dead = _STACKS.get(ident)   # an ended thread whose ident is reused
+            if dead is not None:
+                _merge_totals(_RETIRED, dead[2])
+            _STACKS[ident] = (threading.current_thread().name, *st)
+    return st
+
+
+def _merge_totals(into: dict, cells: dict) -> None:
+    for name, (count, seconds) in list(cells.items()):
+        have = into.get(name, (0, 0.0))
+        into[name] = (have[0] + count, have[1] + seconds)
+
+
+def set_profiler_annotation(cls) -> None:
+    """Install the class whose instances put a span on a live jax profiler
+    session's host plane (``jax.profiler.TraceAnnotation``; its
+    ``is_enabled()`` says whether one is live, so none is made otherwise).
+    Called by ``obs/device.py`` at import, so this module stays importable
+    without jax."""
+    global _annotation
+    _annotation = cls
+
+
+def phase_totals() -> dict:
+    """``{span name: (count, seconds)}`` over every span closed in this
+    process so far, on every thread, whether or not the ring is on."""
+    out: dict = {}
+    with _STACKS_LOCK:
+        _merge_totals(out, _RETIRED)
+        for _name, _stack, cells in list(_STACKS.values()):
+            _merge_totals(out, cells)
+    return out
+
+
+def reset_phase_totals() -> None:
+    """Zero the totals (for tests)."""
+    with _STACKS_LOCK:
+        _RETIRED.clear()
+        for _name, _stack, cells in list(_STACKS.values()):
+            cells.clear()
 
 
 class Tracer:
     """Process-wide span sink: a bounded ring of completed spans plus
     counter/gauge maps. Span records are plain tuples
-    ``(name, rel_start_s, dur_s, thread_ident, depth, args)`` — relative to
-    ``time_origin`` (a ``perf_counter`` anchor paired with a wall-clock
-    epoch, so exports can be mapped back to absolute time)."""
+    ``(name, rel_start_s, dur_s, thread_ident, depth, args, id, parent)`` —
+    relative to ``time_origin`` (a ``perf_counter`` anchor paired with a
+    wall-clock epoch, so exports can be mapped back to absolute time);
+    ``parent`` is the id of the span that was open beneath it on its thread
+    (None at depth 0, as for every ``record_span``)."""
 
     def __init__(self, capacity: int = 65536):
         self.capacity = capacity
@@ -113,7 +168,7 @@ class Tracer:
         self.t_origin = time.perf_counter()
         self.epoch_origin = time.time()
 
-    def _record(self, name, t0, dur, depth, args):
+    def _record(self, name, t0, dur, depth, args, span_id, parent):
         # locked: exports iterate the deque from other threads, and a deque
         # mutated mid-iteration raises RuntimeError (the lock is uncontended
         # on the hot path — ~100ns next to two perf_counter calls)
@@ -122,7 +177,8 @@ class Tracer:
                 self.dropped += 1
             self.total_recorded += 1
             self.spans.append((name, t0 - self.t_origin, dur,
-                               threading.get_ident(), depth, args))
+                               threading.get_ident(), depth, args,
+                               span_id, parent))
 
     def snapshot_spans(self) -> list:
         with self._lock:
@@ -185,15 +241,22 @@ class span:
     """Time a named region: ``with span("fit/dispatch"): ...`` or
     ``@span("data/decode")``. Keyword args become span args in the export
     (e.g. ``span("fit/step", step=12)``); ``sp.set(...)`` attaches more from
-    inside the region. ``sp.duration`` holds the measured seconds after exit
-    (None when tracing was disabled at entry)."""
+    inside the region. After exit ``sp.duration`` holds the measured seconds,
+    ring on or off, and the same seconds are in ``phase_totals()``.
 
-    __slots__ = ("name", "args", "duration", "_t0", "_stack")
+    ``profiler=False`` keeps the span off a live jax profiler session. It is
+    for a span that encloses whole steps (``fit/step``): a reader that gives a
+    device gap to the host span covering most of it (benchmarks/xplane.py)
+    would hand every gap to the enclosing span."""
 
-    def __init__(self, name: str, **args):
+    __slots__ = ("name", "args", "duration", "id", "parent", "_profiler",
+                 "_t0", "_state", "_annotation")
+
+    def __init__(self, name: str, *, profiler: bool = True, **args):
         self.name = name
         self.args = args or None
         self.duration = None
+        self._profiler = profiler
 
     def set(self, **args) -> "span":
         if self.args is None:
@@ -203,23 +266,41 @@ class span:
         return self
 
     def __enter__(self) -> "span":
-        if _tracer is None:
-            self._t0 = None
-            return self
-        s = _stack()
-        s.append(self)
-        self._stack = s
+        state = self._state = getattr(_TLS, "state", None) or _thread_state()
+        stack = state[0]
+        self.id = next(_SPAN_IDS)
+        self.parent = stack[-1].id if stack else None
+        stack.append(self)
+        note = None
+        if (self._profiler and _annotation is not None
+                and _annotation.is_enabled()):   # a profiler session is live
+            note = _annotation(self.name)
+            note.__enter__()
+        self._annotation = note
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *_exc) -> bool:
-        t1 = time.perf_counter()
-        if self._t0 is None:
-            return False
-        s = self._stack
-        if s and s[-1] is self:
-            s.pop()
-        self.duration = t1 - self._t0
+        dur = self.duration = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        stack, totals = self._state
+        if stack and stack[-1] is self:
+            stack.pop()
+            depth = len(stack)
+        elif self in stack:
+            # closed out of order: fit/warmup opens before the first
+            # fit/step and ends inside it
+            depth = stack.index(self)
+            del stack[depth]
+        else:
+            depth = 0
+        cell = totals.get(self.name)
+        if cell is None:
+            totals[self.name] = [1, dur]
+        else:
+            cell[0] += 1
+            cell[1] += dur
         tr = _tracer
         if tr is not None:
             # ambient trace context (obs/context.py): a span recorded while
@@ -232,15 +313,16 @@ class span:
                     self.args = {"trace_id": tid}
                 else:
                     self.args.setdefault("trace_id", tid)
-            tr._record(self.name, self._t0, self.duration, len(s), self.args)
+            tr._record(self.name, self._t0, dur, depth, self.args,
+                       self.id, self.parent)
         return False
 
     def __call__(self, fn):
-        name, args = self.name, self.args
+        name, args, profiler = self.name, self.args, self._profiler
 
         @functools.wraps(fn)
         def wrapped(*a, **kw):
-            with span(name, **(args or {})):
+            with span(name, profiler=profiler, **(args or {})):
                 return fn(*a, **kw)
 
         return wrapped
@@ -393,14 +475,16 @@ def record_span(name: str, start_perf_s: float, duration_s: float,
     captured at region start; the record lands in the same ring as regular
     spans (depth 0) and exports identically. No-op when tracing is off.
     Like ``span``, inherits the thread's ambient trace_id (obs/context.py)
-    unless one is passed explicitly."""
+    unless one is passed explicitly. Ring only: it reaches neither
+    ``phase_totals()`` nor the profiler."""
     tr = _tracer
     if tr is None:
         return
     tid = current_trace_id()
     if tid is not None and "trace_id" not in args:
         args["trace_id"] = tid
-    tr._record(name, start_perf_s, duration_s, 0, args or None)
+    tr._record(name, start_perf_s, duration_s, 0, args or None,
+               next(_SPAN_IDS), None)
 
 
 def open_spans() -> dict:
@@ -408,7 +492,7 @@ def open_spans() -> dict:
     ``{"MainThread:140..": ["fit/step", "fit/dispatch"], ...}``. The stall
     watchdog's "where is it stuck" signal."""
     out = {}
-    for ident, (tname, stack) in list(_STACKS.items()):
+    for ident, (tname, stack, _totals) in list(_STACKS.items()):
         names = [sp.name for sp in list(stack)]
         if names:
             out[f"{tname}:{ident}"] = names
@@ -419,21 +503,29 @@ def open_spans() -> dict:
 # exports
 # ---------------------------------------------------------------------------
 
+def span_row_json(tr: Tracer, row: tuple) -> dict:
+    """One ring record as the JSON object of ``spans.jsonl`` and of the
+    telemetry payload (obs/collect.py): absolute ``ts`` (unix seconds),
+    ``dur_s``, thread id, nesting depth, ``id``, ``parent`` and args."""
+    name, rel, dur, tid, depth, args, span_id, parent = row
+    rec = {"name": name, "ts": tr.epoch_origin + rel, "rel_s": rel,
+           "dur_s": dur, "tid": tid, "depth": depth, "id": span_id,
+           "parent": parent}
+    if args:
+        rec["args"] = args
+    return rec
+
+
 def export_spans_jsonl(path: str, tracer: Optional[Tracer] = None) -> int:
-    """Write the ring as JSONL — one span object per line with absolute
-    ``ts`` (unix seconds), ``dur_s``, thread id, nesting depth, and args.
+    """Write the ring as JSONL — one ``span_row_json`` object per line.
     Returns the number of spans written."""
     tr = tracer or _tracer
     if tr is None:
         return 0
     rows = tr.snapshot_spans()
     with open(path, "w") as fh:
-        for name, rel, dur, tid, depth, args in rows:
-            rec = {"name": name, "ts": tr.epoch_origin + rel, "rel_s": rel,
-                   "dur_s": dur, "tid": tid, "depth": depth}
-            if args:
-                rec["args"] = args
-            fh.write(json.dumps(rec) + "\n")
+        for row in rows:
+            fh.write(json.dumps(span_row_json(tr, row)) + "\n")
     return len(rows)
 
 
@@ -456,19 +548,17 @@ def export_chrome_trace(path: str, tracer: Optional[Tracer] = None, *,
     pid = os.getpid()
     events = []
     rows = tr.snapshot_spans()
-    for name, rel, dur, tid, depth, args in rows:
-        ev = {"name": name, "ph": "X", "pid": pid, "tid": tid,
-              "ts": rel * 1e6, "dur": dur * 1e6}
-        if args:
-            ev["args"] = dict(args)
-        events.append(ev)
+    for name, rel, dur, tid, depth, args, span_id, parent in rows:
+        events.append({"name": name, "ph": "X", "pid": pid, "tid": tid,
+                       "ts": rel * 1e6, "dur": dur * 1e6,
+                       "args": dict(args or {}, id=span_id, parent=parent)})
     if request_tracks:
         # synthetic process 1: one virtual tid per trace_id, named after it
         track_ids: dict = {}
         events.append({"ph": "M", "pid": 1, "tid": 0,
                        "name": "process_name",
                        "args": {"name": "requests (graftscope)"}})
-        for name, rel, dur, tid, depth, args in rows:
+        for name, rel, dur, tid, _depth, args, _id, _parent in rows:
             trace_id = (args or {}).get("trace_id")
             if trace_id is None:
                 continue

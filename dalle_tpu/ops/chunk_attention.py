@@ -237,6 +237,7 @@ def chunk_flash_fwd(q, k, v, q_off, k_off, *, scale: float, n_valid: int,
         out_shape=[jax.ShapeDtypeStruct((b, h, nq_, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, nq_, 128), jnp.float32)],
         interpret=_interp(interpret),
+        name="chunk_attn_fwd",
     )(offs, q, k, v)
     return o, lse[..., 0]
 
@@ -268,6 +269,7 @@ def chunk_flash_dq(q, k, v, do, lse, delta, q_off, k_off, *, scale: float,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, nq_, d), jnp.float32),
         interpret=_interp(interpret),
+        name="chunk_attn_dq",
     )(offs, q, k, v, do, lse128, delta128)
 
 
@@ -298,6 +300,7 @@ def chunk_flash_dkv(q, k, v, do, lse, delta, q_off, k_off, *, scale: float,
         out_shape=[jax.ShapeDtypeStruct((b, h, nk_, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, nk_, d), jnp.float32)],
         interpret=_interp(interpret),
+        name="chunk_attn_dkv",
     )(offs, q, k, v, do, lse128, delta128)
 
 

@@ -144,6 +144,7 @@ def decode_attend_kernel(q, cache, length, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), out_dtype),
         interpret=interpret,
+        name="decode_attn",
     )(jnp.asarray(length, jnp.int32).reshape(1), *args)
     return out[:, :, None, :]
 
@@ -275,6 +276,7 @@ def decode_attend_window_kernel(q, cache, starts, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, w * h, d), out_dtype),
         interpret=interpret,
+        name="decode_attn_window",
     )(jnp.asarray(starts, jnp.int32).reshape(b), *args)
     return out.reshape(b, w, h, d).transpose(0, 2, 1, 3)
 
@@ -458,6 +460,7 @@ def decode_attend_kernel_chunked(q, cache, length, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), out_dtype),
         interpret=interpret,
+        name="decode_attn_chunked",
     )(jnp.asarray(length, jnp.int32).reshape(1), *args)
     return out[:, :, None, :]
 

@@ -361,6 +361,7 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
                 jax.ShapeDtypeStruct((b, h, n_pad, 128), jnp.float32),
             ],
             interpret=interpret,
+            name="flash_attn_fwd",
         )(*operands)
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -408,6 +409,7 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
             grid_spec=dq_grid,
             out_shape=jax.ShapeDtypeStruct((b, h, n_pad, d), qp.dtype),
             interpret=interpret,
+            name="flash_attn_dq",
         )(*dq_operands)
 
         kblock_spec = pl.BlockSpec((1, 1, block_k, d),
@@ -443,6 +445,7 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
                 jax.ShapeDtypeStruct((b, h, n_pad, d), qp.dtype),
             ],
             interpret=interpret,
+            name="flash_attn_dkv",
         )(*dkv_operands)
         return dq[:, :, :n], dk[:, :, :n], dv[:, :, :n]
 

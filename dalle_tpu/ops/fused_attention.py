@@ -228,6 +228,7 @@ def _fused_fwd(qkv, mask, heads, scale, interpret, mask_spec=None):
         out_shape=jax.ShapeDtypeStruct((b, n, hd), qkv.dtype),
         compiler_params=_compiler_params(18 * n * hd + 10 * n * n),
         interpret=_interp(interpret),
+        name="fused_attn_fwd",
     )(qkv.astype(jnp.bfloat16), jnp.asarray(tbl))
     return out, (qkv,)
 
@@ -249,6 +250,7 @@ def _fused_bwd(mask, heads, scale, interpret, mask_spec, res, do):
         out_shape=jax.ShapeDtypeStruct((b, n, hd3), qkv.dtype),
         compiler_params=_compiler_params(_bwd_bytes(n, hd)),
         interpret=_interp(interpret),
+        name="fused_attn_bwd",
     )(qkv.astype(jnp.bfloat16), do.astype(jnp.bfloat16), jnp.asarray(tbl))
     return (dqkv,)
 

@@ -135,6 +135,7 @@ def _persist_fwd(q, k, v, mask, scale, interpret):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b, h, n, d), q.dtype),
         interpret=_interp(interpret),
+        name="persist_attn_fwd",
     )(*args)
     return out, (q, k, v)
 
@@ -157,6 +158,7 @@ def _persist_bwd(mask, scale, interpret, res, do):
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((b, h, n, d), q.dtype)] * 3,
         interpret=_interp(interpret),
+        name="persist_attn_bwd",
     )(*args)
     return dq, dk, dv
 

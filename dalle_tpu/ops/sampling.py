@@ -7,27 +7,10 @@ Reference analogues: ``top_k`` (dalle_pytorch/dalle_pytorch.py:63-69),
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
 import jax.numpy as jnp
 
-from ..obs.trace import enabled as _obs_enabled
-from ..obs.trace import span as _span
-
 NEG_INF = -jnp.inf
-
-
-def _eager_span(name: str, *arrays):
-    """grafttrace span that only records for EAGER calls: under jit these
-    functions run at trace time once (and the scan body's wall clock is
-    invisible from the host anyway), so timing a Tracer would log trace
-    overhead as if it were decode latency. Eager callers — the sampling
-    eval scripts and any host-side decode loop — get real per-op spans."""
-    if not _obs_enabled() or any(isinstance(a, jax.core.Tracer)
-                                 for a in arrays):
-        return contextlib.nullcontext()
-    return _span(name)
 
 
 def top_k_filter(logits: jnp.ndarray, thres: float = 0.5,
@@ -46,34 +29,31 @@ def top_k_filter(logits: jnp.ndarray, thres: float = 0.5,
     scripts/eval_decode_precisions.py)."""
     num = logits.shape[-1]
     k = max(int((1.0 - thres) * num), 1)
-    with _eager_span("sampling/top_k_filter", logits):
-        if approx:
-            kth = jax.lax.approx_max_k(logits, k)[0][..., -1:]
-        else:
-            kth = jax.lax.top_k(logits, k)[0][..., -1:]
-        return jnp.where(logits < kth, NEG_INF, logits)
+    if approx:
+        kth = jax.lax.approx_max_k(logits, k)[0][..., -1:]
+    else:
+        kth = jax.lax.top_k(logits, k)[0][..., -1:]
+    return jnp.where(logits < kth, NEG_INF, logits)
 
 
 def top_p_filter(logits: jnp.ndarray, top_p: float = 0.9) -> jnp.ndarray:
     """Nucleus filtering (additive capability; the reference exposes top-k only)."""
-    with _eager_span("sampling/top_p_filter", logits):
-        sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
-        probs = jax.nn.softmax(sorted_logits, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        # keep tokens until cumulative prob exceeds top_p (always keep the first)
-        keep_sorted = jnp.concatenate(
-            [jnp.ones_like(cum[..., :1], dtype=bool), cum[..., :-1] < top_p], axis=-1)
-        kth = jnp.min(jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1, keepdims=True)
-        return jnp.where(logits < kth, NEG_INF, logits)
+    sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
+    probs = jax.nn.softmax(sorted_logits, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    # keep tokens until cumulative prob exceeds top_p (always keep the first)
+    keep_sorted = jnp.concatenate(
+        [jnp.ones_like(cum[..., :1], dtype=bool), cum[..., :-1] < top_p], axis=-1)
+    kth = jnp.min(jnp.where(keep_sorted, sorted_logits, jnp.inf), axis=-1, keepdims=True)
+    return jnp.where(logits < kth, NEG_INF, logits)
 
 
 def gumbel_sample(key: jax.Array, logits: jnp.ndarray, temperature: float = 1.0,
                   axis: int = -1) -> jnp.ndarray:
     """argmax(logits/T + Gumbel noise) — identical semantics to the reference's
     gumbel trick (dalle_pytorch.py:54-61)."""
-    with _eager_span("sampling/gumbel_sample", logits, key):
-        g = jax.random.gumbel(key, logits.shape, dtype=jnp.float32)
-        return jnp.argmax(logits.astype(jnp.float32) / max(temperature, 1e-10) + g, axis=axis)
+    g = jax.random.gumbel(key, logits.shape, dtype=jnp.float32)
+    return jnp.argmax(logits.astype(jnp.float32) / max(temperature, 1e-10) + g, axis=axis)
 
 
 def gumbel_sample_rows(keys: jax.Array, logits: jnp.ndarray, *,

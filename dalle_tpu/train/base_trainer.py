@@ -5,6 +5,7 @@ and throughput metering — one implementation for every model family."""
 
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import time
@@ -53,8 +54,12 @@ class BaseTrainer:
     # machinery added after them
     _last_good_device = None
     _deferred_metrics = None
-    _obs_last_h2d = 0.0
-    _obs_last_ckpt = 0.0
+    # the open span of fit()'s current phase (None outside fit: a bare
+    # train_step gets no breakdown), the id its iteration's spans share,
+    # and fit/warmup while it is open
+    _fit_phase = None
+    _fit_step = None
+    _fit_warmup = None
     # graftpulse (obs/anomaly.py): built by fit() when ObsConfig.health is
     # set; every fetched metrics dict passes through _health_observe once
     health_sentry = None
@@ -87,12 +92,7 @@ class BaseTrainer:
         self._last_good = None   # host copy of (params, opt_state) for rollback
         self._last_good_device = None   # on-device copy (rollback_snapshot)
         self._host_step = 0      # host mirror of state.step: no device sync
-        # grafttrace step-breakdown state (set by fit, consumed by
-        # _finish_step; None dispatch-t0 = bare train_step outside fit)
-        self._obs_dispatch_t0 = None
-        self._obs_last_wait = 0.0
-        self._obs_last_h2d = 0.0
-        self._obs_last_ckpt = 0.0
+        # the starvation window between two records (_finish_breakdown)
         self._obs_wait_accum = 0.0
         self._obs_window_t0 = None
         self._obs_poll_bucket = -1
@@ -158,27 +158,98 @@ class BaseTrainer:
         check of the CURRENT state)."""
         if getattr(self, "_pending_metrics", None) is None:
             return {}
-        sync0 = time.perf_counter()
-        with span("fit/sync", on_demand=True):
-            metrics = {k: float(v) for k, v in
-                       jax.device_get(self._pending_metrics).items()}
         # the same step's metrics are now consumed in-band — retire the
         # deferred copy so the next boundary doesn't re-emit them, but keep
         # its parked breakdown: dropping it would lose every t_* column
         # (and the once-consumed t_ckpt_s) whenever the save cadence
         # coincides with the metrics cadence
+        part = None
         if (self._deferred_metrics is not None
                 and self._deferred_metrics[0] == self._host_step):
             part = self._deferred_metrics[2]
             self._deferred_metrics = None
-            if part is not None:
-                now = time.perf_counter()
-                part["t_sync_s"] = now - sync0
-                metrics.update(self._finish_breakdown(part, now))
+        metrics = self._fetch(self._pending_metrics, part)
         rep = self.meter.step(self._host_step)
         if rep:
             metrics.update(rep)
         return self._health_observe(self._host_step, metrics)
+
+    def _phase(self, name: Optional[str]):
+        """Move fit()'s iteration on to the phase ``name``: close the open
+        phase span and open the next as its sibling (``None``: close only),
+        so a phase that starts in ``fit`` can end inside ``train_step``'s
+        ``_finish_step`` and the phases tile the iteration. Returns the span
+        just closed (None if ``name`` was already open). ``fit/warmup`` ends
+        where the first ``fit/after_step`` starts."""
+        cur = self._fit_phase
+        if cur is not None:
+            if cur.name == name:
+                return None
+            cur.__exit__(None, None, None)
+            if cur.name == "fit/after_step":
+                self._fit_after += cur.duration
+        if name == "fit/after_step" and self._fit_warmup is not None:
+            self._fit_warmup.__exit__(None, None, None)
+            self._fit_warmup = None
+        nxt = None
+        if name:
+            nxt = (span(name) if self._fit_step is None   # a bare train_step
+                   else span(name, step=self._fit_step)).__enter__()
+        self._fit_phase = nxt
+        return cur
+
+    @contextlib.contextmanager
+    def _iteration(self):
+        """One ``fit/step`` (in the ring for obs_report's step histogram,
+        kept off the profiler because it encloses the phases), opened on its
+        first phase, ``fit/batch_wait``."""
+        self._fit_step = self._host_step
+        self._fit_after = 0.0
+        with span("fit/step", profiler=False, step=self._fit_step):
+            try:
+                self._phase("fit/batch_wait")
+                yield
+            finally:
+                self._phase(None)
+                self._fit_step = None
+                # for the next record: the writer is called inside the
+                # phase this describes
+                self._fit_late["t_after_s"] = self._fit_after
+
+    def _fetch(self, device_metrics, part: Optional[dict] = None) -> dict:
+        """Every host fetch of a step's metrics, in-band, on-demand and
+        flush: ``jax.device_get`` under ``fit/sync``. Inside fit() that is a
+        phase (a sibling of ``fit/dispatch``, followed by
+        ``fit/after_step``). ``part`` is the parked breakdown of the step the
+        metrics describe: the span's seconds become its ``t_sync_s``."""
+        after = "fit/after_step" if self._fit_phase is not None else None
+        self._phase("fit/sync")
+        sync = self._fit_phase
+        try:
+            metrics = {k: float(v)
+                       for k, v in jax.device_get(device_metrics).items()}
+        finally:
+            self._phase(after)
+        if part is not None:
+            part["t_sync_s"] = sync.duration
+            metrics.update(self._finish_breakdown(part))
+        return metrics
+
+    def _create_state(self, params, apply_fn):
+        """The single-optimizer trainers' state from freshly initialised
+        params: placed on the mesh, given ``train_cfg.optim``'s optimizer
+        state, committed; each step under its ``init/*`` span."""
+        from ..parallel import commit_to_mesh, shard_params
+        from .train_state import TrainState, make_optimizer
+        tc = self.train_cfg
+        with span("init/commit_to_mesh"):
+            params = shard_params(self.mesh, params)
+        with span("init/optimizer"):
+            state = TrainState.create(
+                apply_fn=apply_fn, params=params, tx=make_optimizer(tc.optim),
+                lr_scale=1.0 if tc.runtime_lr_scale else None)
+        with span("init/commit_to_mesh"):
+            return commit_to_mesh(self.mesh, state)
 
     def _health_observe(self, step: int, metrics: dict) -> dict:
         """Run the graftpulse sentry over one FETCHED metrics dict (host
@@ -294,11 +365,16 @@ class BaseTrainer:
         check of the current state).
 
         grafttrace (``train_cfg.obs``, docs/OBSERVABILITY.md): every
-        iteration is a ``fit/step`` span nesting ``fit/batch_wait`` (time
-        blocked on the batch iterator), ``fit/dispatch`` (host work + device
-        dispatch), and ``fit/sync`` (the metrics device_get, inside
-        ``_finish_step``); the same splits land in the metrics dict as a
-        per-step breakdown with a data-starvation ratio. With
+        iteration is a ``fit/step`` span whose four phases are siblings that
+        tile it: ``fit/batch_wait`` (blocked on the batch iterator, then the
+        chaos hook), ``fit/dispatch`` (host work and the jitted call),
+        ``fit/sync`` (every metrics device_get) and ``fit/after_step`` (the
+        watchdog beat, ``on_step``, the writer, the save decision with
+        ``fit/checkpoint`` inside it, the sampling hook). The spans'
+        durations are the record's ``t_batch_wait_s`` / ``t_dispatch_s`` /
+        ``t_sync_s`` / ``t_after_s`` columns, beside a data-starvation
+        ratio; ``fit/warmup`` runs from fit()'s entry to the end of the
+        first iteration's ``fit/sync``. With
         ``obs.watchdog_deadline_s > 0`` a heartbeat watchdog reports stalls
         (open spans + thread stacks) instead of hanging silently; with
         ``obs.trace`` the span ring is exported as Perfetto-openable
@@ -354,6 +430,8 @@ class BaseTrainer:
                                                        stacked=item[0])),
                 depth=tc.device_prefetch)
             batches = prefetcher
+        self._fit_late = {}    # t_ckpt_s / t_after_s: land one record late
+        self._fit_warmup = span("fit/warmup").__enter__()
         meta = self._meta()
         if tc.preflight_checkpoint:
             self.ckpt.preflight(self.state, meta)
@@ -368,38 +446,48 @@ class BaseTrainer:
         _END = object()
         try:
             while True:
-                with span("fit/step") as step_span:
-                    t_wait0 = time.perf_counter()
-                    with span("fit/batch_wait"):
-                        item = next(it, _END)
+                with self._iteration():
+                    item = next(it, _END)
                     if item is _END:
                         break
-                    self._obs_last_wait = time.perf_counter() - t_wait0
-                    self._obs_wait_accum += self._obs_last_wait
-                    self._obs_last_h2d = (prefetcher.last_put_s
-                                          if prefetcher is not None else 0.0)
+                    # chaos injection point: kill/hang/slow/corrupt faults
+                    # fire here, BEFORE the dispatch — "mid-step" from the
+                    # run's point of view (the last durable save < this
+                    # step). Still inside fit/batch_wait: t_dispatch_s is the
+                    # straggler detector's "blocked" signal (degrade/), which
+                    # an injected host stall on the victim must not inflate
+                    _chaos_step_hook(self._host_step)
+                    # one phase ends where the next starts: a stall between
+                    # two statements (another thread holding the interpreter)
+                    # falls inside a phase and has a name
+                    wait = self._phase("fit/dispatch")
+                    self._fit_wait = wait.duration
+                    self._obs_wait_accum += wait.duration
+                    self._fit_h2d = (prefetcher.last_put_s
+                                     if prefetcher is not None else 0.0)
                     stacked, batch = item
                     step_call = self.train_steps if stacked else self.train_step
                     k_this = batch[0].shape[0] if stacked else 1
                     prev_step = self._host_step
-                    step_span.set(step=prev_step)
-                    # chaos injection point: kill/hang/slow/corrupt faults
-                    # fire here, BEFORE the dispatch — "mid-step" from the
-                    # run's point of view (the last durable save < this step)
-                    _chaos_step_hook(prev_step)
-                    self._obs_dispatch_t0 = time.perf_counter()
                     # profile the REAL step containing profile_step — no
                     # hidden extra update (the reference's flops profile also
-                    # wraps a live step, legacy/train_dalle.py:492-499)
-                    if tc.profile_step and prev_step < tc.profile_step <= prev_step + k_this:
-                        logdir = f"{tc.checkpoint_dir}/profile_step{tc.profile_step}"
-                        with jax.profiler.trace(logdir):
-                            with span("fit/dispatch", profiled=True):
-                                m = step_call(*batch)
+                    # wraps a live step, legacy/train_dalle.py:492-499).
+                    # fit/dispatch is opened anew once the session is live,
+                    # so that it lies on the profile's host plane
+                    logdir = None
+                    if tc.profile_step and (prev_step < tc.profile_step
+                                            <= prev_step + k_this):
+                        logdir = (f"{tc.checkpoint_dir}/"
+                                  f"profile_step{tc.profile_step}")
+                        self._phase(None)
+                    with (jax.profiler.trace(logdir) if logdir
+                          else contextlib.nullcontext()):
+                        self._phase("fit/dispatch")   # open unless profiled
+                        # _finish_step, inside, moves on to fit/sync
+                        m = step_call(*batch)
+                        self._phase("fit/after_step")
+                    if logdir:
                         log(f"[profile] step {self._host_step}: trace → {logdir}")
-                    else:
-                        with span("fit/dispatch"):
-                            m = step_call(*batch)
                     step_num = self._host_step
                     if watchdog is not None:
                         watchdog.beat(step_num)
@@ -430,14 +518,7 @@ class BaseTrainer:
                             # its breakdown)
                             dstep, dm, dpart = self._deferred_metrics
                             self._deferred_metrics = None
-                            dsync0 = time.perf_counter()
-                            with span("fit/sync", on_demand=True):
-                                dm = {k: float(v) for k, v in
-                                      jax.device_get(dm).items()}
-                            if dpart is not None:
-                                dnow = time.perf_counter()
-                                dpart["t_sync_s"] = dnow - dsync0
-                                dm.update(self._finish_breakdown(dpart, dnow))
+                            dm = self._fetch(dm, dpart)
                             self._health_observe(dstep, dm)
                             if metrics_writer is not None:
                                 metrics_writer.log(dstep, dm)
@@ -455,8 +536,7 @@ class BaseTrainer:
                             metrics_writer.log(mstep, m)
                         if want_save:
                             signal_save = getattr(self, "_signal_save", False)
-                            t_ckpt0 = time.perf_counter()
-                            with span("fit/checkpoint", step=step_num):
+                            with span("fit/checkpoint", step=step_num) as ckpt:
                                 # async manager: returns after the snapshot;
                                 # the write overlaps the next steps. An
                                 # operator-requested (SIGUSR1) save drains so
@@ -470,7 +550,7 @@ class BaseTrainer:
                                 if signal_save:
                                     self._ckpt_wait()
                                 self._snapshot_good()
-                            self._obs_last_ckpt = time.perf_counter() - t_ckpt0
+                            self._fit_late["t_ckpt_s"] = ckpt.duration
                             self._signal_save = False
                             if (getattr(tc, "log_artifacts", False)
                                     and metrics_writer is not None
@@ -507,21 +587,16 @@ class BaseTrainer:
                 if steps is not None and step_num >= steps:
                     break
         finally:
-            self._obs_dispatch_t0 = None   # bare train_step: no breakdown
+            if self._fit_warmup is not None:   # no step ran to its sync
+                self._fit_warmup.__exit__(None, None, None)
+                self._fit_warmup = None
             if self._deferred_metrics is not None:
                 # defer_metrics parks the final boundary's metrics — flush so
                 # the run's last record isn't silently dropped
                 fstep, fmetrics, fpart = self._deferred_metrics
                 self._deferred_metrics = None
                 try:
-                    fsync0 = time.perf_counter()
-                    with span("fit/sync", flush=True):
-                        fm = {k: float(v) for k, v in
-                              jax.device_get(fmetrics).items()}
-                    if fpart is not None:
-                        fnow = time.perf_counter()
-                        fpart["t_sync_s"] = fnow - fsync0
-                        fm.update(self._finish_breakdown(fpart, fnow))
+                    fm = self._fetch(fmetrics, fpart)
                     self._health_observe(fstep, fm)
                     log(f"[step {fstep}] " + _fmt_metrics(fm))
                     if metrics_writer is not None:
@@ -716,78 +791,65 @@ class BaseTrainer:
             return {}
         step_of = self._host_step
         defer = bool(getattr(self.train_cfg, "defer_metrics", False))
+        # defer: hand back the PREVIOUS boundary's metrics (that step has
+        # long finished — the device_get returns without stalling the
+        # pipeline) and park this boundary's for the next call, so the
+        # first boundary fetches nothing
+        will_sync = not defer or self._deferred_metrics is not None
         part = None
+        if self._fit_phase is not None:
+            # under fit(): the dispatch ends here
+            dispatch = self._phase("fit/sync" if will_sync
+                                   else "fit/after_step")
+            part = self._partial_breakdown(dispatch.duration)
         if defer:
-            # one-boundary-delayed pull: hand back the PREVIOUS boundary's
-            # metrics (that step has long finished — the device_get returns
-            # without stalling the pipeline) and park this boundary's for the
-            # next call. Records carry their true step via ``metrics_step``,
-            # and the wait/dispatch/h2d timings are parked WITH the step they
-            # describe so the record's columns all belong to metrics_step.
-            part = self._partial_breakdown(time.perf_counter())
+            # records carry their true step via ``metrics_step``, and the
+            # wait/dispatch/h2d timings are parked WITH the step they
+            # describe so the record's columns all belong to metrics_step;
+            # the sync paid below IS the handed-back record's fetch
             parked, self._deferred_metrics = (self._deferred_metrics,
                                               (step_of, metrics, part))
             if parked is None:
                 return {}
             step_of, metrics, part = parked
-        sync0 = time.perf_counter()
-        with span("fit/sync"):
-            metrics = {k: float(v) for k, v in jax.device_get(metrics).items()}
+        metrics = self._fetch(metrics, part)
         rep = self.meter.step(self._host_step)
         if rep:
             metrics.update(rep)
-        now = time.perf_counter()
-        if defer:
-            if part is not None:
-                # the sync just paid IS this record's fetch — attribute it here
-                part["t_sync_s"] = now - sync0
-                metrics.update(self._finish_breakdown(part, now))
-        else:
-            metrics.update(self._step_breakdown(sync0, now))
         self._health_observe(step_of, metrics)
         if step_of != self._host_step:
             metrics["metrics_step"] = step_of
         return metrics
 
-    def _step_breakdown(self, sync0: float, now: float) -> dict:
-        """Where did the step go? batch wait vs dispatch vs sync, plus the
-        waiting-on-data share of the whole window since the last report (so
-        ``metrics_every``-skipped steps are covered) — 'input-bound vs
-        compute-bound' as a logged metric instead of a guess. Device gauges
-        (HBM used/peak, compiles, recompiles-per-100-steps) ride along every
-        ``obs.device_poll_every`` steps, and the merged dict is mirrored to
-        the Prometheus textfile when ``obs.prometheus_path`` is set. Only
-        meaningful under fit(): a bare ``train_step()`` call has no
-        batch-wait context and gets no breakdown."""
-        out = self._partial_breakdown(sync0)
-        if out is None:
-            return {}
-        out["t_sync_s"] = now - sync0
-        return self._finish_breakdown(out, now)
-
-    def _partial_breakdown(self, dispatch_end: float) -> Optional[dict]:
-        """The per-step splits knowable at dispatch end (everything except
-        the sync): wait/dispatch/h2d plus the previous boundary's checkpoint
-        cost. None outside fit() (no batch-wait context)."""
-        t0 = getattr(self, "_obs_dispatch_t0", None)
-        if t0 is None:
-            return None
-        out = {"t_batch_wait_s": self._obs_last_wait,
-               "t_dispatch_s": dispatch_end - t0,
+    def _partial_breakdown(self, t_dispatch_s: float) -> dict:
+        """Where did the step go? The splits knowable when the dispatch
+        ends, each the duration of a span of fit(): ``fit/batch_wait``,
+        ``fit/dispatch``, the consumed batch's ``data/h2d`` and, one record
+        late, the previous iteration's ``fit/checkpoint`` and
+        ``fit/after_step``. ``_fetch`` adds ``t_sync_s`` and the window's
+        gauges (``_finish_breakdown``). Only under fit(): a bare
+        ``train_step()`` call has no batch-wait context and gets no
+        breakdown."""
+        out = {"t_batch_wait_s": self._fit_wait,
+               "t_dispatch_s": t_dispatch_s,
                # host-side H2D enqueue cost of the consumed batch (0 without
                # device prefetch — the put then rides inside batch_wait)
-               "t_h2d_s": self._obs_last_h2d}
-        if self._obs_last_ckpt:
-            # checkpoint dispatch cost of the PREVIOUS boundary (saves run
-            # after metrics are fetched, so the cost lands one record late) —
-            # obs_report accounts these steps as their own category
-            out["t_ckpt_s"] = self._obs_last_ckpt
-            self._obs_last_ckpt = 0.0
+               "t_h2d_s": self._fit_h2d}
+        # saves and the writer run after the metrics are fetched, so their
+        # cost lands one record late — obs_report accounts checkpoint steps
+        # as their own category
+        out.update(self._fit_late)
+        self._fit_late.clear()
         return out
 
-    def _finish_breakdown(self, out: dict, now: float) -> dict:
-        """Windowed starvation ratio + device-gauge poll + Prometheus mirror,
-        merged into ``out`` (the per-step splits)."""
+    def _finish_breakdown(self, out: dict) -> dict:
+        """Windowed starvation ratio (the waiting-on-data share of the whole
+        window since the last record, so ``metrics_every``-skipped steps are
+        covered: 'input-bound vs compute-bound' as a logged metric instead
+        of a guess) + device-gauge poll (HBM used/peak, compiles,
+        recompiles-per-100-steps every ``obs.device_poll_every`` steps) +
+        Prometheus mirror, merged into ``out`` (the per-step splits)."""
+        now = time.perf_counter()
         window_t0 = getattr(self, "_obs_window_t0", None)
         if window_t0 is not None and now > window_t0:
             out["data_starvation"] = min(self._obs_wait_accum / (now - window_t0), 1.0)

@@ -99,12 +99,14 @@ class TrainState:
         ratios from it without recomputing ``new - old`` params, which
         would read the donated input buffers) — post-``lr_scale``, i.e. the
         update actually applied."""
+        # make_optimizer's transforms scope themselves ("clip", "optimizer")
         updates, opt_state = self.tx.update(grads, self.opt_state, self.params,
                                             **extra_args)
-        if self.lr_scale is not None:
-            scale = self.lr_scale
-            updates = jax.tree.map(lambda u: u * scale, updates)
-        params = optax.apply_updates(self.params, updates)
+        with jax.named_scope("optimizer"):
+            if self.lr_scale is not None:
+                scale = self.lr_scale
+                updates = jax.tree.map(lambda u: u * scale, updates)
+            params = optax.apply_updates(self.params, updates)
         new = self.replace(step=self.step + 1, params=params,
                            opt_state=opt_state)
         return (new, updates) if return_updates else new
@@ -147,6 +149,20 @@ def make_optimizer(cfg: OptimConfig) -> optax.GradientTransformation:
     return _build_optimizer(cfg)
 
 
+def _scoped(name: str, tx) -> optax.GradientTransformationExtraArgs:
+    """``tx`` with its update traced under ``jax.named_scope(name)``, so the
+    device's operations carry the name in a profile. Same ``init``, so the
+    optimizer state's tree (and every checkpoint) is unchanged; a scope adds
+    no primitive."""
+    tx = optax.with_extra_args_support(tx)
+
+    def update(updates, state, params=None, **extra_args):
+        with jax.named_scope(name):
+            return tx.update(updates, state, params, **extra_args)
+
+    return optax.GradientTransformationExtraArgs(tx.init, update)
+
+
 def _build_optimizer(cfg: OptimConfig) -> optax.GradientTransformation:
     sched = make_lr_schedule(cfg)
     if cfg.optimizer == "adam":
@@ -167,8 +183,9 @@ def _build_optimizer(cfg: OptimConfig) -> optax.GradientTransformation:
         raise ValueError(f"unknown optimizer {cfg.optimizer!r}")
     parts = []
     if cfg.grad_clip_norm and cfg.grad_clip_norm > 0:
-        parts.append(optax.clip_by_global_norm(cfg.grad_clip_norm))
-    parts.append(core)
+        parts.append(_scoped("clip",
+                             optax.clip_by_global_norm(cfg.grad_clip_norm)))
+    parts.append(_scoped("optimizer", core))
     tx = optax.chain(*parts)
     if cfg.grad_accum_steps > 1:
         tx = optax.MultiSteps(tx, every_k_schedule=cfg.grad_accum_steps)

@@ -18,11 +18,10 @@ import optax
 from ..config import ClipConfig, TrainConfig
 from ..models.clip import CLIP, init_clip
 from ..obs import span
-from ..parallel import commit_to_mesh, shard_params
 from .base_trainer import BaseTrainer
 from .metrics import ThroughputMeter, count_params, transformer_train_flops
 from .train_state import (TrainState, cast_floating, compute_dtype,
-                          jit_step, make_optimizer)
+                          jit_step)
 
 
 @functools.lru_cache(maxsize=64)
@@ -34,8 +33,9 @@ def _clip_step_body(model: CLIP, dtype=None, health: bool = False,
     # (obs/health.py) into the program.
     def loss_fn(params, text, images):
         x = images if dtype is None else images.astype(dtype)
-        return model.apply(cast_floating(params, dtype), text, x,
-                           return_loss=True)
+        with jax.named_scope("forward"):
+            return model.apply(cast_floating(params, dtype), text, x,
+                               return_loss=True)
 
     def step(state: TrainState, text, images):
         loss, grads = jax.value_and_grad(loss_fn)(state.params, text, images)
@@ -73,22 +73,21 @@ def make_clip_train_multi_step(model: CLIP, dtype=None, health: bool = False,
 class CLIPTrainer(BaseTrainer):
     model_class = "CLIP"
 
+    @span("trainer/init")
     def __init__(self, model_cfg: ClipConfig, train_cfg: TrainConfig,
                  mesh=None, backend=None):
         super().__init__(train_cfg, mesh=mesh, backend=backend)
         self.model_cfg = model_cfg
-        self.model, params = init_clip(model_cfg, self.base_key)
-        params = shard_params(self.mesh, params)
-        tx = make_optimizer(train_cfg.optim)
-        self.state = commit_to_mesh(self.mesh, TrainState.create(
-            apply_fn=self.model.apply, params=params, tx=tx,
-            lr_scale=1.0 if train_cfg.runtime_lr_scale else None))
+        with span("init/model"):
+            self.model, params = init_clip(model_cfg, self.base_key)
+        self.state = self._create_state(params, self.model.apply)
         self._health_kw = dict(
             health=bool(train_cfg.obs.health),
             health_depth=train_cfg.obs.health_group_depth)
-        self.step_fn = make_clip_train_step(
-            self.model, dtype=compute_dtype(train_cfg.precision),
-            state=self.state, **self._health_kw)
+        with span("init/build_step"):
+            self.step_fn = make_clip_train_step(
+                self.model, dtype=compute_dtype(train_cfg.precision),
+                state=self.state, **self._health_kw)
         self._multi_step_fn = None   # built lazily on first train_steps()
         n = count_params(self.state.params)
         self.num_params = n

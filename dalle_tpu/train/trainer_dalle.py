@@ -27,11 +27,10 @@ import optax
 from ..config import DalleConfig, TrainConfig
 from ..models.dalle import DALLE, init_dalle
 from ..obs import span
-from ..parallel import commit_to_mesh, shard_params
 from .base_trainer import BaseTrainer
 from .metrics import ThroughputMeter, count_params, transformer_train_flops
 from .train_state import (TrainState, cast_floating, compute_dtype,
-                          jit_step, make_optimizer)
+                          jit_step)
 
 
 def _make_dalle_loss_fn(model: DALLE, *, null_cond_prob: float,
@@ -42,11 +41,14 @@ def _make_dalle_loss_fn(model: DALLE, *, null_cond_prob: float,
             rngs["cfg"] = jax.random.fold_in(key, 0)
         if use_dropout:
             rngs["dropout"] = jax.random.fold_in(key, 1)
-        loss, aux = model.apply(cast_floating(params, dtype), text, image_ids,
-                                return_loss=True,
-                                null_cond_prob=null_cond_prob,
-                                deterministic=not use_dropout,
-                                rngs=rngs or None)
+        # the model scopes its own "loss" inside; what jax.value_and_grad
+        # transposes from here shows as transpose(jvp(forward)) on the device
+        with jax.named_scope("forward"):
+            loss, aux = model.apply(cast_floating(params, dtype), text,
+                                    image_ids, return_loss=True,
+                                    null_cond_prob=null_cond_prob,
+                                    deterministic=not use_dropout,
+                                    rngs=rngs or None)
         return loss, aux
 
     return loss_fn
@@ -147,6 +149,7 @@ class DalleTrainer(BaseTrainer):
 
     model_class = "DALLE"
 
+    @span("trainer/init")
     def __init__(self, model_cfg: DalleConfig, train_cfg: TrainConfig,
                  mesh=None, backend=None, null_cond_prob: float = 0.0):
         super().__init__(train_cfg, mesh=mesh, backend=backend)
@@ -160,19 +163,19 @@ class DalleTrainer(BaseTrainer):
                 f"sequence parallelism (sp > 1) supports attn_types {sp_ok}; "
                 f"got unsupported {bad} (tabled 'sparse' masks need host-side "
                 "block lists the ring cannot shard)")
-        self.model, params = init_dalle(
-            model_cfg, self.base_key, sp_mesh=self.mesh if sp > 1 else None)
-        params = shard_params(self.mesh, params)
-        tx = make_optimizer(train_cfg.optim)
-        self.state = commit_to_mesh(self.mesh, TrainState.create(
-            apply_fn=self.model.apply, params=params, tx=tx,
-            lr_scale=1.0 if train_cfg.runtime_lr_scale else None))
+        with span("init/model"):
+            self.model, params = init_dalle(
+                model_cfg, self.base_key,
+                sp_mesh=self.mesh if sp > 1 else None)
+        self.state = self._create_state(params, self.model.apply)
         use_dropout = (model_cfg.attn_dropout > 0 or model_cfg.ff_dropout > 0)
-        self.step_fn = make_dalle_train_step(
-            self.model, null_cond_prob=null_cond_prob, use_dropout=use_dropout,
-            dtype=compute_dtype(train_cfg.precision), state=self.state,
-            health=bool(train_cfg.obs.health),
-            health_depth=train_cfg.obs.health_group_depth)
+        with span("init/build_step"):
+            self.step_fn = make_dalle_train_step(
+                self.model, null_cond_prob=null_cond_prob,
+                use_dropout=use_dropout,
+                dtype=compute_dtype(train_cfg.precision), state=self.state,
+                health=bool(train_cfg.obs.health),
+                health_depth=train_cfg.obs.health_group_depth)
         self._multi_step_kw = dict(null_cond_prob=null_cond_prob,
                                    use_dropout=use_dropout,
                                    dtype=compute_dtype(train_cfg.precision),

@@ -28,11 +28,10 @@ import optax
 from ..config import AnnealConfig, DVAEConfig, TrainConfig
 from ..models.dvae import DiscreteVAE, init_dvae
 from ..obs import span
-from ..parallel import commit_to_mesh, shard_params
 from .base_trainer import BaseTrainer
 from .metrics import ThroughputMeter, count_params
 from .train_state import (TrainState, cast_floating, compute_dtype,
-                          jit_step, make_optimizer)
+                          jit_step)
 
 
 def anneal_temperature(cfg: AnnealConfig, global_step: int) -> float:
@@ -52,9 +51,11 @@ def _vae_step_body(model: DiscreteVAE, dtype=None, health: bool = False,
     def loss_fn(params, images, key, temp):
         if dtype is not None:
             images = images.astype(dtype)
-        out = model.apply(
-            cast_floating(params, dtype), images, temp=temp, return_loss=True,
-            return_recons=True, return_health=health, rngs={"gumbel": key})
+        with jax.named_scope("forward"):
+            out = model.apply(
+                cast_floating(params, dtype), images, temp=temp,
+                return_loss=True, return_recons=True, return_health=health,
+                rngs={"gumbel": key})
         if health:
             loss, _recons, hm = out
             return loss, hm
@@ -112,6 +113,7 @@ def _codebook_counts(indices, num_tokens):
 class VAETrainer(BaseTrainer):
     model_class = "DiscreteVAE"
 
+    @span("trainer/init")
     def __init__(self, model_cfg: DVAEConfig, train_cfg: TrainConfig,
                  anneal_cfg: Optional[AnnealConfig] = None, mesh=None,
                  backend=None):
@@ -124,18 +126,16 @@ class VAETrainer(BaseTrainer):
         # a collapsed codebook; temp is a traced scalar so no recompile
         self._anneal_step0 = 0
 
-        self.model, params = init_dvae(model_cfg, self.base_key)
-        params = shard_params(self.mesh, params)
-        tx = make_optimizer(train_cfg.optim)
-        self.state = commit_to_mesh(self.mesh, TrainState.create(
-            apply_fn=self.model.apply, params=params, tx=tx,
-            lr_scale=1.0 if train_cfg.runtime_lr_scale else None))
+        with span("init/model"):
+            self.model, params = init_dvae(model_cfg, self.base_key)
+        self.state = self._create_state(params, self.model.apply)
         self._health_kw = dict(
             health=bool(train_cfg.obs.health),
             health_depth=train_cfg.obs.health_group_depth)
-        self.step_fn = make_vae_train_step(
-            self.model, dtype=compute_dtype(train_cfg.precision),
-            state=self.state, **self._health_kw)
+        with span("init/build_step"):
+            self.step_fn = make_vae_train_step(
+                self.model, dtype=compute_dtype(train_cfg.precision),
+                state=self.state, **self._health_kw)
         self._multi_step_fn = None   # built lazily on first train_steps()
 
         n = count_params(self.state.params)

@@ -121,10 +121,11 @@ def make_vqgan_train_step(model: VQModel, disc: NLayerDiscriminator,
         rngs = {"gumbel": key, "dropout": jax.random.fold_in(key, 1)}
         gen_c = cast_floating(gen_params, dtype)
         images_c = images if dtype is None else images.astype(dtype)
-        q = model.apply(gen_c, images_c, temp=temp, deterministic=False,
-                        method=VQModel.encode, rngs=rngs)
-        recon, h_last = model.apply(gen_c, q.quantized, False, True,
-                                    method=VQModel.decode, rngs=rngs)
+        with jax.named_scope("forward"):
+            q = model.apply(gen_c, images_c, temp=temp, deterministic=False,
+                            method=VQModel.encode, rngs=rngs)
+            recon, h_last = model.apply(gen_c, q.quantized, False, True,
+                                        method=VQModel.decode, rngs=rngs)
 
         def nll_of(r):
             # loss reductions in f32 regardless of the compute dtype
@@ -140,13 +141,15 @@ def make_vqgan_train_step(model: VQModel, disc: NLayerDiscriminator,
                 train=True, mutable=["batch_stats"])
             return -jnp.mean(logits_fake)
 
-        nll = nll_of(recon)
-        g_loss = g_of(recon)
-        conv_out = gen_c["params"]["decoder"]["conv_out"]
-        d_weight = adaptive_disc_weight(nll_of, g_of, h_last, conv_out,
-                                        lc.disc_weight)
-        disc_factor = adopt_weight(lc.disc_factor, step, lc.disc_start)
-        loss = nll + d_weight * disc_factor * g_loss + lc.codebook_weight * q.loss
+        with jax.named_scope("loss"):
+            nll = nll_of(recon)
+            g_loss = g_of(recon)
+            conv_out = gen_c["params"]["decoder"]["conv_out"]
+            d_weight = adaptive_disc_weight(nll_of, g_of, h_last, conv_out,
+                                            lc.disc_weight)
+            disc_factor = adopt_weight(lc.disc_factor, step, lc.disc_start)
+            loss = (nll + d_weight * disc_factor * g_loss
+                    + lc.codebook_weight * q.loss)
         aux = {"recon": recon, "nll_loss": nll, "g_loss": g_loss,
                "quant_loss": q.loss, "d_weight": d_weight,
                "disc_factor": disc_factor}
@@ -157,13 +160,16 @@ def make_vqgan_train_step(model: VQModel, disc: NLayerDiscriminator,
 
     def disc_loss_fn(disc_params, batch_stats, images, recon, step):
         variables = {"params": disc_params, "batch_stats": batch_stats}
-        logits_real, vars1 = disc.apply(variables, images, train=True,
-                                        mutable=["batch_stats"])
-        logits_fake, vars2 = disc.apply(
-            {"params": disc_params, "batch_stats": vars1["batch_stats"]},
-            jax.lax.stop_gradient(recon), train=True, mutable=["batch_stats"])
-        disc_factor = adopt_weight(lc.disc_factor, step, lc.disc_start)
-        d_loss = disc_factor * d_loss_fn(logits_real, logits_fake)
+        with jax.named_scope("forward"):
+            logits_real, vars1 = disc.apply(variables, images, train=True,
+                                            mutable=["batch_stats"])
+            logits_fake, vars2 = disc.apply(
+                {"params": disc_params, "batch_stats": vars1["batch_stats"]},
+                jax.lax.stop_gradient(recon), train=True,
+                mutable=["batch_stats"])
+        with jax.named_scope("loss"):
+            disc_factor = adopt_weight(lc.disc_factor, step, lc.disc_start)
+            d_loss = disc_factor * d_loss_fn(logits_real, logits_fake)
         aux = {"batch_stats": vars2["batch_stats"],
                "logits_real": jnp.mean(logits_real),
                "logits_fake": jnp.mean(logits_fake)}
@@ -178,14 +184,17 @@ def make_vqgan_train_step(model: VQModel, disc: NLayerDiscriminator,
             temp, state.step)
         gen_updates, gen_opt = state.gen_tx.update(
             gen_grads, state.opt_state["gen"], gen_p, value=ae_loss)
-        gen_p = optax.apply_updates(gen_p, gen_updates)
+        with jax.named_scope("optimizer"):
+            gen_p = optax.apply_updates(gen_p, gen_updates)
         # --- optimizer_idx 1: discriminator -------------------------------
         (d_loss, d_aux), disc_grads = jax.value_and_grad(
             disc_loss_fn, has_aux=True)(disc_p["params"], state.batch_stats,
                                         images, aux["recon"], state.step)
         disc_updates, disc_opt = state.disc_tx.update(
             disc_grads, state.opt_state["disc"], disc_p["params"], value=d_loss)
-        disc_p = {"params": optax.apply_updates(disc_p["params"], disc_updates)}
+        with jax.named_scope("optimizer"):
+            disc_p = {"params": optax.apply_updates(disc_p["params"],
+                                                    disc_updates)}
         state = state.replace(
             step=state.step + 1,
             params={"gen": gen_p, "disc": disc_p, "lpips": lpips_p},
@@ -228,8 +237,9 @@ def make_vq_simple_train_step(model: VQModel, loss_cfg: GANLossConfig,
         rngs = {"gumbel": key, "dropout": jax.random.fold_in(key, 1)}
         p = cast_floating(params, dtype)
         x = images if dtype is None else images.astype(dtype)
-        recon, qloss, indices = model.apply(p, x, temp=temp,
-                                            deterministic=False, rngs=rngs)
+        with jax.named_scope("forward"):
+            recon, qloss, indices = model.apply(p, x, temp=temp,
+                                                deterministic=False, rngs=rngs)
         recon32 = recon.astype(jnp.float32)
         hm = {}
         if health:
@@ -266,6 +276,7 @@ def make_vq_simple_train_step(model: VQModel, loss_cfg: GANLossConfig,
 class VQGANTrainer(BaseTrainer):
     model_class = "VQModel"
 
+    @span("trainer/init")
     def __init__(self, model_cfg: VQGANConfig, train_cfg: TrainConfig,
                  loss_cfg: Optional[GANLossConfig] = None, mesh=None,
                  backend=None, disc_optim=None,
@@ -283,67 +294,72 @@ class VQGANTrainer(BaseTrainer):
             health=bool(train_cfg.obs.health),
             health_depth=train_cfg.obs.health_group_depth)
 
-        self.model, gen_params = init_vqgan(model_cfg, self.base_key)
+        with span("init/model"):
+            self.model, gen_params = init_vqgan(model_cfg, self.base_key)
         if loss_mode != "gan":
-            gen_params = shard_params(self.mesh, gen_params)
-            tx = make_optimizer(train_cfg.optim)
-            self.state = commit_to_mesh(self.mesh, TrainState.create(
-                apply_fn=self.model.apply, params=gen_params, tx=tx,
-                lr_scale=1.0 if train_cfg.runtime_lr_scale else None))
-            self.step_fn = make_vq_simple_train_step(
-                self.model, self.loss_cfg, loss_mode,
-                dtype=compute_dtype(train_cfg.precision), state=self.state,
-                **self._health_kw)
+            self.state = self._create_state(gen_params, self.model.apply)
+            with span("init/build_step"):
+                self.step_fn = make_vq_simple_train_step(
+                    self.model, self.loss_cfg, loss_mode,
+                    dtype=compute_dtype(train_cfg.precision),
+                    state=self.state, **self._health_kw)
             self.disc = self.lpips = None
             self._finish_init(temp_scheduler)
             return
-        self.disc = NLayerDiscriminator(ndf=self.loss_cfg.disc_ndf,
-                                        n_layers=self.loss_cfg.disc_num_layers,
-                                        use_actnorm=self.loss_cfg.use_actnorm)
-        disc_vars = self.disc.init(
-            jax.random.fold_in(self.base_key, 1),
-            jnp.zeros((2, model_cfg.resolution, model_cfg.resolution,
-                       model_cfg.in_channels), jnp.float32), train=True)
-        batch_stats = disc_vars.get("batch_stats", {})
-        if self.loss_cfg.perceptual_weight > 0:
-            if self.loss_cfg.perceptual_net == "tiny":
-                # the shipped in-repo perceptual weights (real metric, no
-                # egress needed — scripts/train_perceptual.py)
-                from ..models.lpips import load_tiny_perceptual
-                try:
-                    self.lpips, lpips_params = load_tiny_perceptual()
-                except FileNotFoundError:
-                    import warnings
-                    warnings.warn("tiny_perceptual.npz missing — perceptual "
-                                  "loss falls back to a random-init net")
+        with span("init/model"):   # the discriminator and LPIPS
+            self.disc = NLayerDiscriminator(ndf=self.loss_cfg.disc_ndf,
+                                            n_layers=self.loss_cfg.disc_num_layers,
+                                            use_actnorm=self.loss_cfg.use_actnorm)
+            disc_vars = self.disc.init(
+                jax.random.fold_in(self.base_key, 1),
+                jnp.zeros((2, model_cfg.resolution, model_cfg.resolution,
+                           model_cfg.in_channels), jnp.float32), train=True)
+            batch_stats = disc_vars.get("batch_stats", {})
+            if self.loss_cfg.perceptual_weight > 0:
+                if self.loss_cfg.perceptual_net == "tiny":
+                    # the shipped in-repo perceptual weights (real metric, no
+                    # egress needed — scripts/train_perceptual.py)
+                    from ..models.lpips import load_tiny_perceptual
+                    try:
+                        self.lpips, lpips_params = load_tiny_perceptual()
+                    except FileNotFoundError:
+                        import warnings
+                        warnings.warn("tiny_perceptual.npz missing — perceptual "
+                                      "loss falls back to a random-init net")
+                        self.lpips, lpips_params = init_lpips(
+                            jax.random.fold_in(self.base_key, 2),
+                            model_cfg.resolution)
+                else:
+                    # torchvision-shaped trunk; import real weights via
+                    # models.lpips.load_torch_weights when vgg.pth is on disk
                     self.lpips, lpips_params = init_lpips(
-                        jax.random.fold_in(self.base_key, 2),
-                        model_cfg.resolution)
+                        jax.random.fold_in(self.base_key, 2), model_cfg.resolution)
             else:
-                # torchvision-shaped trunk; import real weights via
-                # models.lpips.load_torch_weights when vgg.pth is on disk
-                self.lpips, lpips_params = init_lpips(
-                    jax.random.fold_in(self.base_key, 2), model_cfg.resolution)
-        else:
-            self.lpips, lpips_params = None, {}
+                self.lpips, lpips_params = None, {}
 
-        gen_params = shard_params(self.mesh, gen_params)
-        disc_params = shard_params(self.mesh, {"params": disc_vars["params"]})
-        lpips_params = shard_params(self.mesh, lpips_params)
+        with span("init/commit_to_mesh"):
+            gen_params = shard_params(self.mesh, gen_params)
+            disc_params = shard_params(self.mesh,
+                                       {"params": disc_vars["params"]})
+            lpips_params = shard_params(self.mesh, lpips_params)
 
         # taming configure_optimizers: both Adam(lr, betas=(0.5, 0.9))
         # (taming/models/vqgan.py:121-131)
-        gen_tx = make_optimizer(train_cfg.optim)
-        self.disc_optim = disc_optim or train_cfg.optim
-        disc_tx = make_optimizer(self.disc_optim)
-        self.state = commit_to_mesh(self.mesh, GANTrainState.create(
-            gen_params=gen_params, disc_params=disc_params,
-            lpips_params=lpips_params, batch_stats=batch_stats,
-            gen_tx=gen_tx, disc_tx=disc_tx))
-        self.step_fn = make_vqgan_train_step(
-            self.model, self.disc, self.lpips, self.loss_cfg,
-            dtype=compute_dtype(train_cfg.precision), state=self.state,
-            **self._health_kw)
+        with span("init/optimizer"):
+            gen_tx = make_optimizer(train_cfg.optim)
+            self.disc_optim = disc_optim or train_cfg.optim
+            disc_tx = make_optimizer(self.disc_optim)
+            state = GANTrainState.create(
+                gen_params=gen_params, disc_params=disc_params,
+                lpips_params=lpips_params, batch_stats=batch_stats,
+                gen_tx=gen_tx, disc_tx=disc_tx)
+        with span("init/commit_to_mesh"):
+            self.state = commit_to_mesh(self.mesh, state)
+        with span("init/build_step"):
+            self.step_fn = make_vqgan_train_step(
+                self.model, self.disc, self.lpips, self.loss_cfg,
+                dtype=compute_dtype(train_cfg.precision), state=self.state,
+                **self._health_kw)
         self._finish_init(temp_scheduler)
 
     def _finish_init(self, temp_scheduler):

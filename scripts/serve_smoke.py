@@ -128,7 +128,7 @@ def main(argv=None):
 
     spans = tracer.snapshot_spans()
     by_name = {}
-    for name, rel, dur, tid, depth, sargs in spans:
+    for name, rel, dur, tid, depth, sargs, *_ in spans:
         by_name.setdefault(name, []).append((dur, sargs))
     for want in ("serve/request", "serve/request_ttft"):
         rows = by_name.get(want, [])

@@ -314,17 +314,118 @@ def test_fit_exports_trace_with_nested_spans(tmp_path):
     tr.fit(_batches(3), log=lambda *a: None)
     doc = json.load(open(outdir / "trace.json"))
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"fit/step", "fit/batch_wait", "fit/dispatch",
-            "fit/sync"} <= names
-    # fit/dispatch nests inside its fit/step window
-    steps = [(e["ts"], e["ts"] + e["dur"]) for e in doc["traceEvents"]
-             if e["name"] == "fit/step"]
+    assert {"fit/warmup", "fit/step", "fit/batch_wait", "fit/dispatch",
+            "fit/sync", "fit/after_step"} <= names
+    # the phases are children of their fit/step, in time and by parent id
+    steps = {e["args"]["id"]: (e["ts"], e["ts"] + e["dur"])
+             for e in doc["traceEvents"] if e["name"] == "fit/step"}
     for e in doc["traceEvents"]:
-        if e["name"] == "fit/dispatch":
-            assert any(lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1
-                       for lo, hi in steps)
+        if e["name"] in ("fit/batch_wait", "fit/dispatch", "fit/sync",
+                         "fit/after_step"):
+            lo, hi = steps[e["args"]["parent"]]
+            assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1
     rows = [json.loads(l) for l in open(outdir / "spans.jsonl")]
     assert any(r["name"] == "fit/sync" for r in rows)
+    # fit/warmup: from fit()'s entry to the end of the first step's sync,
+    # closed before that step's fit/after_step opens
+    by_name = {}
+    for r in rows:
+        by_name.setdefault(r["name"], []).append(r)
+    warm, = by_name["fit/warmup"]
+    first_sync = min(by_name["fit/sync"], key=lambda r: r["rel_s"])
+    first_after = min(by_name["fit/after_step"], key=lambda r: r["rel_s"])
+    assert warm["rel_s"] <= by_name["fit/step"][0]["rel_s"]
+    warm_end = warm["rel_s"] + warm["dur_s"]
+    assert first_sync["rel_s"] + first_sync["dur_s"] <= warm_end
+    assert warm_end <= first_after["rel_s"]
+
+
+@pytest.mark.parametrize("defer", [False, True], ids=["in_band", "defer"])
+def test_fit_phases_tile_the_step_and_feed_the_record(tmp_path, defer):
+    """Over a 5-step fit(): fit/dispatch and fit/sync are siblings that never
+    overlap; per ``step`` id the phase spans add up to their fit/step span
+    within 1 %; and each record's t_* columns ARE the durations of the spans
+    of the step it describes (t_after_s: of the iteration before it)."""
+    outdir = tmp_path / "obs"
+    tc = _tc(tmp_path, device_prefetch=0, defer_metrics=defer,
+             obs=ObsConfig(trace=True, trace_dir=str(outdir)))
+    tr = FakeTrainer(tc, step_sleep=0.02)
+    writer = RecordingWriter()
+    tr.fit(_batches(5), log=lambda *a: None, metrics_writer=writer)
+    rows = [json.loads(l) for l in open(outdir / "spans.jsonl")]
+    phases = ("fit/batch_wait", "fit/dispatch", "fit/sync", "fit/after_step")
+    by_step = {}
+    for r in rows:
+        # (under defer the last record's flush runs after the loop: a
+        # fit/sync with no step)
+        if (r["name"] in phases or r["name"] == "fit/step") and "args" in r:
+            by_step.setdefault(r["args"]["step"], {}).setdefault(
+                r["name"], []).append(r)
+    # the sixth iteration only finds the iterator at its end
+    assert sorted(by_step.pop(5)) == ["fit/batch_wait", "fit/step"]
+    assert sorted(by_step) == [0, 1, 2, 3, 4]
+    for step, spans in by_step.items():
+        whole, = spans["fit/step"]
+        parts = [r for name in phases for r in spans.get(name, [])]
+        assert all(r["parent"] == whole["id"] for r in parts)
+        assert sum(r["dur_s"] for r in parts) == pytest.approx(
+            whole["dur_s"], rel=0.01)
+        parts.sort(key=lambda r: r["rel_s"])      # siblings never overlap
+        for a, b in zip(parts, parts[1:]):
+            assert a["rel_s"] + a["dur_s"] <= b["rel_s"]
+    assert len(writer.records) == 5
+    for mstep, m in writer.records:
+        spans = by_step[mstep - 1]                # the step it describes
+        assert m["t_batch_wait_s"] == spans["fit/batch_wait"][0]["dur_s"]
+        assert m["t_dispatch_s"] == spans["fit/dispatch"][0]["dur_s"]
+        assert m["t_dispatch_s"] >= 0.02          # the step, not the sync
+        # the sync that fetched it: its own step's, or under defer the next
+        # iteration's (the flush of the last record runs after the loop)
+        syncs = [r["dur_s"] for r in rows if r["name"] == "fit/sync"]
+        assert m["t_sync_s"] in syncs
+        if mstep >= 2:
+            before = by_step[mstep - 2]["fit/after_step"]
+            assert m["t_after_s"] == pytest.approx(
+                sum(r["dur_s"] for r in before))
+        else:
+            assert "t_after_s" not in m
+    if not defer:
+        for mstep, m in writer.records:
+            sync, = by_step[mstep - 1]["fit/sync"]
+            assert m["t_sync_s"] == sync["dur_s"]
+
+
+def test_fit_phase_durations_need_no_ring(tmp_path):
+    """The record's t_* columns and the totals with tracing off."""
+    obs.disable()
+    obs.reset_phase_totals()
+    tr = FakeTrainer(_tc(tmp_path, device_prefetch=0), step_sleep=0.01)
+    writer = RecordingWriter()
+    tr.fit(_batches(3), log=lambda *a: None, metrics_writer=writer)
+    assert not obs.enabled()
+    assert all(m["t_dispatch_s"] >= 0.01 and m["t_sync_s"] >= 0
+               for _, m in writer.records)
+    totals = obs.phase_totals()
+    assert totals["fit/dispatch"][0] == 3 and totals["fit/warmup"][0] == 1
+    assert totals["fit/step"][0] == 4              # 3 steps + the end
+    assert sum(m["t_dispatch_s"] for _, m in writer.records) == pytest.approx(
+        totals["fit/dispatch"][1])
+    assert tr._fit_phase is None and tr._fit_warmup is None
+    obs.reset_phase_totals()
+
+
+def test_fit_closes_its_spans_when_a_step_raises(tmp_path):
+    obs.disable()
+    tc = _tc(tmp_path, device_prefetch=0)
+
+    def boom(step):
+        raise RuntimeError("step failed")
+
+    tr = FakeTrainer(tc, step_metrics=boom)
+    with pytest.raises(RuntimeError):
+        tr.fit(_batches(2), log=lambda *a: None)
+    assert obs.open_spans() == {}
+    assert tr._fit_phase is None and tr._fit_warmup is None
 
 
 def test_fit_watchdog_fires_on_stalled_step(tmp_path):

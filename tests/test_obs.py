@@ -27,12 +27,100 @@ def tracer():
 
 # -- span core --------------------------------------------------------------
 
-def test_span_disabled_is_noop():
+def test_span_times_itself_with_the_ring_off():
+    """One measurement, whatever is switched on: ``duration`` and the
+    process-wide totals need no ring; the ring-only surfaces stay empty."""
     obs.disable()
+    obs.reset_phase_totals()
     with obs.span("x") as sp:
+        time.sleep(0.002)
+    with obs.span("x"):
         pass
-    assert sp.duration is None
+    assert sp.duration >= 0.002
+    count, seconds = obs.phase_totals()["x"]
+    assert count == 2 and seconds >= sp.duration
     assert obs.metrics_snapshot() == {}
+    assert obs.open_spans() == {}
+    obs.reset_phase_totals()
+    assert obs.phase_totals() == {}
+
+
+def test_phase_totals_are_exact_across_threads():
+    """Per-thread cells merged at read: no update is lost, and a thread
+    that has ended keeps its share."""
+    obs.disable()
+    obs.reset_phase_totals()
+
+    def worker():
+        for _ in range(500):
+            with obs.span("t/work"):
+                pass
+
+    threads = [threading.Thread(target=worker) for _ in range(4)]
+    for t in threads:
+        t.start()
+    worker()
+    for t in threads:
+        t.join()
+    assert obs.phase_totals()["t/work"][0] == 2500
+    obs.reset_phase_totals()
+
+
+def test_record_span_stays_ring_only(tracer):
+    obs.reset_phase_totals()
+    obs.record_span("serve/request", time.perf_counter(), 0.5)
+    assert "serve/request" not in obs.phase_totals()
+    assert [r[0] for r in tracer.spans] == ["serve/request"]
+
+
+def test_span_id_and_parent(tracer):
+    """Every record carries its own id and the id of the span that was open
+    beneath it on its thread; depth 0 and record_span have no parent."""
+    with obs.span("outer") as outer:
+        with obs.span("inner") as inner:
+            pass
+        with obs.span("inner2") as inner2:
+            pass
+    obs.record_span("late", time.perf_counter(), 0.0)
+    rows = {r[0]: r for r in tracer.spans}
+    assert rows["outer"][6] == outer.id and rows["outer"][7] is None
+    assert rows["inner"][6] == inner.id and rows["inner"][7] == outer.id
+    assert rows["inner2"][7] == outer.id and rows["late"][7] is None
+    assert len({r[6] for r in tracer.spans}) == 4
+
+
+def test_span_closed_out_of_order_leaves_the_stack_sound(tracer):
+    """fit/warmup opens before the first fit/step and ends inside it."""
+    warm = obs.span("warm").__enter__()
+    with obs.span("step"):
+        with obs.span("phase"):
+            pass
+        warm.__exit__(None, None, None)
+        assert list(obs.open_spans().values()) == [["step"]]
+        with obs.span("phase2"):
+            pass
+    assert obs.open_spans() == {}
+    rows = {r[0]: r for r in tracer.spans}
+    assert rows["step"][7] == warm.id and rows["phase2"][4] == 1
+
+
+def test_obs_imports_and_spans_without_jax():
+    """data/loaders.py and webdataset.py time themselves in processes that
+    never load jax: the profiler's annotation class is installed by
+    obs/device.py, not imported by obs/trace.py."""
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "from dalle_tpu import obs\n"
+            "from dalle_tpu.obs.trace import span\n"
+            "from dalle_tpu.data import device_prefetch\n"
+            "with span('x') as sp: pass\n"
+            "assert sp.duration is not None and obs.phase_totals()['x'][0] == 1\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, cwd=os.path.dirname(os.path.dirname(
+                              os.path.abspath(__file__))))
+    assert done.returncode == 0, done.stderr
 
 
 def test_span_nesting_depth_and_order(tracer):
@@ -155,7 +243,9 @@ def test_chrome_trace_export(tmp_path, tracer):
     assert ev["parent"]["ts"] <= ev["child"]["ts"]
     assert (ev["child"]["ts"] + ev["child"]["dur"]
             <= ev["parent"]["ts"] + ev["parent"]["dur"] + 1)
-    assert ev["parent"]["args"] == {"step": 1}
+    assert ev["parent"]["args"] == {"step": 1, "id": ev["parent"]["args"]["id"],
+                                    "parent": None}
+    assert ev["child"]["args"]["parent"] == ev["parent"]["args"]["id"]
 
 
 def test_spans_jsonl_export_and_report(tmp_path, tracer):
@@ -166,6 +256,8 @@ def test_spans_jsonl_export_and_report(tmp_path, tracer):
     assert obs.export_spans_jsonl(path) == 3
     rows = obs_report.load_jsonl(path)
     assert all(r["name"] == "work" and "dur_s" in r for r in rows)
+    assert len({r["id"] for r in rows}) == 3
+    assert all(r["parent"] is None and "i" in r["args"] for r in rows)
     agg = obs_report.span_aggregate(rows)
     assert agg[0]["name"] == "work" and agg[0]["count"] == 3
     text = obs_report.summarize_run(path)

@@ -432,7 +432,7 @@ def test_engine_spans_and_gauges(model_params):
         done = eng.run(q)
         spans = tracer.snapshot_spans()
         by_name = {}
-        for name, rel, dur, tid, depth, args in spans:
+        for name, rel, dur, tid, depth, args, *_ in spans:
             by_name.setdefault(name, []).append((dur, args))
         for want in ("serve/request", "serve/request_ttft",
                      "serve/request_queue_wait"):
@@ -690,7 +690,7 @@ def test_engine_chunked_prefill_exact_and_interleaves(model_params):
     tracer = obs.configure()
     try:
         done, eng = run(3)
-        chunk_spans = [args for name, _r, _d, _t, _dep, args
+        chunk_spans = [args for name, _r, _d, _t, _dep, args, *_
                        in tracer.snapshot_spans()
                        if name == "serve/prefill_chunk"]
     finally:
@@ -762,7 +762,7 @@ def test_engine_decode_health_exact_with_quality_telemetry(model_params):
         for c in tapped:
             np.testing.assert_array_equal(c.tokens, refs[c.request_id])
             np.testing.assert_array_equal(c.tokens, plain[c.request_id])
-        qspans = [args for name, _r, _d, _t, _dep, args
+        qspans = [args for name, _r, _d, _t, _dep, args, *_
                   in tracer.snapshot_spans() if name == "serve/request"]
         assert len(qspans) == 3
         for args in qspans:
