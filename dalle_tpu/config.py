@@ -488,14 +488,6 @@ class TrainConfig(ConfigBase):
     # their target shardings, so batch-wait + H2D leave the device critical
     # path. 0 disables (fit pulls and puts inline, the pre-PR3 behavior)
     device_prefetch: int = 2
-    # fetch step metrics one metrics-boundary late so the device_get lands
-    # after the NEXT dispatch (the sync then reads an already-finished step
-    # instead of blocking on the running one). Costs: the loss column lags
-    # one boundary (records carry their true step via ``metrics_step``) and
-    # NaN rollback triggers one boundary late on non-save steps — save
-    # boundaries still force a synchronous fetch of the current step, so
-    # nothing is ever checkpointed without a NaN check
-    defer_metrics: bool = False
     preflight_checkpoint: bool = True    # ref: legacy/train_dalle.py:591-594
     sample_every_steps: int = 0
     profile_step: int = 0                # >0 → dump a jax.profiler trace + MFU report

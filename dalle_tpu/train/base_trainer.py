@@ -16,8 +16,8 @@ import numpy as np
 
 from ..chaos import step_hook as _chaos_step_hook
 from ..config import TrainConfig
-from ..obs import (DeviceTelemetry, StallWatchdog, export_chrome_trace,
-                   export_spans_jsonl, span)
+from ..obs import (DeviceTelemetry, StallWatchdog, counter_add,
+                   export_chrome_trace, export_spans_jsonl, span)
 from ..obs import configure as obs_configure
 from .checkpoints import CheckpointManager
 
@@ -53,7 +53,16 @@ class BaseTrainer:
     # test suite's host-only FakeTrainer) still satisfy the fit()/breakdown
     # machinery added after them
     _last_good_device = None
-    _deferred_metrics = None
+    # what fit() has not fetched yet: the newest metrics boundary as (step,
+    # device metrics, stamp, breakdown), read once the next step is
+    # dispatched (_finish_step), and the newest step's (device metrics,
+    # stamp), for a save that lands between two boundaries
+    _parked = None
+    _pending_metrics = None
+    # metrics fetches since fit()'s entry: of a step already dispatched
+    # over, and of the newest step (the host then waits for the device)
+    _fetched_late = 0
+    _fetched_in_band = 0
     # the open span of fit()'s current phase (None outside fit: a bare
     # train_step gets no breakdown), the id its iteration's spans share,
     # and fit/warmup while it is open
@@ -97,7 +106,6 @@ class BaseTrainer:
         self._obs_window_t0 = None
         self._obs_poll_bucket = -1
         self._telemetry = None
-        self._deferred_metrics = None   # (step, device metrics) under defer
         self.last_watchdog = None
         # per-instance extras merged into checkpoint metadata, e.g. vae
         # identity for DALLE ckpts (reference legacy/train_dalle.py:535-582)
@@ -151,28 +159,22 @@ class BaseTrainer:
         self._signal_save = getattr(self, "_signal_save", False)
         signal.signal(signal.SIGTERM, handler)
 
-    def _fetch_pending_metrics(self) -> dict:
-        """Host-fetch the most recent step's device metrics (used when a save
-        boundary lands on a metrics-skipped step, or to bypass the
-        ``defer_metrics`` lag: nothing may be checkpointed without a NaN
-        check of the CURRENT state)."""
-        if getattr(self, "_pending_metrics", None) is None:
-            return {}
-        # the same step's metrics are now consumed in-band — retire the
-        # deferred copy so the next boundary doesn't re-emit them, but keep
-        # its parked breakdown: dropping it would lose every t_* column
-        # (and the once-consumed t_ckpt_s) whenever the save cadence
-        # coincides with the metrics cadence
-        part = None
-        if (self._deferred_metrics is not None
-                and self._deferred_metrics[0] == self._host_step):
-            part = self._deferred_metrics[2]
-            self._deferred_metrics = None
-        metrics = self._fetch(self._pending_metrics, part)
-        rep = self.meter.step(self._host_step)
-        if rep:
-            metrics.update(rep)
-        return self._health_observe(self._host_step, metrics)
+    def _fetch_parked(self, current: bool) -> list:
+        """What fit() still owes its writer, as (step, record) pairs, oldest
+        first: the parked boundary, and with ``current`` (a save: nothing is
+        checkpointed without a NaN check of the state saved) the newest
+        step's metrics where ``metrics_every`` skipped it. A fetch of the
+        newest step is in band: the host waits for the device."""
+        out = []
+        parked, self._parked = self._parked, None
+        if parked is not None:
+            out.append((parked[0], self._record(*parked)))
+        step = self._host_step
+        if (current and self._pending_metrics is not None
+                and (parked is None or parked[0] != step)):
+            out.append((step, self._record(step, *self._pending_metrics,
+                                           None)))
+        return out
 
     def _phase(self, name: Optional[str]):
         """Move fit()'s iteration on to the phase ``name``: close the open
@@ -216,24 +218,42 @@ class BaseTrainer:
                 # phase this describes
                 self._fit_late["t_after_s"] = self._fit_after
 
-    def _fetch(self, device_metrics, part: Optional[dict] = None) -> dict:
-        """Every host fetch of a step's metrics, in-band, on-demand and
-        flush: ``jax.device_get`` under ``fit/sync``. Inside fit() that is a
-        phase (a sibling of ``fit/dispatch``, followed by
-        ``fit/after_step``). ``part`` is the parked breakdown of the step the
-        metrics describe: the span's seconds become its ``t_sync_s``."""
+    def _record(self, step: int, device_metrics, stamp: Optional[dict],
+                part: Optional[dict]) -> dict:
+        """The finished record of ``step``, through the one host fetch of a
+        step's metrics: ``jax.device_get`` under ``fit/sync``. Inside fit()
+        that is a phase (a sibling of ``fit/dispatch``, followed by
+        ``fit/after_step``). The fetch is late when a later step has been
+        dispatched over ``step``, so the device stays busy through it;
+        otherwise it is in band and the host waits for the newest step (a
+        bare step, a save boundary, fit()'s exit). Joined to the metrics:
+        the columns the host knew when the step ran (``stamp``; under fit()
+        the parked breakdown ``part``, to which the span's seconds add
+        ``t_sync_s``), the throughput report, the health sentry's verdict."""
+        late = step < self._host_step
         after = "fit/after_step" if self._fit_phase is not None else None
         self._phase("fit/sync")
-        sync = self._fit_phase
+        sync = self._fit_phase.set(late=late)
         try:
             metrics = {k: float(v)
                        for k, v in jax.device_get(device_metrics).items()}
         finally:
             self._phase(after)
+        if late:
+            self._fetched_late += 1
+            counter_add("fit.fetch_late")
+        else:
+            self._fetched_in_band += 1
+            counter_add("fit.fetch_in_band")
         if part is not None:
             part["t_sync_s"] = sync.duration
-            metrics.update(self._finish_breakdown(part))
-        return metrics
+            metrics.update(self._finish_breakdown(part, step))
+        if stamp:
+            metrics.update(stamp)
+        rep = self.meter.step(self._host_step)
+        if rep:
+            metrics.update(rep)
+        return self._health_observe(step, metrics)
 
     def _create_state(self, params, apply_fn):
         """The single-optimizer trainers' state from freshly initialised
@@ -254,7 +274,7 @@ class BaseTrainer:
     def _health_observe(self, step: int, metrics: dict) -> dict:
         """Run the graftpulse sentry over one FETCHED metrics dict (host
         floats) exactly once per metrics step — every path that finalizes a
-        record (in-band, deferred-consumed, save-boundary fetch, flushes)
+        record (``_record``: late, in band at a save boundary, the exit flush)
         routes through here. Mutates ``metrics`` with breach columns."""
         sentry = self.health_sentry
         if sentry is None or not metrics or step == self._health_last_step:
@@ -358,11 +378,25 @@ class BaseTrainer:
         calls should pass ``device_prefetch=0``); with
         ``train_cfg.async_checkpointing`` a mid-run save
         costs one device→host snapshot (the write overlaps following steps;
-        SIGUSR1-latch saves and fit exit drain); with
-        ``train_cfg.defer_metrics`` the metrics device_get reads the
-        previous boundary's already-finished step (save boundaries still
-        force a synchronous fetch — nothing is checkpointed without a NaN
-        check of the current state).
+        SIGUSR1-latch saves and fit exit drain).
+
+        The metrics fetch is one boundary late, always: step N's loss is
+        read once step N+1 has been dispatched (``_finish_step``), so the
+        host's dispatch overlaps the running step and the device does not
+        wait for it; the host runs one step ahead and no further. What
+        holds regardless: every record reaches ``metrics_writer`` once,
+        under its true step, in increasing order, the last one by a flush
+        at exit; a save boundary (cadence, SIGUSR1, SIGTERM) first fetches
+        the CURRENT step in band, so nothing is checkpointed or taken as
+        the rollback snapshot without a NaN check of the state saved; a NaN
+        read one step late rolls back as ever and drops the record of the
+        step that ran on the poisoned state; the exit flush is NaN-checked
+        too, so with ``nan_rollback`` fit() never returns a state whose
+        last step went NaN. ``on_step(N)``, ``sample_fn``, the watchdog
+        beat and the health sentry's actions run when step N is dispatched
+        and N-1 is known good; reading ``trainer.state`` in them waits for
+        N. A bare ``train_step()`` outside fit() returns its own step's
+        metrics.
 
         grafttrace (``train_cfg.obs``, docs/OBSERVABILITY.md): every
         iteration is a ``fit/step`` span whose four phases are siblings that
@@ -373,8 +407,12 @@ class BaseTrainer:
         ``fit/checkpoint`` inside it, the sampling hook). The spans'
         durations are the record's ``t_batch_wait_s`` / ``t_dispatch_s`` /
         ``t_sync_s`` / ``t_after_s`` columns, beside a data-starvation
-        ratio; ``fit/warmup`` runs from fit()'s entry to the end of the
-        first iteration's ``fit/sync``. With
+        ratio; ``fit/sync`` carries ``late`` and the counters
+        ``fit.fetch_late`` / ``fit.fetch_in_band`` say how often the fetch
+        found a later step queued (fit() logs both at exit); ``fit/warmup``
+        runs from fit()'s entry to the return of the first step's dispatch
+        (its program loaded or compiled; with a save at step 1, to the end
+        of that step). With
         ``obs.watchdog_deadline_s > 0`` a heartbeat watchdog reports stalls
         (open spans + thread stacks) instead of hanging silently; with
         ``obs.trace`` the span ring is exported as Perfetto-openable
@@ -440,6 +478,25 @@ class BaseTrainer:
         def crossed(prev, cur, every):
             return every > 0 and prev // every != cur // every
 
+        def emit(records, say: bool) -> bool:
+            """NaN-check, log and write ``records`` in step order. True at
+            the first NaN, with the rollback done: that record is not
+            written, and whatever follows it came from the poisoned state
+            and is dropped with it."""
+            for mstep, m in records:
+                if tc.nan_rollback and not math.isfinite(
+                        self._nan_check_value(m, log)):
+                    log(f"[step {mstep}] NaN loss — rolling back to last "
+                        f"good state")
+                    self._rollback()
+                    return True
+                if say:
+                    log(f"[step {mstep}] " + _fmt_metrics(m))
+                if metrics_writer is not None:
+                    metrics_writer.log(mstep, m)
+            return False
+
+        self._fetched_late = self._fetched_in_band = 0
         self._obs_wait_accum = 0.0
         self._obs_window_t0 = time.perf_counter()
         it = iter(batches)
@@ -485,6 +542,10 @@ class BaseTrainer:
                         self._phase("fit/dispatch")   # open unless profiled
                         # _finish_step, inside, moves on to fit/sync
                         m = step_call(*batch)
+                        if logdir:
+                            # the profile is of THIS step, whose metrics
+                            # nothing fetches before the next dispatch
+                            jax.block_until_ready(self.state)
                         self._phase("fit/after_step")
                     if logdir:
                         log(f"[profile] step {self._host_step}: trace → {logdir}")
@@ -494,46 +555,19 @@ class BaseTrainer:
                     if on_step is not None:
                         on_step(step_num)
                     # latch the signal flag ONCE per iteration; a save
-                    # decision must see the same value the metrics-fetch
-                    # decision does
+                    # decision must see the same value the fetch decision does
                     want_save = (crossed(prev_step, step_num, tc.save_every_steps) or
                                  getattr(self, "_signal_save", False))
-                    # the step these metrics belong to: with defer_metrics the
-                    # in-band dict is one boundary stale and tags itself
-                    mstep = m.pop("metrics_step", step_num) if m else step_num
-                    if want_save and (not m or mstep != step_num):
-                        # the save's NaN gate must see the CURRENT step — any
-                        # stale (deferred) record is flushed first, BEFORE the
-                        # current step's, so writer steps stay monotonic
-                        # (wandb silently drops out-of-order steps); then the
-                        # live metrics are pulled
-                        if m and metrics_writer is not None:
-                            metrics_writer.log(mstep, m)
-                        elif (not m and self._deferred_metrics is not None
-                              and self._deferred_metrics[0] != step_num):
-                            # save landed on a metrics-skipped step: an OLDER
-                            # boundary's record is still parked — emit it now
-                            # (a parked record of the current step is instead
-                            # retired by _fetch_pending_metrics, which keeps
-                            # its breakdown)
-                            dstep, dm, dpart = self._deferred_metrics
-                            self._deferred_metrics = None
-                            dm = self._fetch(dm, dpart)
-                            self._health_observe(dstep, dm)
-                            if metrics_writer is not None:
-                                metrics_writer.log(dstep, dm)
-                        m = self._fetch_pending_metrics()
-                        mstep = step_num
-                    nan = bool(m) and tc.nan_rollback and not math.isfinite(
-                        self._nan_check_value(m, log))
-                    if nan:
-                        log(f"[step {mstep}] NaN loss — rolling back to last good state")
-                        self._rollback()
-                    else:
-                        if m and crossed(prev_step, step_num, tc.log_every):
-                            log(f"[step {mstep}] " + _fmt_metrics(m))
-                        if m and metrics_writer is not None:
-                            metrics_writer.log(mstep, m)
+                    # the record at hand is the previous boundary's
+                    records = [(m.pop("metrics_step", step_num), m)] if m else []
+                    if want_save:
+                        # the save's NaN gate must see the CURRENT step:
+                        # whatever is still parked is fetched now, in band.
+                        # Older records go first, so writer steps stay
+                        # monotonic (wandb silently drops out-of-order steps)
+                        records += self._fetch_parked(current=True)
+                    if not emit(records,
+                                crossed(prev_step, step_num, tc.log_every)):
                         if want_save:
                             signal_save = getattr(self, "_signal_save", False)
                             with span("fit/checkpoint", step=step_num) as ckpt:
@@ -587,22 +621,19 @@ class BaseTrainer:
                 if steps is not None and step_num >= steps:
                     break
         finally:
-            if self._fit_warmup is not None:   # no step ran to its sync
+            if self._fit_warmup is not None:   # no step was dispatched
                 self._fit_warmup.__exit__(None, None, None)
                 self._fit_warmup = None
-            if self._deferred_metrics is not None:
-                # defer_metrics parks the final boundary's metrics — flush so
-                # the run's last record isn't silently dropped
-                fstep, fmetrics, fpart = self._deferred_metrics
-                self._deferred_metrics = None
-                try:
-                    fm = self._fetch(fmetrics, fpart)
-                    self._health_observe(fstep, fm)
-                    log(f"[step {fstep}] " + _fmt_metrics(fm))
-                    if metrics_writer is not None:
-                        metrics_writer.log(fstep, fm)
-                except Exception:  # noqa: BLE001 - the flush is best-effort:
-                    pass           # fit may be unwinding from a device error
+            # the last boundary is still parked: its record is written, and
+            # it gets the NaN check too, so fit() never returns a state whose
+            # last step went NaN while nan_rollback is on
+            try:
+                emit(self._fetch_parked(current=False), True)
+            except Exception as exc:  # noqa: BLE001 - fit may be unwinding
+                # from a device error, which this must not mask
+                log(f"[fit] the last record was not flushed: {exc!r}")
+            log(f"[fit] metrics fetches: {self._fetched_late} late (a later "
+                f"step already dispatched), {self._fetched_in_band} in band")
             # drain in-flight async checkpoint writes: a fit() that returned
             # must leave durable checkpoints behind (duck-typed managers in
             # tests may not expose the drain)
@@ -740,11 +771,12 @@ class BaseTrainer:
                 self._preemptive_good = jax.device_get(live)
 
     def _rollback(self):
-        # metrics computed from the poisoned state must die with it: a
-        # parked (defer_metrics) NaN record would otherwise trigger a
-        # second, spurious rollback at the next boundary, discarding the
-        # good step just trained from the restored state
-        self._deferred_metrics = None
+        # metrics computed from the poisoned state must die with it: the
+        # parked record of the step that fit() had already dispatched when
+        # it read the NaN would otherwise trigger a second, spurious
+        # rollback at the next boundary, discarding the good step just
+        # trained from the restored state
+        self._parked = None
         self._pending_metrics = None
         with span("ckpt/rollback"):
             if self._preemptive_good_device is not None:
@@ -770,63 +802,54 @@ class BaseTrainer:
             params, opt_state = restored
             self.state = self.state.replace(params=params, opt_state=opt_state)
 
-    def _finish_step(self, metrics) -> dict:
-        """Post-step bookkeeping: advance the host step, pull metrics, attach
-        the throughput report keyed on the POST-increment step so it lands in
-        the same metrics dict fit() logs at ``log_every`` boundaries.
+    def _finish_step(self, metrics, stamp: Optional[dict] = None) -> dict:
+        """Post-step bookkeeping: advance the host step and hand back a
+        record. ``stamp``: columns of this step that the host already knows
+        (the dVAE's temperature); they travel with the step's record.
 
-        With ``metrics_every > 1`` the device_get (a host↔device sync that
-        stalls the step pipeline) only happens every N steps; other steps
-        return an empty dict and fit() skips their NaN check / logging.
+        A bare ``train_step()`` / ``train_steps()`` call gets its own step's
+        metrics, fetched in band, with the throughput report keyed on the
+        post-increment step. Under fit() the fetch is one boundary late:
+        this step's device metrics are parked with its breakdown
+        (batch-wait/dispatch/h2d splits; the sync, the data-starvation ratio
+        and, at ``obs.device_poll_every`` cadence, the HBM and recompile
+        gauges join at the fetch), and the record handed back is the
+        previous boundary's, tagged ``metrics_step``. That step has
+        finished or is about to, and this one is already queued behind it,
+        so the device does not idle through the host's dispatch. The first
+        boundary of a fit() hands back nothing; the fetch of N-1 is what
+        keeps the host from running more than one step ahead.
 
-        Boundary steps additionally carry the grafttrace step breakdown
-        (batch-wait/dispatch/sync splits, data-starvation ratio) and — at
-        ``obs.device_poll_every`` cadence — the HBM and recompile gauges."""
+        With ``metrics_every > 1`` only every Nth step is a boundary; the
+        others return an empty dict and fit() skips their NaN check and
+        logging."""
         self._host_step += 1
         self._stepped = True
-        self._pending_metrics = metrics   # fit() fetches these on demand at
-                                          # save boundaries (NaN-check gate)
+        step = self._host_step
+        self._pending_metrics = (metrics, stamp)
         every = max(getattr(self.train_cfg, "metrics_every", 1), 1)
-        if self._host_step % every != 0:
+        if step % every != 0:
             return {}
-        step_of = self._host_step
-        defer = bool(getattr(self.train_cfg, "defer_metrics", False))
-        # defer: hand back the PREVIOUS boundary's metrics (that step has
-        # long finished — the device_get returns without stalling the
-        # pipeline) and park this boundary's for the next call, so the
-        # first boundary fetches nothing
-        will_sync = not defer or self._deferred_metrics is not None
-        part = None
-        if self._fit_phase is not None:
-            # under fit(): the dispatch ends here
-            dispatch = self._phase("fit/sync" if will_sync
-                                   else "fit/after_step")
-            part = self._partial_breakdown(dispatch.duration)
-        if defer:
-            # records carry their true step via ``metrics_step``, and the
-            # wait/dispatch/h2d timings are parked WITH the step they
-            # describe so the record's columns all belong to metrics_step;
-            # the sync paid below IS the handed-back record's fetch
-            parked, self._deferred_metrics = (self._deferred_metrics,
-                                              (step_of, metrics, part))
-            if parked is None:
-                return {}
-            step_of, metrics, part = parked
-        metrics = self._fetch(metrics, part)
-        rep = self.meter.step(self._host_step)
-        if rep:
-            metrics.update(rep)
-        self._health_observe(step_of, metrics)
-        if step_of != self._host_step:
-            metrics["metrics_step"] = step_of
-        return metrics
+        if self._fit_phase is None:
+            return self._record(step, metrics, stamp, None)
+        parked = self._parked
+        # the dispatch ends here
+        dispatch = self._phase("fit/sync" if parked is not None
+                               else "fit/after_step")
+        self._parked = (step, metrics, stamp,
+                        self._partial_breakdown(dispatch.duration))
+        if parked is None:
+            return {}
+        record = self._record(*parked)
+        record["metrics_step"] = parked[0]
+        return record
 
     def _partial_breakdown(self, t_dispatch_s: float) -> dict:
         """Where did the step go? The splits knowable when the dispatch
         ends, each the duration of a span of fit(): ``fit/batch_wait``,
         ``fit/dispatch``, the consumed batch's ``data/h2d`` and, one record
         late, the previous iteration's ``fit/checkpoint`` and
-        ``fit/after_step``. ``_fetch`` adds ``t_sync_s`` and the window's
+        ``fit/after_step``. ``_record`` adds ``t_sync_s`` and the window's
         gauges (``_finish_breakdown``). Only under fit(): a bare
         ``train_step()`` call has no batch-wait context and gets no
         breakdown."""
@@ -842,7 +865,7 @@ class BaseTrainer:
         self._fit_late.clear()
         return out
 
-    def _finish_breakdown(self, out: dict) -> dict:
+    def _finish_breakdown(self, out: dict, step: int) -> dict:
         """Windowed starvation ratio (the waiting-on-data share of the whole
         window since the last record, so ``metrics_every``-skipped steps are
         covered: 'input-bound vs compute-bound' as a logged metric instead
@@ -857,7 +880,9 @@ class BaseTrainer:
         self._obs_wait_accum = 0.0
         oc = getattr(self.train_cfg, "obs", None)
         if oc is not None and oc.device_poll_every > 0:
-            bucket = self._host_step // oc.device_poll_every
+            # by the record's step: the exit flush runs at the host step
+            # of the fetch before it
+            bucket = step // oc.device_poll_every
             if bucket != getattr(self, "_obs_poll_bucket", -1):
                 self._obs_poll_bucket = bucket
                 if getattr(self, "_telemetry", None) is None:

@@ -188,10 +188,9 @@ class VAETrainer(BaseTrainer):
         with span("vae/step"):
             self.state, metrics = self.step_fn(self.state, images, key,
                                                jnp.float32(temp))
-        metrics = self._finish_step(metrics)
-        if metrics:   # empty when metrics_every skips the host sync this step
-            metrics["temperature"] = temp
-        return metrics
+        # the stamp travels with this step's record: under fit() the record
+        # handed back is the previous boundary's
+        return self._finish_step(metrics, {"temperature": temp})
 
     # -- k steps in one device program ---------------------------------------
     def train_steps(self, images: np.ndarray, _labels=None):
@@ -209,18 +208,14 @@ class VAETrainer(BaseTrainer):
         k = images.shape[0]
         steps = self._host_step + np.arange(k)
         keys = self._step_keys(k)
-        temps = jnp.asarray([self._temp_at(int(s)) for s in steps],
-                            jnp.float32)
+        temps = [self._temp_at(int(s)) for s in steps]
         with span("vae/shard_batch", k=k):
             images = self._put(images, np.float32, stacked=True)
         with span("vae/steps", k=k):
             self.state, metrics = self._multi_step_fn(
-                self.state, (images, keys, temps))
+                self.state, (images, keys, jnp.asarray(temps, jnp.float32)))
         self._host_step += k - 1     # _finish_step adds the final +1
-        metrics = self._finish_step(metrics)
-        if metrics:
-            metrics["temperature"] = float(temps[-1])
-        return metrics
+        return self._finish_step(metrics, {"temperature": float(temps[-1])})
 
     # -- eval utilities ----------------------------------------------------
     def reconstruct(self, images: np.ndarray, hard: bool = True):

@@ -402,10 +402,11 @@ class VQGANTrainer(BaseTrainer):
         with span("vqgan/step"):
             self.state, metrics = self.step_fn(self.state, images, key,
                                                jnp.float32(temp))
-        metrics = self._finish_step(metrics)
-        if metrics and self.temp_scheduler is not None:
-            metrics["temperature"] = temp
-        return metrics
+        # the stamp travels with this step's record: under fit() the record
+        # handed back is the previous boundary's
+        return self._finish_step(
+            metrics, {"temperature": temp} if self.temp_scheduler is not None
+            else None)
 
     # -- k steps in one device program ---------------------------------------
     def train_steps(self, images: np.ndarray, targets=None):
@@ -425,25 +426,24 @@ class VQGANTrainer(BaseTrainer):
                     scanned=True, **self._health_kw)
         k = images.shape[0]
         steps = self._host_step + np.arange(k)
-        temps = jnp.asarray(
-            [self.temp_scheduler(int(s)) if self.temp_scheduler is not None
-             else 1.0 for s in steps], jnp.float32)
+        temps = [self.temp_scheduler(int(s)) if self.temp_scheduler is not None
+                 else 1.0 for s in steps]
+        temps_dev = jnp.asarray(temps, jnp.float32)
         keys = self._step_keys(k)
         with span("vqgan/shard_batch", k=k):
             images = self._put(images, np.float32, stacked=True)
         if self.loss_mode != "gan":
             t = (images if targets is None
                  else self._put(targets, np.float32, stacked=True))
-            xs = (images, t, keys, temps)
+            xs = (images, t, keys, temps_dev)
         else:
-            xs = (images, keys, temps)
+            xs = (images, keys, temps_dev)
         with span("vqgan/steps", k=k):
             self.state, metrics = self._multi_step_fn(self.state, xs)
         self._host_step += k - 1     # _finish_step adds the final +1
-        metrics = self._finish_step(metrics)
-        if metrics and self.temp_scheduler is not None:
-            metrics["temperature"] = float(temps[-1])
-        return metrics
+        return self._finish_step(
+            metrics, {"temperature": float(temps[-1])}
+            if self.temp_scheduler is not None else None)
 
     # -- eval utilities ----------------------------------------------------
     @property

@@ -336,8 +336,8 @@ def install_resilience(args, trainer, log=print):
 
 def add_overlap_args(parser):
     """Host-overlap flags shared by every train CLI (docs/PERFORMANCE.md):
-    async checkpointing, device prefetch depth, deferred metrics, and the
-    rollback-snapshot placement."""
+    async checkpointing, device prefetch depth, and the rollback-snapshot
+    placement."""
     grp = parser.add_argument_group("host overlap (docs/PERFORMANCE.md)")
     grp.add_argument("--sync_checkpointing", action="store_true",
                      help="disable async orbax saves (save() blocks until "
@@ -346,11 +346,6 @@ def add_overlap_args(parser):
                      help="batches kept device-resident ahead of the step "
                           "loop (0 disables; H2D then rides the critical "
                           "path)")
-    grp.add_argument("--defer_metrics", action="store_true",
-                     help="fetch step metrics one boundary late so the "
-                          "device_get reads an already-finished step "
-                          "(loss column lags one boundary; NaN rollback on "
-                          "non-save steps triggers one boundary late)")
     grp.add_argument("--rollback_snapshot", type=str, default="auto",
                      choices=["auto", "device", "host"],
                      help="where the NaN-rollback snapshot lives (auto = "
@@ -363,6 +358,5 @@ def overlap_train_kwargs(args) -> dict:
     return {
         "async_checkpointing": not args.sync_checkpointing,
         "device_prefetch": args.device_prefetch,
-        "defer_metrics": args.defer_metrics,
         "rollback_snapshot": args.rollback_snapshot,
     }

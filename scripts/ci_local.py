@@ -109,8 +109,8 @@ def run_wire_audit_stage() -> int:
 
 def run_obs_smoke_stage() -> int:
     """The grafttrace + host-overlap + graftpulse smoke stage: a short
-    synthetic traced fit (device prefetch + async checkpointing + deferred
-    metrics + model-health taps ON) that must produce a well-formed
+    synthetic traced fit (device prefetch + async checkpointing +
+    model-health taps ON; the metrics fetch is one step late, as always) that must produce a well-formed
     Perfetto trace, the step-time breakdown AND health/* columns in the
     metrics JSONL, steady-state batch_wait+sync ≈ 0 with the taps fused
     in, a bounded checkpoint-boundary step, a quiet watchdog, <1% span

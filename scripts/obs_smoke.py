@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Observability + host-overlap smoke: a short synthetic traced DALLE fit
-with every PR3 overlap layer ON (device prefetch, async checkpointing,
-deferred metrics) AND the graftpulse health taps fused into the step, then
+with every PR3 overlap layer ON (device prefetch, async checkpointing;
+fit()'s metrics fetch is always one step late) AND the graftpulse health taps fused into the step, then
 assert the telemetry AND overlap contracts end to end (the CI stage behind
 docs/OBSERVABILITY.md and docs/PERFORMANCE.md):
 
@@ -14,7 +14,7 @@ docs/OBSERVABILITY.md and docs/PERFORMANCE.md):
      gauge, and t_ckpt_s on the records after each save boundary;
   3. OVERLAP: steady-state t_batch_wait_s + t_sync_s is ~0 WITH the health
      taps on (the graftpulse free-tap contract: the per-layer-group
-     vitals ride the existing deferred-metrics fetch, zero added host
+     vitals ride fit()'s one late metrics fetch, zero added host
      syncs), and a step crossing a checkpoint boundary stays within a
      bounded multiple of the median step time;
   4. the watchdog (armed with a generous deadline) stayed quiet;
@@ -89,7 +89,7 @@ def main(argv=None):
         batch_size=4, log_every=1, metrics_every=1,
         save_every_steps=args.save_every, keep_n_checkpoints=2,
         preflight_checkpoint=False,
-        async_checkpointing=True, device_prefetch=2, defer_metrics=True,
+        async_checkpointing=True, device_prefetch=2,
         rollback_snapshot="auto",
         checkpoint_dir=os.path.join(args.outdir, "ckpt"),
         mesh=mesh_cfg,
@@ -185,7 +185,7 @@ def main(argv=None):
                    if "t_batch_wait_s" in r and not r.get("t_ckpt_s"))
     if waits:
         med_wait = waits[len(waits) // 2]
-        # "≈ 0": an in-memory iterator + device-resident batches + deferred
+        # "≈ 0": an in-memory iterator + device-resident batches + the late
         # sync leave only bookkeeping — bounded by 10% of a (tiny, ~ms-scale)
         # step with a 5 ms absolute floor for CI scheduler noise
         bound = max(0.10 * med_step, 0.005)
@@ -217,8 +217,7 @@ def main(argv=None):
 
     # -- 5. span overhead < 1% of step time --------------------------------
     # against the median steady fit/step span of section 3: t_dispatch_s is
-    # the fit/dispatch span alone, which under defer_metrics is the host's
-    # share of a step and not the step
+    # the fit/dispatch span alone: the host's share of a step, not the step
     per_span = span_overhead_s()
     spans_per_step = len(events) / max(args.steps, 1)
     if steady:

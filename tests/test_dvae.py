@@ -156,6 +156,27 @@ class TestTrainer:
         assert meta["model_class"] == "DiscreteVAE"
         assert meta["hparams"]["num_tokens"] == SMALL.num_tokens
 
+    def test_fit_stamps_each_record_with_its_own_temperature(self, tmp_path):
+        """fit() hands a step's record back one step late: the temperature
+        on it is that step's, not the one of the step just dispatched."""
+        tc = TrainConfig(batch_size=8, checkpoint_dir=str(tmp_path / "ck3"),
+                         preflight_checkpoint=False, save_every_steps=0,
+                         log_every=1000, mesh=MeshConfig())
+        trainer = VAETrainer(SMALL, tc, AnnealConfig(
+            starting_temp=1.0, temp_min=0.1, anneal_rate=0.2))
+        records = []
+
+        class Writer:
+            def log(self, step, metrics):
+                records.append((step, metrics["temperature"]))
+
+        imgs, _ = ShapesDataset(image_size=32).as_arrays(limit=8)
+        trainer.fit(iter([(imgs,)] * 4), log=lambda *a: None,
+                    metrics_writer=Writer())
+        want = [(s + 1, trainer._temp_at(s)) for s in range(4)]
+        assert records == want and len({t for _, t in want}) == 4
+        assert trainer.train_step(imgs)["temperature"] == trainer._temp_at(4)
+
     def test_codebook_histogram(self, tmp_path):
         tc = TrainConfig(batch_size=8, checkpoint_dir=str(tmp_path / "ck2"),
                          preflight_checkpoint=False, mesh=MeshConfig())

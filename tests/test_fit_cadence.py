@@ -74,6 +74,7 @@ class FakeTrainer(BaseTrainer):
         self._obs_window_t0 = None
         self.last_watchdog = None
         self.rollbacks = 0
+        self.rolled_back_at = []
         self.single_calls = 0
         self.scan_calls = []
         self._step_metrics = step_metrics or (
@@ -93,10 +94,15 @@ class FakeTrainer(BaseTrainer):
         return self._finish_step(self._step_metrics(self._host_step))
 
     def _snapshot_good(self):
-        self._last_good = "snapshot"
+        pass                       # nothing on a device to copy
 
     def _rollback(self):
+        """The real bookkeeping (the unfetched records die with the state);
+        with no snapshot there is nothing to restore, so mark the state."""
         self.rollbacks += 1
+        self.rolled_back_at.append(self._host_step)
+        super()._rollback()
+        self.state = "restored"
 
 
 def _tc(tmp_path, **kw):
@@ -242,15 +248,14 @@ def test_nan_guard_warns_once_when_nothing_checkable(tmp_path):
     assert tr.rollbacks == 0
 
 
-# -- deferred metrics loop logic (host-only; trainer-level defer tests live
-# in test_overlap.py) ---------------------------------------------------------
+# -- the late metrics fetch: fit()'s loop logic (host-only; the real trainer's
+# run of it lives in test_overlap.py) ----------------------------------------
 
-def test_defer_metrics_save_on_skipped_step_keeps_writer_monotonic(tmp_path):
+def test_save_on_skipped_step_keeps_writer_monotonic(tmp_path):
     """A save boundary landing on a metrics-skipped step must flush the
     OLDER parked record before writing its own — wandb silently drops
     out-of-order steps, so writer steps must stay monotonic."""
-    tc = _tc(tmp_path, defer_metrics=True, metrics_every=3,
-             save_every_steps=5)
+    tc = _tc(tmp_path, metrics_every=3, save_every_steps=5)
     tr = FakeTrainer(tc)
     w = RecordingWriter()
     tr.fit(_batches(7), log=lambda *a: None, metrics_writer=w)
@@ -262,17 +267,92 @@ def test_defer_metrics_save_on_skipped_step_keeps_writer_monotonic(tmp_path):
     assert tr.ckpt.saves == [5]
 
 
-def test_defer_metrics_breakdown_survives_coinciding_save_cadence(tmp_path):
-    """save_every == metrics_every: every boundary force-fetches; the parked
-    breakdown must transfer into the in-band record, not be dropped with
-    the retired deferred entry."""
-    tc = _tc(tmp_path, defer_metrics=True, metrics_every=1,
-             save_every_steps=1)
+def test_breakdown_survives_coinciding_save_cadence(tmp_path):
+    """save_every == metrics_every: every boundary is fetched in band; the
+    parked breakdown must go into that record, not be dropped with the
+    parked entry."""
+    tc = _tc(tmp_path, metrics_every=1, save_every_steps=1)
     tr = FakeTrainer(tc)
     w = RecordingWriter()
     tr.fit(_batches(3), log=lambda *a: None, metrics_writer=w)
     assert [s for s, _ in w.records] == [1, 2, 3]
     assert all("t_batch_wait_s" in m for _, m in w.records), w.records
+
+
+def test_fit_writes_each_step_once_in_order_the_last_at_exit(tmp_path):
+    """One record a step under its true step number, in increasing order;
+    the writer lags the loop (step N's record is not written before step
+    N+1 is dispatched), and the last record comes from the flush at exit."""
+    tr = FakeTrainer(_tc(tmp_path),
+                     step_metrics=lambda step: {"loss": np.float32(step + 1)})
+    w = RecordingWriter()
+    written_at = {}
+    logs = []
+    tr.fit(_batches(5), log=logs.append, metrics_writer=w,
+           on_step=lambda step: written_at.update(
+               {step: [s for s, _ in w.records]}))
+    assert [s for s, _ in w.records] == [1, 2, 3, 4, 5]
+    assert [m["loss"] for _, m in w.records] == [1.0, 2.0, 3.0, 4.0, 5.0]
+    assert all(step not in written for step, written in written_at.items())
+    assert written_at[5] == [1, 2, 3]      # 4 is fetched, not yet written
+    assert "[fit] metrics fetches: 4 late" in logs[-1]
+    assert "1 in band" in logs[-1]
+
+
+@pytest.mark.parametrize("nan_steps,n,written,rolled_back_at", [
+    # NaN at step 3, read once step 4 (which ran on the poisoned state, so
+    # NaN as well) is dispatched: one rollback, 4's parked record dropped
+    ((3, 4), 6, [1, 2, 5, 6], [4]),
+    # NaN at the last step: the exit flush finds it
+    ((5,), 5, [1, 2, 3, 4], [5]),
+], ids=["mid_run", "last_step"])
+def test_nan_read_one_step_late_rolls_back_once(tmp_path, nan_steps, n,
+                                                written, rolled_back_at):
+    tr = FakeTrainer(_tc(tmp_path), step_metrics=lambda step: {
+        "loss": np.float32("nan" if step + 1 in nan_steps else 0.5)})
+    w = RecordingWriter()
+    state = tr.fit(_batches(n), log=lambda *a: None, metrics_writer=w)
+    assert tr.rolled_back_at == rolled_back_at
+    assert [s for s, _ in w.records] == written
+    assert state == "restored"             # what fit() returns is not NaN's
+
+
+def test_save_boundary_fetches_its_step_before_the_save(tmp_path):
+    """Nothing is checkpointed without the NaN check of the state saved: at
+    a save boundary the current step's record is fetched (in band) and
+    written before ckpt.save, and a NaN there skips the save."""
+    tc = _tc(tmp_path, save_every_steps=2,
+             obs=ObsConfig(trace=True, trace_dir=str(tmp_path / "obs")))
+    tr = FakeTrainer(tc, step_metrics=lambda step: {
+        "loss": np.float32("nan" if step + 1 == 4 else 0.5)})
+    w = RecordingWriter()
+    seen = {}
+    save = tr.ckpt.save
+
+    def save_after_its_record(step, state, meta=None):
+        seen[step] = [s for s, _ in w.records]
+        save(step, state, meta)
+
+    tr.ckpt.save = save_after_its_record
+    tr.fit(_batches(6), log=lambda *a: None, metrics_writer=w)
+    assert seen == {2: [1, 2], 6: [1, 2, 3, 5, 6]}
+    assert tr.ckpt.saves == [2, 6] and tr.rolled_back_at == [4]
+    counters = obs.metrics_snapshot()
+    # late: 1 (at step 2), 3 (at 4), 5 (at 6); in band: the boundaries 2, 4, 6
+    assert counters["fit.fetch_late"] == 3
+    assert counters["fit.fetch_in_band"] == 3
+
+
+def test_bare_train_step_returns_its_own_metrics(tmp_path):
+    """Outside fit() a step is fetched in band, before and after a fit()."""
+    tr = FakeTrainer(_tc(tmp_path, log_every=100),     # no meter report
+                     step_metrics=lambda step: {"loss": np.float32(step + 1)})
+    x = np.zeros((4, 8), np.float32)
+    assert tr.train_step(x) == {"loss": 1.0}
+    tr.fit(_batches(2), log=lambda *a: None)
+    assert tr._parked is None
+    assert tr.train_step(x) == {"loss": 4.0}
+    assert tr.train_steps(np.zeros((2, 4, 8), np.float32)) == {"loss": 6.0}
 
 
 # -- grafttrace integration ---------------------------------------------------
@@ -297,7 +377,9 @@ def test_fit_emits_step_breakdown_and_starvation(tmp_path):
                 "data_starvation", "hbm_bytes_in_use", "compiles_total"):
         assert col in m, col
     assert m["t_batch_wait_s"] >= 0.02
-    assert m["data_starvation"] > 0.5        # input-bound by construction
+    # input-bound by construction, in every window between two fetches (the
+    # flush at exit closes one that holds no batch wait)
+    assert all(m["data_starvation"] > 0.5 for _, m in writer.records[:-1])
 
 
 def test_fit_compute_bound_low_starvation(tmp_path):
@@ -322,43 +404,53 @@ def test_fit_exports_trace_with_nested_spans(tmp_path):
     for e in doc["traceEvents"]:
         if e["name"] in ("fit/batch_wait", "fit/dispatch", "fit/sync",
                          "fit/after_step"):
+            if e["args"]["parent"] is None:       # the flush at fit()'s exit
+                assert e["name"] == "fit/sync" and not e["args"]["late"]
+                continue
             lo, hi = steps[e["args"]["parent"]]
             assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1
     rows = [json.loads(l) for l in open(outdir / "spans.jsonl")]
     assert any(r["name"] == "fit/sync" for r in rows)
-    # fit/warmup: from fit()'s entry to the end of the first step's sync,
-    # closed before that step's fit/after_step opens
+    # fit/warmup: from fit()'s entry to the return of the first step's
+    # dispatch, closed before that step's fit/after_step opens; the first
+    # fetch comes an iteration later
     by_name = {}
     for r in rows:
         by_name.setdefault(r["name"], []).append(r)
     warm, = by_name["fit/warmup"]
-    first_sync = min(by_name["fit/sync"], key=lambda r: r["rel_s"])
-    first_after = min(by_name["fit/after_step"], key=lambda r: r["rel_s"])
+    first = {name: min(by_name[name], key=lambda r: r["rel_s"])
+             for name in ("fit/dispatch", "fit/sync", "fit/after_step")}
     assert warm["rel_s"] <= by_name["fit/step"][0]["rel_s"]
     warm_end = warm["rel_s"] + warm["dur_s"]
-    assert first_sync["rel_s"] + first_sync["dur_s"] <= warm_end
-    assert warm_end <= first_after["rel_s"]
+    assert (first["fit/dispatch"]["rel_s"] + first["fit/dispatch"]["dur_s"]
+            <= warm_end <= first["fit/after_step"]["rel_s"])
+    assert warm_end <= first["fit/sync"]["rel_s"]
 
 
-@pytest.mark.parametrize("defer", [False, True], ids=["in_band", "defer"])
-def test_fit_phases_tile_the_step_and_feed_the_record(tmp_path, defer):
+@pytest.mark.parametrize("save_every", [1, 0],
+                         ids=["in_band_at_saves", "late"])
+def test_fit_phases_tile_the_step_and_feed_the_record(tmp_path, save_every):
     """Over a 5-step fit(): fit/dispatch and fit/sync are siblings that never
     overlap; per ``step`` id the phase spans add up to their fit/step span
     within 1 %; and each record's t_* columns ARE the durations of the spans
-    of the step it describes (t_after_s: of the iteration before it)."""
+    of the step it describes (t_after_s: of the iteration before it). The
+    sync that fetches step N starts after the dispatch of step N+1 has ended
+    (late), but for a save boundary, which fetches its own step (in band);
+    the counters say how many of each."""
     outdir = tmp_path / "obs"
-    tc = _tc(tmp_path, device_prefetch=0, defer_metrics=defer,
+    tc = _tc(tmp_path, device_prefetch=0, save_every_steps=save_every,
              obs=ObsConfig(trace=True, trace_dir=str(outdir)))
     tr = FakeTrainer(tc, step_sleep=0.02)
     writer = RecordingWriter()
     tr.fit(_batches(5), log=lambda *a: None, metrics_writer=writer)
+    counters = obs.metrics_snapshot()
     rows = [json.loads(l) for l in open(outdir / "spans.jsonl")]
     phases = ("fit/batch_wait", "fit/dispatch", "fit/sync", "fit/after_step")
     by_step = {}
     for r in rows:
-        # (under defer the last record's flush runs after the loop: a
-        # fit/sync with no step)
-        if (r["name"] in phases or r["name"] == "fit/step") and "args" in r:
+        # (the last record's flush runs after the loop: a fit/sync with no
+        # step)
+        if (r["name"] in phases or r["name"] == "fit/step") and "step" in r["args"]:
             by_step.setdefault(r["args"]["step"], {}).setdefault(
                 r["name"], []).append(r)
     # the sixth iteration only finds the iterator at its end
@@ -379,20 +471,35 @@ def test_fit_phases_tile_the_step_and_feed_the_record(tmp_path, defer):
         assert m["t_batch_wait_s"] == spans["fit/batch_wait"][0]["dur_s"]
         assert m["t_dispatch_s"] == spans["fit/dispatch"][0]["dur_s"]
         assert m["t_dispatch_s"] >= 0.02          # the step, not the sync
-        # the sync that fetched it: its own step's, or under defer the next
-        # iteration's (the flush of the last record runs after the loop)
-        syncs = [r["dur_s"] for r in rows if r["name"] == "fit/sync"]
-        assert m["t_sync_s"] in syncs
         if mstep >= 2:
             before = by_step[mstep - 2]["fit/after_step"]
             assert m["t_after_s"] == pytest.approx(
                 sum(r["dur_s"] for r in before))
         else:
             assert "t_after_s" not in m
-    if not defer:
+    syncs = [r for r in rows if r["name"] == "fit/sync"]
+    assert len(syncs) == 5
+    if save_every:
+        # every boundary saves: each record by the sync of its own iteration
         for mstep, m in writer.records:
             sync, = by_step[mstep - 1]["fit/sync"]
-            assert m["t_sync_s"] == sync["dur_s"]
+            assert m["t_sync_s"] == sync["dur_s"] and not sync["args"]["late"]
+        assert "fit.fetch_late" not in counters
+        assert counters["fit.fetch_in_band"] == 5
+    else:
+        for mstep, m in writer.records[:-1]:
+            # step N's record by the sync of the iteration that dispatched
+            # step N+1, once that dispatch had ended
+            sync, = by_step[mstep]["fit/sync"]
+            dispatch, = by_step[mstep]["fit/dispatch"]
+            assert m["t_sync_s"] == sync["dur_s"] and sync["args"]["late"]
+            assert dispatch["rel_s"] + dispatch["dur_s"] <= sync["rel_s"]
+        assert "fit/sync" not in by_step[0]       # nothing to fetch yet
+        flush, = [r for r in syncs if "step" not in r["args"]]
+        assert writer.records[-1][1]["t_sync_s"] == flush["dur_s"]
+        assert not flush["args"]["late"]
+        assert counters["fit.fetch_late"] == 4
+        assert counters["fit.fetch_in_band"] == 1
 
 
 def test_fit_phase_durations_need_no_ring(tmp_path):

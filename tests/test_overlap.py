@@ -1,7 +1,7 @@
 """PR3 host-overlap machinery on the CPU mesh: device prefetch semantics,
 on-device rollback snapshots (donation-safe, bit-exact), async checkpointing
 (drain-on-close, rotation with in-flight writes, incomplete-step hygiene),
-and the deferred metrics fetch. See docs/PERFORMANCE.md."""
+and fit()'s late metrics fetch. See docs/PERFORMANCE.md."""
 
 import os
 import time
@@ -340,7 +340,7 @@ def test_signal_save_drains_inflight_write(tmp_path, rng):
     assert tr._signal_save is False
 
 
-# -- deferred metrics ---------------------------------------------------------
+# -- the late metrics fetch ---------------------------------------------------
 
 class _Writer:
     def __init__(self):
@@ -350,19 +350,22 @@ class _Writer:
         self.records.append((step, dict(metrics)))
 
 
-def test_defer_metrics_true_steps_and_save_boundary_fetch(tmp_path, rng):
-    """One fit covers the deferred-metrics contract: records carry their
-    TRUE steps in order with no step lost (stale records flushed before
-    save-boundary force-fetches; the final parked boundary flushed at fit
-    exit), and save boundaries (2, 4) get an in-band record of their OWN
-    step — nothing is checkpointed without a NaN check of the current
-    state."""
-    tc = _tc(tmp_path, defer_metrics=True, save_every_steps=2, log_every=1,
-             metrics_every=1)
+def test_late_fetch_true_steps_and_save_boundary_fetch(tmp_path, rng):
+    """One fit covers the late fetch's contract on a real trainer: records
+    carry their TRUE steps in order with no step lost (the late record
+    written before a save boundary's own; the final parked boundary flushed
+    at fit exit), and save boundaries (2, 4) get an in-band record of their
+    OWN step — nothing is checkpointed without a NaN check of the current
+    state. The losses are those of the same steps run bare (in band)."""
+    tc = _tc(tmp_path, save_every_steps=2, log_every=1, metrics_every=1,
+             device_prefetch=0)
+    batches = [_batch(rng) for _ in range(5)]
     tr = DalleTrainer(TINY, tc, mesh=build_mesh(tc.mesh))
     w = _Writer()
-    tr.fit(iter([_batch(rng) for _ in range(4)]), metrics_writer=w,
-           log=lambda *a: None)
-    assert [s for s, _ in w.records] == [1, 2, 3, 4]
-    assert all("loss" in m for _, m in w.records)
+    tr.fit(iter(batches), metrics_writer=w, log=lambda *a: None)
+    assert [s for s, _ in w.records] == [1, 2, 3, 4, 5]
     assert tr.ckpt.latest_step() == 4
+    assert (tr._fetched_late, tr._fetched_in_band) == (2, 3)   # 1, 3 | 2, 4, 5
+    bare = DalleTrainer(TINY, tc, mesh=tr.mesh)
+    assert [m["loss"] for _, m in w.records] == [
+        bare.train_step(*b)["loss"] for b in batches]
