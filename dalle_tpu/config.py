@@ -217,6 +217,79 @@ class DVAEConfig(ConfigBase):
 
 
 @dataclass(frozen=True)
+class BlockConfig(ConfigBase):
+    """What a transformer layer is built from: attention kind x feed-forward
+    kind x norm, and how positions enter. The defaults are DALLE-pytorch's
+    block (multi-head attention, GEGLU, LayerNorm, LayerScale, the text +
+    axial rotary table) and build today's parameter tree leaf for leaf.
+
+    The other kinds are DeepSeek-V2's (arXiv:2405.04434), sized by the keys
+    of its ``config.json``: ``mla`` (latent attention: queries through a
+    ``q_lora_rank`` latent, keys and values through a ``kv_lora_rank`` latent
+    plus one rotary key part shared by all heads), ``swiglu``, ``moe``
+    (``n_routed_experts`` SwiGLU experts of ``moe_intermediate_size``, of
+    which a token takes ``num_experts_per_tok`` by group-limited greedy
+    routing, plus ``n_shared_experts`` shared ones; the first
+    ``first_dense_layers`` layers keep a ``swiglu`` of ``intermediate_size``),
+    ``rmsnorm``, and ``seq_yarn`` positions (rotary over 0..n-1 with YaRN's
+    frequency blend). They have no biases. Which heads and experts a chip
+    holds is not the block's business: ``heads_held`` / ``experts_held`` on
+    the model's config."""
+    attention: str = "mha"             # mha | mla
+    feed_forward: str = "geglu"        # geglu | swiglu | moe
+    norm: str = "layernorm"            # layernorm | rmsnorm
+    layerscale: bool = True
+    positions: str = "dalle_axial"     # dalle_axial | seq_yarn
+    first_dense_layers: int = 0
+    rms_norm_eps: float = 1e-6
+    # mla
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # seq_yarn (yarn_factor 1 is plain rotary)
+    rope_theta: float = 10000.0
+    yarn_factor: float = 1.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # swiglu / moe
+    intermediate_size: int = 0
+    moe_intermediate_size: int = 0
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    num_experts_per_tok: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+
+    KINDS = {"attention": ("mha", "mla"),
+             "feed_forward": ("geglu", "swiglu", "moe"),
+             "norm": ("layernorm", "rmsnorm"),
+             "positions": ("dalle_axial", "seq_yarn")}
+
+    def __post_init__(self):
+        for field_name, kinds in self.KINDS.items():
+            if getattr(self, field_name) not in kinds:
+                raise ValueError(f"block.{field_name} must be one of {kinds}, "
+                                 f"got {getattr(self, field_name)!r}")
+
+    @property
+    def is_default(self) -> bool:
+        return (self.attention, self.feed_forward, self.norm,
+                self.positions, self.layerscale) == (
+                    "mha", "geglu", "layernorm", "dalle_axial", True)
+
+    @property
+    def name(self) -> str:
+        """The block kind, for messages: ``mla+moe``."""
+        return f"{self.attention}+{self.feed_forward}"
+
+
+@dataclass(frozen=True)
 class TransformerConfig(ConfigBase):
     """Transformer stack (reference: dalle_pytorch/transformer.py:204-328)."""
     seq_len: int = 512           # total text+image sequence length (no bos slot)
@@ -257,6 +330,11 @@ class TransformerConfig(ConfigBase):
     # f32 attention softmax is the safe default; False keeps scores bf16 —
     # the dominant HBM tensor (big train-throughput win, tiny numeric delta)
     attn_softmax_f32: bool = True
+    block: BlockConfig = BlockConfig()
+    # the share of a layer this chip holds (0 = all): heads of ``heads``
+    # (mla), routed experts of ``block.n_routed_experts`` from index 0 (moe)
+    heads_held: int = 0
+    experts_held: int = 0
 
 
 @dataclass(frozen=True)
@@ -297,6 +375,28 @@ class DalleConfig(ConfigBase):
     image_size: int = 128
     image_vocab_size: int = 8192   # vae num_tokens
     image_fmap_size: int = 16      # image_size / 2**vae_layers
+    # the layer's kinds (see BlockConfig) and this chip's share of a layer
+    block: BlockConfig = BlockConfig()
+    heads_held: int = 0
+    experts_held: int = 0
+
+    def __post_init__(self):
+        if isinstance(self.block, dict):     # DalleConfig(**a JSON object)
+            object.__setattr__(self, "block", BlockConfig.from_dict(self.block))
+        b = self.block
+        if not b.is_default and (self.reversible or self.shift_tokens
+                                 or self.sandwich_norm or self.stable
+                                 or self.share_input_output_emb
+                                 or tuple(self.attn_types) != ("full",)
+                                 or self.shared_attn_ids or self.shared_ff_ids):
+            raise ValueError(
+                f"the {b.name} block runs full causal attention in a plain "
+                f"pre-norm stack: reversible, shift_tokens, sandwich_norm, "
+                f"stable, shared layers, tied embeddings and sparse "
+                f"attn_types belong to the default block")
+        if b.attention == "mla" and self.dim_head != b.v_head_dim:
+            raise ValueError(f"mla: dim_head ({self.dim_head}) is the value "
+                             f"head width, block.v_head_dim ({b.v_head_dim})")
 
     @property
     def image_seq_len(self) -> int:
@@ -324,6 +424,8 @@ class DalleConfig(ConfigBase):
             attn_softmax_f32=self.attn_softmax_f32,
             sparse_block_size=self.sparse_block_size, sparse_attn_kernel=self.sparse_attn_kernel,
             sparse_mask_seed=self.sparse_mask_seed,
+            block=self.block, heads_held=self.heads_held,
+            experts_held=self.experts_held,
         )
 
 

@@ -36,6 +36,7 @@ from ..config import DalleConfig
 from ..ops.quantize_weights import QDense
 from ..ops.sampling import (gumbel_sample, gumbel_sample_rows,
                             prob_mask_like, top_k_filter)
+from .latent_moe import RMSNorm
 from .transformer import DivideMax, Transformer
 
 MASK_VALUE = -1e9  # max_neg/2-style fill for the logits mask
@@ -102,7 +103,9 @@ class DALLE(nn.Module):
                 c.dim, (c.image_fmap_size, c.image_fmap_size),
                 name="image_pos_emb")
 
-        self.final_norm = nn.LayerNorm(name="final_norm")
+        self.final_norm = (
+            RMSNorm(c.block.rms_norm_eps, name="final_norm")
+            if c.block.norm == "rmsnorm" else nn.LayerNorm(name="final_norm"))
         self.norm_by_max = DivideMax(axis=-1)
 
         # static (seq, total_tokens) allow-mask: text positions predict text
@@ -214,7 +217,9 @@ class DALLE(nn.Module):
             tokens = tokens[:, :c.total_seq_len]
         tokens = self._stabilize(tokens)
 
-        out = self.transformer(tokens, deterministic=deterministic)
+        # counters of the layers that count (routed experts): {} otherwise
+        out, counters = self.transformer(tokens, deterministic=deterministic,
+                                         return_aux=True)
 
         if not return_loss:
             return self._finish(out, (0, tokens.shape[1]))
@@ -247,7 +252,8 @@ class DALLE(nn.Module):
             loss_img = ce[:, c.text_seq_len:].mean()
             loss = ((loss_text + c.loss_img_weight * loss_img)
                     / (c.loss_img_weight + 1))
-        return loss, {"loss_text": loss_text, "loss_img": loss_img}
+        return loss, {"loss_text": loss_text, "loss_img": loss_img,
+                      **counters}
 
     # -- generation --------------------------------------------------------
     def _prefill(self, text, image_prime: Optional[jnp.ndarray], batch: int,

@@ -38,7 +38,10 @@ from ..ops.attention import (KVCache, attend, cached_attend,
                              cached_attend_window)
 from ..ops.attn_masks import build_mask
 from ..ops.quantize_weights import QDense
-from ..ops.rotary import apply_rotary, dalle_pos_emb
+from ..ops.rotary import (apply_rotary, dalle_pos_emb, seq_yarn_table,
+                          yarn_mscale)
+from .latent_moe import (MLAttention, MoEFeedForward, RMSNorm,
+                         SwiGLUFeedForward)
 
 
 def _block_body(mdl, x, key_mask, ind: int, deterministic: bool):
@@ -50,7 +53,10 @@ def _block_body(mdl, x, key_mask, ind: int, deterministic: bool):
                                  np_mask=mdl.np_masks[t],
                                  mask_spec=mdl.mask_specs[t],
                                  deterministic=deterministic)
-    return x + mdl.ff_layers[ind](x, deterministic=deterministic)
+    y = mdl.ff_layers[ind](x, deterministic=deterministic)
+    # a feed-forward that counts (MoEFeedForward) returns (output, counters)
+    y, counters = y if isinstance(y, tuple) else (y, {})
+    return x + y, counters
 
 
 def layerscale_init_eps(layer_index_1based: int) -> float:
@@ -389,10 +395,18 @@ class TransformerLayer(nn.Module):
     shift: bool = False
     text_len: int = 0
     image_size: int = 0
+    norm_kind: str = "layernorm"   # layernorm | rmsnorm (config.BlockConfig)
+    norm_eps: float = 1e-6
+    layerscale: bool = True
 
     def setup(self):
-        self.norm = nn.LayerNorm(name="norm")
+        self.norm = (RMSNorm(self.norm_eps, name="norm")
+                     if self.norm_kind == "rmsnorm"
+                     else nn.LayerNorm(name="norm"))
         self.norm_out = nn.LayerNorm(name="norm_out") if self.sandwich else None
+        if not self.layerscale:
+            self.scale = None
+            return
         eps = layerscale_init_eps(self.index)
         # explicit dtype: jnp.full of a Python float is WEAK-typed, and a
         # weak-typed param flips to strong after one pass through a jitted
@@ -407,13 +421,15 @@ class TransformerLayer(nn.Module):
     def _post(self, y):
         if self.norm_out is not None:
             y = self.norm_out(y)
-        return y * self.scale
+        return y if self.scale is None else y * self.scale
 
     def __call__(self, x, **kw):
         y = self.norm(x)
         if self.shift:
             y = shift_tokens_full(y, self.text_len, self.image_size)
         y = self.fn(y, **kw)
+        if isinstance(y, tuple):       # (output, counters): see _block_body
+            return self._post(y[0]), y[1]
         return self._post(y)
 
     def prefill(self, x, kv: Optional[KVCache], shift_state: Optional[ShiftState],
@@ -468,8 +484,10 @@ class Transformer(nn.Module):
         # "auto" resolves against the measured v5e crossover: flash kernels
         # for seq ≥ 2048 on TPU, dense below (ops/flash_attention.py)
         from ..ops.flash_attention import resolve_use_pallas
+        blk = c.block
         use_pallas = resolve_use_pallas(c.use_pallas, c.seq_len,
-                                        dim_head=c.dim_head, heads=c.heads)
+                                        dim_head=c.dim_head, heads=c.heads,
+                                        attention=blk.attention)
 
         attn_types = tuple(c.attn_types) or ("full",)
         type_per_layer = list(islice(cycle(attn_types), c.depth))
@@ -518,6 +536,26 @@ class Transformer(nn.Module):
         self.mask_specs = specs
         self.mask_keys = mask_keys
 
+        self.rotary = None
+        if blk.positions == "seq_yarn":
+            angles, cos_sin_scale = seq_yarn_table(
+                c.seq_len + 1, blk.qk_rope_head_dim, blk.rope_theta,
+                {"factor": blk.yarn_factor,
+                 "original_max_position": blk.yarn_original_max_position,
+                 "beta_fast": blk.yarn_beta_fast,
+                 "beta_slow": blk.yarn_beta_slow, "mscale": blk.yarn_mscale,
+                 "mscale_all_dim": blk.yarn_mscale_all_dim})
+            if cos_sin_scale != 1.0:
+                raise NotImplementedError(
+                    f"seq_yarn with yarn_mscale {blk.yarn_mscale} != "
+                    f"yarn_mscale_all_dim {blk.yarn_mscale_all_dim}: cos and "
+                    f"sin would be scaled by {cos_sin_scale}, which "
+                    f"apply_rotary does not do")
+            self.rotary = jnp.asarray(angles)
+        elif c.rotary_emb and c.causal:
+            self.rotary = jnp.asarray(
+                dalle_pos_emb(self.text_len, fmap, c.dim_head))
+
         shared_attn: Dict[Any, Tuple[Attention, str]] = {}
         shared_ff: Dict[Any, GEGLUFeedForward] = {}
         attn_layers, ff_layers = [], []
@@ -532,36 +570,76 @@ class Transformer(nn.Module):
                         f"attn_types do not match shared_attn_ids (ind={ind}, "
                         f'attn_type="{t}", reused="{prev_t}")')
             else:
-                attn = Attention(c.dim, c.heads, c.dim_head, c.attn_dropout,
-                                 causal=c.causal, stable=c.stable,
-                                 use_pallas=use_pallas,
-                                 softmax_f32=c.attn_softmax_f32,
-                                 sp_mesh=self.sp_mesh,
-                                 name=f"attn_{aid}")
+                attn = self._make_attention(f"attn_{aid}", use_pallas)
                 shared_attn[aid] = (attn, t)
             if fid in shared_ff:
                 ff = shared_ff[fid]
             else:
-                ff = GEGLUFeedForward(c.dim, c.ff_mult, c.ff_dropout,
-                                      name=f"ff_{fid}")
+                ff = self._make_feed_forward(f"ff_{fid}", ind)
                 shared_ff[fid] = ff
+            layer_kw = dict(sandwich=c.sandwich_norm, shift=c.shift_tokens,
+                            text_len=self.text_len, image_size=fmap,
+                            norm_kind=blk.norm, norm_eps=blk.rms_norm_eps,
+                            layerscale=blk.layerscale)
             attn_layers.append(TransformerLayer(
-                c.dim, ind + 1, attn, sandwich=c.sandwich_norm,
-                shift=c.shift_tokens, text_len=self.text_len, image_size=fmap,
-                name=f"layer_attn_{ind}"))
+                c.dim, ind + 1, attn, name=f"layer_attn_{ind}", **layer_kw))
             ff_layers.append(TransformerLayer(
-                c.dim, ind + 1, ff, sandwich=c.sandwich_norm,
-                shift=c.shift_tokens, text_len=self.text_len, image_size=fmap,
-                name=f"layer_ff_{ind}"))
+                c.dim, ind + 1, ff, name=f"layer_ff_{ind}", **layer_kw))
             layer_types.append(t)
         self.layer_types = layer_types
         self.attn_layers = attn_layers
         self.ff_layers = ff_layers
 
-        self.rotary = None
-        if c.rotary_emb and c.causal:
-            self.rotary = jnp.asarray(
-                dalle_pos_emb(self.text_len, fmap, c.dim_head))
+    # -- the block's kinds (config.BlockConfig) -----------------------------
+    def _make_attention(self, name: str, use_pallas):
+        c, blk = self.cfg, self.cfg.block
+        if blk.attention == "mha":
+            if blk.positions != "dalle_axial":
+                raise ValueError("mha takes the dalle_axial rotary table")
+            return Attention(c.dim, c.heads, c.dim_head, c.attn_dropout,
+                             causal=c.causal, stable=c.stable,
+                             use_pallas=use_pallas,
+                             softmax_f32=c.attn_softmax_f32,
+                             sp_mesh=self.sp_mesh, name=name)
+        if blk.positions != "seq_yarn" or not c.causal or self.sp_mesh:
+            raise ValueError("mla is causal, takes seq_yarn positions and "
+                             "has no sequence-parallel path")
+        # YaRN stretches the softmax by mscale(factor, mscale_all_dim)^2
+        m = yarn_mscale(blk.yarn_factor, blk.yarn_mscale_all_dim)
+        return MLAttention(
+            c.dim, c.heads_held or c.heads, c.heads, blk.q_lora_rank,
+            blk.kv_lora_rank, blk.qk_nope_head_dim, blk.qk_rope_head_dim,
+            blk.v_head_dim,
+            softmax_scale=(blk.qk_nope_head_dim
+                           + blk.qk_rope_head_dim) ** -0.5 * m * m,
+            eps=blk.rms_norm_eps,
+            softmax_f32=c.attn_softmax_f32, name=name)
+
+    def _make_feed_forward(self, name: str, ind: int):
+        c, blk = self.cfg, self.cfg.block
+        if blk.feed_forward == "geglu":
+            return GEGLUFeedForward(c.dim, c.ff_mult, c.ff_dropout, name=name)
+        if blk.feed_forward == "swiglu" or ind < blk.first_dense_layers:
+            return SwiGLUFeedForward(c.dim, blk.intermediate_size, name=name)
+        return MoEFeedForward(
+            c.dim, blk.moe_intermediate_size,
+            experts_held=c.experts_held or blk.n_routed_experts,
+            n_routed_experts=blk.n_routed_experts, n_group=blk.n_group,
+            topk_group=blk.topk_group, top_k=blk.num_experts_per_tok,
+            routed_scale=blk.routed_scaling_factor,
+            n_shared=blk.n_shared_experts, name=name)
+
+    def _refuse_cached(self, what: str):
+        """The cached paths are written for multi-head keys and values in a
+        ``KVCache`` / ``PagedKVCache``; the block kinds without that layout
+        are refused by name, not run wrongly."""
+        blk = self.cfg.block
+        if blk.attention != "mha" or blk.feed_forward == "moe":
+            raise NotImplementedError(
+                f"{what}: the {blk.name} block has no cached decode path "
+                f"(latent keys and values have no KVCache layout, and a "
+                f"routed layer returns counters); it trains through "
+                f"Transformer.__call__ only")
 
     def _dense_mask(self, t):
         m = self.np_masks[t]
@@ -569,15 +647,23 @@ class Transformer(nn.Module):
 
 
     # -- training / full forward ------------------------------------------
-    def __call__(self, x, key_mask=None, deterministic: bool = True):
+    def __call__(self, x, key_mask=None, deterministic: bool = True,
+                 return_aux: bool = False):
         """Sequential execution by default; ``cfg.reversible`` switches to the
         O(1)-activation custom_vjp path (models/reversible.py) — the TPU
         equivalent of the reference's ReversibleSequence. `jax.checkpoint` at
-        the train-step level is the complementary remat lever."""
+        the train-step level is the complementary remat lever.
+
+        ``return_aux``: also return the layers' counters, reduced over depth
+        (``moe_rows_held`` and ``moe_rows_dropped`` summed,
+        ``moe_load_max_over_mean`` of the worst layer); {} for a stack whose
+        layers count nothing."""
         c = self.cfg
         if c.reversible:
-            return self._call_reversible(x, key_mask, deterministic)
+            out = self._call_reversible(x, key_mask, deterministic)
+            return (out, {}) if return_aux else out
         use_remat = c.use_remat and not self.is_initializing()
+        counted = []
         for ind in range(c.depth):
             if use_remat:
                 # real jax.checkpoint per block pair: activations inside the
@@ -586,10 +672,18 @@ class Transformer(nn.Module):
                 # which is O(1) in depth rather than O(depth) checkpoints)
                 blk = nn.remat(_block_body, prevent_cse=False,
                                static_argnums=(3, 4))
-                x = blk(self, x, key_mask, ind, deterministic)
+                x, counters = blk(self, x, key_mask, ind, deterministic)
             else:
-                x = _block_body(self, x, key_mask, ind, deterministic)
-        return x
+                x, counters = _block_body(self, x, key_mask, ind,
+                                          deterministic)
+            if counters:
+                counted.append(counters)
+        if not return_aux:
+            return x
+        aux = {k: (jnp.max if k == "moe_load_max_over_mean" else jnp.sum)(
+            jnp.stack([layer[k] for layer in counted]))
+            for k in (counted[0] if counted else {})}
+        return x, aux
 
     def _call_reversible(self, x, key_mask, deterministic: bool):
         """Unbind each layer into (pure fn, params) pairs and run the
@@ -656,6 +750,7 @@ class Transformer(nn.Module):
     def init_cache(self, batch: int, max_seq: Optional[int] = None,
                    dtype=jnp.float32) -> Dict[str, Any]:
         c = self.cfg
+        self._refuse_cached("init_cache")
         max_seq = max_seq or c.seq_len + 1
         cache: Dict[str, Any] = {}
         d4 = c.dim // 4
@@ -682,6 +777,7 @@ class Transformer(nn.Module):
         shift_tokens off (Transformer.decode_window asserts it), so no
         shift states."""
         c = self.cfg
+        self._refuse_cached("init_cache_paged")
         assert not c.shift_tokens, "paged serve cache requires shift_tokens off"
         from ..ops.paged_kv import PagedKVCache
         return {f"kv_{ind}": PagedKVCache.init(num_blocks, block_tokens,
@@ -692,6 +788,7 @@ class Transformer(nn.Module):
     def prefill(self, x, cache: Dict[str, Any]):
         """Run the full prefix, filling every layer's caches. Returns (y, cache)."""
         c = self.cfg
+        self._refuse_cached("prefill")
         cache = dict(cache)
         for ind in range(c.depth):
             attn_l, ff_l, t = self.attn_layers[ind], self.ff_layers[ind], self.mask_keys[ind]
@@ -717,6 +814,7 @@ class Transformer(nn.Module):
         the samplers build; sparse masks would need per-row mask gathers and
         shift ring buffers are one-token-sequential by construction)."""
         c = self.cfg
+        self._refuse_cached("decode_window")
         assert not c.shift_tokens, (
             "speculative decode does not support shift_tokens")
         assert all(k == "full" for k in self.mask_keys), (
@@ -740,6 +838,7 @@ class Transformer(nn.Module):
         Sparse masks apply via their offset row; causality is implicit
         (reference attention.py:86 'causality is naturally enforced')."""
         c = self.cfg
+        self._refuse_cached("decode_step")
         cache = dict(cache)
         for ind in range(c.depth):
             attn_l, ff_l, t = self.attn_layers[ind], self.ff_layers[ind], self.mask_keys[ind]

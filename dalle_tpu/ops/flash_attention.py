@@ -29,6 +29,7 @@ exercises them on CPU (tests/test_flash_attention.py).
 from __future__ import annotations
 
 import functools
+import logging
 from typing import NamedTuple, Optional
 
 import jax
@@ -469,8 +470,14 @@ def sparsity_fraction(n: int, block_q: int = 128, block_k: int = 128,
 PALLAS_AUTO_MIN_SEQ = 2048
 
 
+@functools.lru_cache(maxsize=None)
+def _say_once(msg: str) -> None:
+    logging.getLogger(__name__).info(msg)
+
+
 def resolve_use_pallas(setting, seq_len: int, backend: Optional[str] = None,
-                       dim_head: int = 64, heads: int = 8):
+                       dim_head: int = 64, heads: int = 8,
+                       attention: str = "mha"):
     """Resolve a config's ``use_pallas`` ("auto" | "fused" | "persist" | on |
     off, bools and their string forms accepted for config round-trips) into
     the per-model mode: "flash" | "fused" | "persist" | False.
@@ -484,7 +491,17 @@ def resolve_use_pallas(setting, seq_len: int, backend: Optional[str] = None,
     pallas-call boundary breaks XLA's layout fusion around it
     (docs/PERF_SMALL.md r4 addendum). "fused" selects its r5 successor
     (ops/fused_attention.py) whose boundary is the qkv projection's own
-    (b, n, 3·h·d) layout."""
+    (b, n, 3·h·d) layout.
+
+    ``attention="mla"`` (latent attention: a query/key width that differs
+    from the value width, one rotary key part shared across heads) is the
+    dense tier whatever the setting and the length: every kernel here
+    assumes one ``dim_head``. Said once in the log."""
+    if attention == "mla":
+        _say_once(f"use_pallas={setting!r}: latent attention (mla) runs the "
+                  f"dense tier at every length; the flash, fused and "
+                  f"persistent kernels assume one head width")
+        return False
     from .fused_attention import fused_fits, fused_fwd_fits
     from .persistent_attention import persistent_fits
     if setting is True:

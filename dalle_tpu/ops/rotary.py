@@ -20,6 +20,7 @@ runtime cost beyond the fused multiply-adds of ``apply_rotary``.
 from __future__ import annotations
 
 import math
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -54,6 +55,45 @@ def apply_rotary(freqs: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
     t_rot, t_pass = t[..., :rot_dim], t[..., rot_dim:]
     t_rot = t_rot * jnp.cos(freqs) + rotate_half(t_rot) * jnp.sin(freqs)
     return jnp.concatenate((t_rot, t_pass), axis=-1)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor (Peng et al. 2023, eq. 22 with DeepSeek-V2's
+    coefficient): 0.1 * mscale * ln(factor) + 1 beyond the original context."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def seq_yarn_table(n: int, dim: int, theta: float = 10000.0,
+                   scaling: Optional[dict] = None) -> Tuple[np.ndarray, float]:
+    """Rotary angles over sequence positions 0..n-1 for ``dim`` features
+    (each frequency doubled adjacently, as ``apply_rotary`` pairs them), with
+    YaRN's blend of frequencies when ``scaling`` gives ``factor`` > 1:
+    a frequency that turns more than ``beta_fast`` times within
+    ``original_max_position`` positions is kept, one that turns fewer than
+    ``beta_slow`` times is divided by ``factor``, a linear ramp between.
+    Returns (angles (n, dim), the factor cos and sin are scaled by:
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim))."""
+    inv = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    cos_sin_scale = 1.0
+    factor = float((scaling or {}).get("factor", 1.0))
+    if factor > 1:
+        sc = scaling
+
+        def correction_dim(rotations):
+            return (dim * math.log(sc["original_max_position"]
+                                   / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(correction_dim(sc["beta_fast"])), 0)
+        high = min(math.ceil(correction_dim(sc["beta_slow"])), dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+        inv = inv / factor * ramp + inv * (1.0 - ramp)
+        cos_sin_scale = (yarn_mscale(factor, sc.get("mscale", 1.0))
+                         / yarn_mscale(factor, sc.get("mscale_all_dim", 0.0)))
+    angles = np.repeat(np.outer(np.arange(n, dtype=np.float64), inv), 2, -1)
+    return angles.astype(np.float32), cos_sin_scale
 
 
 def dalle_pos_emb(text_len: int, image_fmap_size: int, dim_head: int) -> np.ndarray:

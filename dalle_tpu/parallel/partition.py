@@ -37,9 +37,23 @@ DEFAULT_RULES: Tuple[Tuple[str, P], ...] = (
     # attention projections
     (r".*attn.*(to_qkv|to_q|to_kv|query|key|value)/kernel$", P("fsdp", "tp")),
     (r".*attn.*(to_out|out_proj)/kernel$",                   P("tp", "fsdp")),
+    # latent attention (models/latent_moe.py): the per-head expansions and
+    # the output projection shard their head-structured dim over tp like
+    # to_qkv / to_out; the latents' down-projections have no head dim
+    (r".*attn.*(q_b|kv_b)/kernel$",                          P("fsdp", "tp")),
+    (r".*attn.*(q_a|kv_a)/kernel$",                          P("fsdp", None)),
+    (r".*attn.*/o/kernel$",                                  P("tp", "fsdp")),
     # feed-forward
-    (r".*(ff|mlp).*(w1|wi|fc1|dense_in)/kernel$",            P("fsdp", "tp")),
-    (r".*(ff|mlp).*(w2|wo|fc2|dense_out)/kernel$",           P("tp", "fsdp")),
+    (r".*(ff|mlp).*(w1|wi|fc1|dense_in|w_gate|w_up)/kernel$", P("fsdp", "tp")),
+    (r".*(ff|mlp).*(w2|wo|fc2|dense_out|w_down)/kernel$",    P("tp", "fsdp")),
+    # routed experts: three stacked (held, in, out) leaves. There is no
+    # expert axis yet, so the leading axis is whole on every device and the
+    # matrices shard like a feed-forward's; the grouped product runs on a
+    # one-device mesh only (train/trainer_dalle.py refuses others). The
+    # router is small and replicated.
+    (r".*ff.*/(e_gate|e_up)$",                               P(None, "fsdp", "tp")),
+    (r".*ff.*/e_down$",                                      P(None, "tp", "fsdp")),
+    (r".*ff.*/router$",                                      P()),
     # embeddings + output head. Vocab shards over BOTH axes with the feature
     # dim replicated: a gather from a vocab-sharded table emits a replicated
     # feature dim, so activations stay batch-sharded at remat-block boundaries

@@ -237,6 +237,15 @@ class DecodeEngine:
                  kv_pool_blocks: Optional[int] = None,
                  radix_cache: bool = True):
         c = model.cfg
+        if not c.block.is_default:
+            # the engine's cache is multi-head keys and values in a KVCache
+            # or PagedKVCache; a block without that layout is refused by
+            # name, not run wrongly
+            raise NotImplementedError(
+                f"the serve engine decodes the default mha+geglu block only; "
+                f"the {c.block.name} block has no cached decode path yet "
+                f"(latent keys and values through KVCache / PagedKVCache and "
+                f"the decode kernels)")
         attn_types = tuple(c.attn_types) or ("full",)
         if any(t != "full" for t in attn_types) or c.shift_tokens:
             # same constraint set as speculative decode: per-row windows

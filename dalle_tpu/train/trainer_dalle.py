@@ -163,6 +163,12 @@ class DalleTrainer(BaseTrainer):
                 f"sequence parallelism (sp > 1) supports attn_types {sp_ok}; "
                 f"got unsupported {bad} (tabled 'sparse' masks need host-side "
                 "block lists the ring cannot shard)")
+        if model_cfg.block.feed_forward == "moe" and self.mesh.size > 1:
+            raise NotImplementedError(
+                f"the {model_cfg.block.name} block trains on a one-device "
+                f"mesh: parallel/partition.py has no expert axis and the "
+                f"grouped product no exchange across devices yet (mesh "
+                f"{dict(self.mesh.shape)})")
         with span("init/model"):
             self.model, params = init_dalle(
                 model_cfg, self.base_key,
@@ -199,6 +205,20 @@ class DalleTrainer(BaseTrainer):
         text, image_ids = batch
         return (self._put(text, np.int32, stacked),
                 self._put(image_ids, np.int32, stacked))
+
+    def _record(self, step, device_metrics, stamp, part):
+        """A routed step that dropped rows did not compute the model: every
+        fetched record passes through here once, and the first that carries
+        ``moe_rows_dropped`` > 0 stops the run."""
+        metrics = super()._record(step, device_metrics, stamp, part)
+        if metrics.get("moe_rows_dropped", 0) > 0:
+            raise RuntimeError(
+                f"step {step} dropped {metrics['moe_rows_dropped']:.0f} "
+                f"routed rows (moe_rows_dropped): the held experts drew "
+                f"more than models/latent_moe.ROW_BUFFER times their "
+                f"uniform share; routing has collapsed onto this chip's "
+                f"experts")
+        return metrics
 
     # -- single step ---------------------------------------------------------
     def train_step(self, text: np.ndarray, image_ids: np.ndarray):

@@ -29,6 +29,7 @@ from dalle_tpu.ops.decode_attention import (decode_attend_kernel,
                                             decode_attend_window_paged)
 from dalle_tpu.ops.flash_attention import flash_attention
 from dalle_tpu.ops.fused_attention import fused_qkv_attention
+from dalle_tpu.ops.grouped_matmul import grouped_matmul
 from dalle_tpu.ops.paged_kv import PagedKVCache
 
 # DALL·E-1.4B decode shapes as the serve engine builds them: 8 slots,
@@ -157,3 +158,27 @@ def test_flash_fwd_bwd_512(one_chip, masked):
             if masked else None)
     qkv = [_sds(one_chip, (2, 2, n, 64), jnp.bfloat16)] * 3
     _mosaic_text(_flash_loss(mask), *qkv)
+
+
+@pytest.mark.parametrize("k,n", [(5120, 1536), (1536, 5120)],
+                         ids=["gate_up_5120x1536", "down_1536x5120"])
+def test_grouped_product_fwd_bwd_dsv2_share(one_chip, k, n):
+    """The routed experts' three kernels (moe_gmm_fwd, moe_gmm_dlhs,
+    moe_gmm_drhs) at train_dsv2_share16_fit's shapes: the 15,360-row buffer
+    of batch 8 x 1280 tokens (models/latent_moe.row_buffer_size), 10 held
+    experts."""
+    from dalle_tpu.models.latent_moe import row_buffer_size
+    rows = row_buffer_size(8 * 1280, 6, 10, 160)
+
+    def loss(lhs, rhs, sizes):
+        out = grouped_matmul(lhs, rhs, sizes, use_kernel=True,
+                             interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _mosaic_text(jax.grad(loss, argnums=(0, 1)),
+                        _sds(one_chip, (rows, k), jnp.bfloat16),
+                        _sds(one_chip, (10, k, n), jnp.bfloat16),
+                        _sds(one_chip, (10,), jnp.int32))
+    for kernel in ("moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
+        # jax.grad of a sum needs no forward output: XLA may drop that call
+        assert kernel in text or kernel == "moe_gmm_fwd", kernel
