@@ -36,6 +36,7 @@ from ..config import DalleConfig
 from ..ops.quantize_weights import QDense
 from ..ops.sampling import (gumbel_sample, gumbel_sample_rows,
                             prob_mask_like, top_k_filter)
+from ..ops.table_lookup import grad_path, take_rows
 from .latent_moe import RMSNorm
 from .transformer import DivideMax, Transformer
 
@@ -123,7 +124,7 @@ class DALLE(nn.Module):
         ops/quantize_weights.py) dequantize per gathered row — only the int8
         bytes cross HBM."""
         tab = self.shared_emb
-        rows = jnp.take(tab, ids, axis=0)
+        rows = take_rows(tab, ids)
         if tab.dtype == jnp.int8:
             scale = self.get_variable("quant", "shared_emb_scale")
             dt = self.logits_bias.dtype
@@ -133,12 +134,12 @@ class DALLE(nn.Module):
     def _embed_text_ids(self, ids):
         if self.cfg.share_input_output_emb:
             return self._shared_rows(ids)
-        return self.text_emb(ids)
+        return take_rows(self.text_emb.embedding, ids)
 
     def _embed_image_ids(self, ids):
         if self.cfg.share_input_output_emb:
             return self._shared_rows(ids + self.num_text_tokens)
-        return self.image_emb(ids)
+        return take_rows(self.image_emb.embedding, ids)
 
     def _logits(self, x):
         x = self.final_norm(x)
@@ -665,6 +666,24 @@ class DALLE(nn.Module):
         final = sample_text(last_logits, jax.random.fold_in(key, n_new))
         toks = jnp.moveaxis(toks, 0, 1)
         return jnp.concatenate([text, toks, final[:, None]], axis=1)
+
+
+def table_grad_paths(cfg: DalleConfig, dtype, batch: int) -> Dict[str, dict]:
+    """What the training forward's token lookups give as their tables'
+    backward, per table: ``ops/table_lookup.grad_path``'s name beside the
+    shapes it was chosen from and the ids a step of ``batch`` looks up.
+    ``dtype`` is the compute dtype the tables are cast to."""
+    text_ids = batch * (cfg.text_seq_len + 1)
+    image_ids = batch * cfg.image_seq_len
+    if cfg.share_input_output_emb:
+        tables = {"shared_emb": (cfg.total_tokens, text_ids + image_ids)}
+    else:
+        tables = {"text_emb": (cfg.total_tokens - cfg.image_vocab_size,
+                               text_ids),
+                  "image_emb": (cfg.image_vocab_size, image_ids)}
+    return {name: {"path": grad_path(rows, cfg.dim, dtype), "rows": rows,
+                   "width": cfg.dim, "ids": ids}
+            for name, (rows, ids) in tables.items()}
 
 
 def init_dalle(cfg: DalleConfig, key: jax.Array, batch: int = 1, sp_mesh=None):

@@ -25,7 +25,7 @@ import numpy as np
 import optax
 
 from ..config import DalleConfig, TrainConfig
-from ..models.dalle import DALLE, init_dalle
+from ..models.dalle import DALLE, init_dalle, table_grad_paths
 from ..obs import span
 from .base_trainer import BaseTrainer
 from .metrics import ThroughputMeter, count_params, transformer_train_flops
@@ -175,16 +175,18 @@ class DalleTrainer(BaseTrainer):
                 sp_mesh=self.mesh if sp > 1 else None)
         self.state = self._create_state(params, self.model.apply)
         use_dropout = (model_cfg.attn_dropout > 0 or model_cfg.ff_dropout > 0)
-        with span("init/build_step"):
+        dtype = compute_dtype(train_cfg.precision)
+        # the tables' backward is chosen from shapes, once per compile
+        with span("init/build_step", **table_grad_paths(
+                model_cfg, jnp.float32 if dtype is None else dtype,
+                train_cfg.batch_size)):
             self.step_fn = make_dalle_train_step(
                 self.model, null_cond_prob=null_cond_prob,
-                use_dropout=use_dropout,
-                dtype=compute_dtype(train_cfg.precision), state=self.state,
+                use_dropout=use_dropout, dtype=dtype, state=self.state,
                 health=bool(train_cfg.obs.health),
                 health_depth=train_cfg.obs.health_group_depth)
         self._multi_step_kw = dict(null_cond_prob=null_cond_prob,
-                                   use_dropout=use_dropout,
-                                   dtype=compute_dtype(train_cfg.precision),
+                                   use_dropout=use_dropout, dtype=dtype,
                                    health=bool(train_cfg.obs.health),
                                    health_depth=train_cfg.obs.health_group_depth)
         self._multi_step_fn = None   # built lazily on first train_steps()

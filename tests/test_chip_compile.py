@@ -182,3 +182,25 @@ def test_grouped_product_fwd_bwd_dsv2_share(one_chip, k, n):
     for kernel in ("moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
         # jax.grad of a sum needs no forward output: XLA may drop that call
         assert kernel in text or kernel == "moe_gmm_fwd", kernel
+
+
+@pytest.mark.parametrize("rows,ids", [(8192, (8, 1024)), (4608, (8, 257))],
+                         ids=["image_emb", "text_emb"])
+def test_table_product_backward_dsv2_share(one_chip, rows, ids):
+    """train_dsv2_share16_fit's two token tables (5120 wide, bfloat16): the
+    backward is the product and no scatter, and the compiler folds the
+    one-hot into the product's operand, so the program has no temporary
+    (an ids x rows one-hot in memory would be 134 MB for the image table)."""
+    from dalle_tpu.ops.table_lookup import grad_path, take_rows
+    assert grad_path(rows, 5120, jnp.bfloat16) == "product"
+
+    def d_table(table, i, g):
+        return jax.vjp(lambda t: take_rows(t, i), table)[1](g)[0]
+
+    compiled = jax.jit(d_table).lower(
+        _sds(one_chip, (rows, 5120), jnp.bfloat16),
+        _sds(one_chip, ids, jnp.int32),
+        _sds(one_chip, ids + (5120,), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert " scatter(" not in text and "convolution(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20

@@ -1,13 +1,23 @@
 """DALLE model tests: vocab layout, loss, masks, generation consistency, CLIP."""
 
+import hashlib
+import json
+import os
+import re
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dalle_tpu.config import ClipConfig, DalleConfig
+from dalle_tpu import obs
+from dalle_tpu.config import (BlockConfig, ClipConfig, DalleConfig, MeshConfig,
+                              OptimConfig, PrecisionConfig, TrainConfig)
+from dalle_tpu.models import dalle as dalle_module
 from dalle_tpu.models.clip import CLIP, init_clip
-from dalle_tpu.models.dalle import DALLE, init_dalle
+from dalle_tpu.models.dalle import DALLE, init_dalle, table_grad_paths
+from dalle_tpu.ops import table_lookup
 
 CFG = DalleConfig(num_text_tokens=100, text_seq_len=8, dim=32, depth=2, heads=2,
                   dim_head=16, image_vocab_size=64, image_fmap_size=4,
@@ -246,3 +256,288 @@ def test_chunked_loss_matches_full():
     for a, b in zip(jax.tree.leaves(g_full), jax.tree.leaves(g_chunk)):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=2e-5, atol=1e-6)
+
+
+# -- the token tables' lookup and its backward (ops/table_lookup.py) ----------
+
+ROWS = 90          # a tied table of 58 text rows and 32 codes
+ID_PATTERNS = {
+    "all_equal": lambda r: np.full((130,), 7),
+    "first_and_last_row": lambda r: np.array([0, ROWS - 1, 0, ROWS - 1, ROWS - 1]),
+    "count_of_1031": lambda r: r.integers(0, ROWS, (1031,)),
+    "count_of_one": lambda r: np.array([5]),
+    "batch_of_3x50": lambda r: r.integers(0, ROWS, (3, 50)),
+    "tied_text_then_codes_offset": lambda r: np.concatenate(
+        [r.integers(0, 58, (2, 9)), r.integers(0, 32, (2, 16)) + 58], axis=1),
+}
+
+
+def _steer_to_product(monkeypatch):
+    """The rule takes the product for every 16-bit table, whatever its
+    width (a test's way to reach the path at a tiny width)."""
+    monkeypatch.setattr(table_lookup, "WIDE_ROW", 0)
+
+
+def _within_one_rounding(got, exact32):
+    """``got`` (bfloat16) is the float32 sum rounded once: half a bfloat16
+    step of the sum, with the float32 sum's own order-of-addition slack."""
+    got = np.asarray(got.astype(jnp.float32))
+    np.testing.assert_allclose(got, exact32, rtol=2.0 ** -8, atol=1e-5)
+
+
+@pytest.mark.parametrize("pattern", sorted(ID_PATTERNS))
+@pytest.mark.parametrize("width", [512, 5120])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_table_lookup_forward_is_take_and_backward_is_the_float32_sum(
+        pattern, width, dtype, monkeypatch):
+    rng = np.random.default_rng(5)
+    dtype = jnp.dtype(dtype)
+    ids = jnp.asarray(ID_PATTERNS[pattern](rng), jnp.int32)
+    table = jnp.asarray(rng.standard_normal((ROWS, width)), dtype)
+    # multiples of 1/64: every float32 partial sum is exact in any order
+    g = jnp.asarray(rng.integers(-256, 257, ids.shape + (width,)) / 64, dtype)
+    exact = np.asarray(jnp.zeros((ROWS, width), jnp.float32)
+                       .at[ids.reshape(-1)].add(
+                           g.reshape(-1, width).astype(jnp.float32)))
+
+    rows = jnp.take(table, ids, axis=0)
+    embed = nn.Embed(ROWS, width, param_dtype=dtype)
+    for lookup in (table_lookup.take_rows, table_lookup._take_rows,
+                   lambda t, i: embed.apply({"params": {"embedding": t}}, i)):
+        got = lookup(table, ids)
+        assert got.dtype == dtype and bool((got == rows).all())
+
+    # the product, reached directly: exact in float32, one rounding in bf16
+    d_table, = jax.vjp(lambda t: table_lookup._take_rows(t, ids), table)[1](g)
+    assert d_table.dtype == dtype and d_table.shape == table.shape
+    if dtype == jnp.float32:
+        assert bool((np.asarray(d_table) == exact).all())
+    else:
+        _within_one_rounding(d_table, exact)
+
+    # through the rule: the product where it picks it, else take's own
+    path = table_lookup.grad_path(ROWS, width, dtype)
+    assert path == ("product" if (width, dtype) == (5120, jnp.bfloat16)
+                    else "scatter")
+    ruled, = jax.vjp(lambda t: table_lookup.take_rows(t, ids), table)[1](g)
+    if path == "product":
+        assert bool((ruled == d_table).all())
+    else:
+        seeds, = jax.vjp(lambda t: jnp.take(t, ids, axis=0), table)[1](g)
+        assert bool((ruled == seeds).all())
+
+
+def test_table_lookup_repeated_ids_sum_in_float32():
+    """130 copies of one id, random bfloat16 rows: the product rounds the
+    float32 sum once, where a bfloat16 running sum drifts."""
+    rng = np.random.default_rng(6)
+    ids = jnp.full((130,), 3, jnp.int32)
+    g = jnp.asarray(rng.standard_normal((130, 256)), jnp.bfloat16)
+    table = jnp.zeros((8, 256), jnp.bfloat16)
+    d_table, = jax.vjp(lambda t: table_lookup._take_rows(t, ids), table)[1](g)
+    exact = np.zeros((8, 256), np.float32)
+    exact[3] = np.asarray(g.astype(jnp.float32)).sum(0, dtype=np.float64)
+    _within_one_rounding(d_table, exact)
+
+
+BENCH_CONFIGS = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                             "configs")
+
+
+@pytest.mark.parametrize("config, batch, expected", [
+    ("rudalle_malevich", 4, {"text_emb": ("scatter", 16512, 2048, 516),
+                             "image_emb": ("scatter", 8192, 2048, 4096)}),
+    ("dalle_small", 64, {"text_emb": ("scatter", 10256, 512, 16448),
+                         "image_emb": ("scatter", 8192, 512, 16384)}),
+    ("deepseek_v2_share16", 8, {"text_emb": ("product", 4608, 5120, 2056),
+                                "image_emb": ("product", 8192, 5120, 8192)}),
+])
+def test_grad_path_for_the_benchmarks_real_tables(config, batch, expected):
+    with open(os.path.join(BENCH_CONFIGS, f"{config}.json")) as f:
+        cfg = DalleConfig(**json.load(f)["model"])
+    got = table_grad_paths(cfg, jnp.bfloat16, batch)
+    assert {k: tuple(v.values()) for k, v in got.items()} == expected
+    # a float32 table would need six MXU passes: it keeps the scatter
+    assert {v["path"] for v in table_grad_paths(cfg, jnp.float32,
+                                                batch).values()} == {"scatter"}
+
+
+@pytest.mark.parametrize("rows, width, dtype, expected", [
+    (8192, 4096, "bfloat16", "scatter"),     # the scatter's steady side
+    (8192, 4224, "bfloat16", "product"),
+    (8192, 8192, "float16", "product"),
+    (8192, 5120, "float32", "scatter"),      # six MXU passes
+    (16384, 6144, "bfloat16", "scatter"),    # the product grows with the rows
+    (8192, 5120, "int8", "scatter"),         # the decode path's table
+])
+def test_grad_path_reads_shapes_and_dtypes(rows, width, dtype, expected):
+    assert table_lookup.grad_path(rows, width, dtype) == expected
+
+
+_MLA = dict(attention="mla", norm="rmsnorm", layerscale=False,
+            positions="seq_yarn", q_lora_rank=24, kv_lora_rank=16,
+            qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+            intermediate_size=48)
+_MOE = dict(_MLA, feed_forward="moe", first_dense_layers=1,
+            moe_intermediate_size=16, n_routed_experts=16, n_shared_experts=2,
+            num_experts_per_tok=3, n_group=4, topk_group=2,
+            routed_scaling_factor=16.0)
+_BASE = dict(num_text_tokens=50, text_seq_len=8, dim=32, depth=2, heads=4,
+             dim_head=8, image_vocab_size=32, image_fmap_size=4,
+             image_size=32, loss_chunk=8)
+# configuration, and its parameter tree's digest at the seed commit
+# (sorted "path shape dtype" lines, sha256) with the number of leaves
+BLOCK_KINDS = {
+    "geglu": (DalleConfig(**_BASE), "a79f30d02d14dacb", 32),
+    "tied": (DalleConfig(**_BASE, share_input_output_emb=True),
+             "4a1c7c57d3b0dced", 30),
+    "swiglu": (DalleConfig(**_BASE, block=BlockConfig(feed_forward="swiglu",
+                                                      **_MLA)),
+               "096b2b668a314735", 29),
+    "moe": (DalleConfig(**_BASE, block=BlockConfig(**_MOE), heads_held=2,
+                        experts_held=4), "4d3f2b20753a6fe0", 33),
+}
+
+
+def _leaves(tree):
+    return {jax.tree_util.keystr(p): v
+            for p, v in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+@pytest.mark.parametrize("kind", sorted(BLOCK_KINDS))
+def test_training_gradient_agrees_with_the_seeds_path(kind, monkeypatch):
+    """bfloat16 compute over float32 masters, as the trainer's step: with the
+    product steered on, every leaf's gradient is the seed's (``jnp.take``'s
+    scatter); the tables' differ by the roundings the scatter adds."""
+    cfg, digest, n_leaves = BLOCK_KINDS[kind]
+    model, params = init_dalle(cfg, jax.random.PRNGKey(0), batch=2)
+    leaves = _leaves(params)
+    lines = sorted(f"{k} {tuple(v.shape)} {v.dtype}" for k, v in leaves.items())
+    assert len(lines) == n_leaves
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16] == digest
+
+    rng = np.random.default_rng(7)
+    text = jnp.asarray(rng.integers(0, 50, (4, 8)), jnp.int32)   # 0s: pads
+    ids = jnp.asarray(rng.integers(0, 32, (4, 16)), jnp.int32)
+
+    def grads():      # a new function each call: traced under the rule as set
+        from dalle_tpu.train.train_state import cast_floating
+        return jax.jit(jax.grad(lambda p: model.apply(
+            cast_floating(p, jnp.bfloat16), text, ids, return_loss=True)[0]))(
+                params)
+
+    with monkeypatch.context() as seed:
+        seed.setattr(dalle_module, "take_rows",
+                     lambda table, i: jnp.take(table, i, axis=0))
+        theirs = _leaves(grads())
+    _steer_to_product(monkeypatch)
+    ours = _leaves(grads())
+    tables = [k for k in ours if re.search(r"(text_emb|image_emb)'\]\['embedding|shared_emb", k)]
+    assert len(tables) == (1 if kind == "tied" else 2)
+    for name, g in ours.items():
+        a, b = np.asarray(g), np.asarray(theirs[name])
+        if name in tables:
+            # a few bfloat16 steps of the largest entry: the scatter rounds
+            # after every repeated id (each pad id repeats down the batch)
+            np.testing.assert_allclose(a, b, atol=2.0 ** -6 * np.abs(b).max(),
+                                       err_msg=name)
+            assert np.abs(b).max() > 0
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def _tiny_trainer(tmp_path, mesh_cfg, compute, devices=None):
+    from dalle_tpu.parallel.mesh import build_mesh
+    from dalle_tpu.train.trainer_dalle import DalleTrainer
+    # a width no other test trains, so no cached step is handed over
+    cfg = DalleConfig(num_text_tokens=32, text_seq_len=8, dim=40, depth=1,
+                      heads=2, dim_head=20, image_size=16, image_vocab_size=32,
+                      image_fmap_size=4)
+    tc = TrainConfig(batch_size=8, checkpoint_dir=str(tmp_path),
+                     preflight_checkpoint=False, mesh=mesh_cfg,
+                     precision=PrecisionConfig(compute=compute),
+                     optim=OptimConfig(learning_rate=1e-2))
+    return DalleTrainer(cfg, tc, mesh=build_mesh(mesh_cfg, devices=devices))
+
+
+def _step_text(tr):
+    """The trainer's step body, lowered: a new body each call, so that no
+    trace made under another rule is handed over."""
+    from dalle_tpu.train.trainer_dalle import _dalle_step_body
+    body = _dalle_step_body.__wrapped__(tr.model, dtype=jnp.bfloat16)
+    text = np.ones((8, 8), np.int32)
+    ids = np.zeros((8, 16), np.int32)
+    return jax.jit(body).lower(tr.state, *tr._put_batch((text, ids)),
+                               jax.random.PRNGKey(0)).as_text()
+
+
+def _table_scatters(stablehlo: str) -> int:
+    """scatters into a table-shaped operand: (40, 40) text, (32, 40) codes."""
+    return len(re.findall(r"\}\) : \(tensor<(?:40|32)x40xbf16>, "
+                          r"tensor<\d+x\d+x1xi32>", stablehlo))
+
+
+def test_trainer_step_loses_two_scatters_where_the_rule_picks_the_product(
+        tmp_path, monkeypatch):
+    one = dict(mesh_cfg=MeshConfig(), devices=jax.devices()[:1])
+    tracer = obs.configure()
+    try:
+        rule_keeps = _step_text(_tiny_trainer(tmp_path / "a", compute="bfloat16",
+                                              **one))
+        with monkeypatch.context() as seed:
+            seed.setattr(dalle_module, "take_rows",
+                         lambda table, i: jnp.take(table, i, axis=0))
+            seeds_text = _step_text(_tiny_trainer(
+                tmp_path / "b", compute="bfloat16", **one))
+        _steer_to_product(monkeypatch)
+        steered = _tiny_trainer(tmp_path / "c", compute="bfloat16", **one)
+        rule_picks = _step_text(steered)
+        spans = [s for s in tracer.snapshot_spans()
+                 if s[0] == "init/build_step"]
+    finally:
+        obs.disable()
+    assert rule_keeps == seeds_text          # unchanged: the seed's
+    n = _table_scatters(rule_keeps)
+    assert n >= 2 and _table_scatters(rule_picks) == n - 2
+    assert rule_picks.count('"stablehlo.scatter"') == \
+        rule_keeps.count('"stablehlo.scatter"') - 2
+    # the span says what was chosen, per table
+    assert spans[0][5]["text_emb"] == {"path": "scatter", "rows": 40,
+                                       "width": 40, "ids": 72}
+    assert spans[-1][5] == {
+        "text_emb": {"path": "product", "rows": 40, "width": 40, "ids": 72},
+        "image_emb": {"path": "product", "rows": 32, "width": 40, "ids": 128}}
+
+
+def test_sharded_product_backward_matches_one_device(tmp_path, monkeypatch):
+    """text_emb's rows over ("tp", "fsdp"), image_emb replicated, the batch
+    over dp x fsdp: the product's table gradients and the first loss are one
+    device's."""
+    from dalle_tpu.train.trainer_dalle import _make_dalle_loss_fn
+    _steer_to_product(monkeypatch)
+    rng = np.random.RandomState(3)
+    text = rng.randint(0, 32, (8, 8))
+    ids = rng.randint(0, 32, (8, 16))
+    got = {}
+    for name, mesh_cfg, devices in [
+            ("mesh", MeshConfig(dp=2, fsdp=2, tp=2), None),
+            ("one", MeshConfig(), jax.devices()[:1])]:
+        tr = _tiny_trainer(tmp_path / name, mesh_cfg, "bfloat16", devices)
+        emb = tr.state.params["params"]["text_emb"]["embedding"]
+        if name == "mesh":
+            assert emb.sharding.spec[0] == ("tp", "fsdp")
+        loss_fn = _make_dalle_loss_fn(tr.model, null_cond_prob=0.0,
+                                      use_dropout=False, dtype=jnp.bfloat16)
+        (loss, _), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
+            tr.state.params, *tr._put_batch((text, ids)),
+            jax.random.PRNGKey(0))
+        got[name] = (float(loss), grads["params"])
+        assert tr.train_step(text, ids)["loss"] == pytest.approx(float(loss),
+                                                                 rel=1e-6)
+    assert got["mesh"][0] == pytest.approx(got["one"][0], rel=2e-3)
+    for table in ("text_emb", "image_emb"):
+        a = np.asarray(got["mesh"][1][table]["embedding"])
+        b = np.asarray(got["one"][1][table]["embedding"])
+        np.testing.assert_allclose(a, b, atol=2.0 ** -6 * np.abs(b).max(),
+                                   err_msg=table)
+        assert np.abs(b).max() > 0
