@@ -4,9 +4,10 @@
 Drives the main path once on ONE TPU chip through the entry points a user
 calls, at the full width and depth of DALL·E-1.4B (24L, 14 heads x 128, dim
 1792, CLIP text vocab 49,408, 256 text + 256 image tokens, image vocab 8,192
-— the bench.py configuration), with random weights made from a seed:
+— widths no published model has; ROADMAP.md queues their removal), with
+random weights made from a seed:
 
-  train_1p4b   ``DalleTrainer.fit`` with the bench.py recipe (Adafactor,
+  train_1p4b   ``DalleTrainer.fit`` with the flagship cell's recipe (Adafactor,
                grad_clip_norm 0.5, loss_chunk 128, bf16 scores, no remat,
                batch 8): a scanned ``train_steps`` dispatch plus single
                steps on one repeated batch. Loss finite at every step and
@@ -67,12 +68,12 @@ PHASE_TIMEOUT_S = 900
 TEACHER_FORCED_MIN_AGREE = 0.90
 TEACHER_FORCED_MAX_GAP = 3.0
 
-# DALL·E-1.4B, the bench.py configuration
+# DALL·E-1.4B at 14 x 128 (the benchmark's flagship is rudalle_malevich.json)
 FLAGSHIP = dict(
     num_text_tokens=49408, text_seq_len=256, dim=1792, depth=24, heads=14,
     dim_head=128, image_size=128, image_vocab_size=8192, image_fmap_size=16,
     attn_softmax_f32=False, loss_chunk=128, use_remat=False)
-# DALL·E-small (__graft_entry__.entry / scripts/bench_sweep.py SMALL)
+# DALL·E-small (__graft_entry__.entry; benchmarks/configs/dalle_small.json)
 SMALL = dict(
     num_text_tokens=10000, text_seq_len=256, dim=512, depth=12, heads=8,
     dim_head=64, image_size=128, image_vocab_size=8192, image_fmap_size=16,
@@ -249,10 +250,10 @@ def phase_train_1p4b(model_kw=FLAGSHIP, batch: int = 8) -> dict:
     steady_s = time.perf_counter() - t0
     steady_compiles = ledger.compiles - warm_compiles
 
-    # bench.py times around block_until_ready: does it wait for the device?
-    # One more scanned dispatch with no metrics fetch (bench.py's
-    # metrics_every), then how long a scalar pull still has to wait once
-    # block_until_ready has returned.
+    # a timing stops at block_until_ready: does that wait for the device?
+    # One more scanned dispatch with no metrics fetch (metrics_every), then
+    # how long a scalar pull still has to wait once block_until_ready has
+    # returned.
     trainer.train_cfg = trainer.train_cfg.replace(metrics_every=1000)
     t0 = time.perf_counter()
     trainer.train_steps(np.stack([text, text]), np.stack([ids, ids]))

@@ -30,7 +30,7 @@ from .core import REPO_ROOT, FileContext, Finding, ProjectRule, register_rule
 
 _FUSED_PATH = "dalle_tpu/ops/fused_attention.py"
 
-# the one measured calibration point (docs/PERF_SMALL.md r5, commit b695782):
+# the one measured calibration point (commit b695782):
 # medium config n=513, h·d=1024; compiler reported 25.68M scoped-vmem demand;
 # the tier that admits it is 32M.
 _CAL_N, _CAL_HD = 513, 1024

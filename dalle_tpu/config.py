@@ -321,11 +321,9 @@ class TransformerConfig(ConfigBase):
     shared_attn_ids: Optional[Tuple[int, ...]] = None
     shared_ff_ids: Optional[Tuple[int, ...]] = None
     optimize_for_inference: bool = False  # sparse→dense+static-mask swap
-    # pallas attention kernels: "auto" (default) self-selects by the measured
-    # crossovers — flash at seq ≥ 2048 on TPU, the fused-boundary kernel
-    # (ops/fused_attention.py) at mid lengths where it fits scoped VMEM,
-    # dense otherwise (ops/flash_attention.resolve_use_pallas); "fused"/
-    # "persist" force the mid-length kernels, "on"/"off" (or bools) override
+    # auto | off. "auto": ops.attention.attention_tier chooses the training
+    # attention kernel from seq_len, heads, dim_head and the backend (flash,
+    # fused or dense); "off" (or False) is the dense tier everywhere
     use_pallas: str = "auto"
     # f32 attention softmax is the safe default; False keeps scores bf16 —
     # the dominant HBM tensor (big train-throughput win, tiny numeric delta)
@@ -366,7 +364,7 @@ class DalleConfig(ConfigBase):
     share_input_output_emb: bool = False
     reversible: bool = False
     use_remat: bool = True
-    use_pallas: str = "auto"   # auto | fused | persist | on | off (see TransformerConfig)
+    use_pallas: str = "auto"   # auto | off (ops.attention.attention_tier)
     attn_softmax_f32: bool = True
     sparse_block_size: int = 128
     sparse_attn_kernel: int = 5

@@ -73,8 +73,8 @@ class MLAttention(nn.Module):
     (``qk_rope_head_dim``) computed once and shared by all heads. A head's
     query and key are ``qk_nope_head_dim + qk_rope_head_dim`` wide, its value
     ``v_head_dim``: ``attend`` takes the two widths as they come. Always the
-    dense tier (``resolve_use_pallas``: the fused and flash kernels assume
-    one head width)."""
+    dense tier: the fused and flash kernels assume one head width, so
+    ``Transformer.setup`` builds this layer without asking for a tier."""
     dim: int
     heads_held: int
     heads_total: int

@@ -8,7 +8,7 @@ TPU-first redesign decisions:
   * Every sparse attention variant is the dense MXU kernel + a compile-time
     static mask (ops/attn_masks.py). The reference itself proves mask-equivalence
     via `optimize_for_inference` (transformer.py:333-350). Pallas kernels slot in
-    behind the same interface for long sequences (cfg.use_pallas).
+    behind the same interface (ops.attention.attention_tier chooses).
   * The decode cache is a pytree of preallocated buffers threaded functionally
     (static shapes under jit/scan) — replacing the reference's mutated dicts,
     growing concats, and deques (transformer.py:38-71,138-153; attention.py:71-76).
@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import TransformerConfig
-from ..ops.attention import (KVCache, attend, cached_attend,
+from ..ops.attention import (KVCache, attend, attention_tier, cached_attend,
                              cached_attend_window)
 from ..ops.attn_masks import build_mask
 from ..ops.quantize_weights import QDense
@@ -103,10 +103,11 @@ class Attention(nn.Module):
     """Multi-head attention over the shared dense core (reference attention.py:39-99).
     Rotary is applied to q, k AND v — preserved reference behavior (:66-67).
 
-    With ``use_pallas`` the full-sequence forward runs the Pallas flash kernel
-    (ops/flash_attention.py), which also block-skips any static sparse mask —
-    the TPU-native successor of the DeepSpeed SparseSelfAttention path
-    (attention.py:339-398). Flash is inherently max-subtracting, so the
+    ``tier`` is what ``ops.attention.attention_tier`` chose for the model:
+    "dense", "fused" (ops/fused_attention.py) or "flash"
+    (ops/flash_attention.py, which also block-skips any static sparse mask —
+    the TPU-native successor of the DeepSpeed SparseSelfAttention path,
+    attention.py:339-398). Flash is inherently max-subtracting, so the
     ``stable`` softmax variant is subsumed. Decode keeps the dense cached core
     (single-token steps are bandwidth-, not matmul-bound)."""
     dim: int
@@ -115,7 +116,7 @@ class Attention(nn.Module):
     dropout: float = 0.0
     causal: bool = True
     stable: bool = False
-    use_pallas: bool = False
+    tier: str = "dense"
     softmax_f32: bool = True
     # sequence parallelism: a Mesh with an 'sp' axis routes the full-causal
     # training forward through ring attention (parallel/ring_attention.py) —
@@ -141,45 +142,37 @@ class Attention(nn.Module):
         converts it to a jnp constant — a single source of truth so the two
         backends can never disagree."""
         b, n, _ = x.shape
-        if (self.use_pallas == "fused" and key_mask is None and self.causal
-                and not self.stable and self.sp_mesh is None
-                and not self.is_initializing()):
-            # fused-boundary kernel: operand is the qkv projection's own
-            # (b, n, 3·h·d) layout, head split/merge live inside the kernel
-            # (ops/fused_attention.py — the r5 answer to the persistent
-            # kernel's 60 ms/step boundary tax). Rotary rides the same
-            # layout: applied on the (b, n, 3h, d) VIEW — a reshape, not
-            # the head-split transpose the dense path pays. The fits check
-            # re-validates with the RUNTIME n (resolve saw cfg.seq_len) so
-            # a stale/defaulted resolve can never reach a failing Mosaic
-            # compile — unfit shapes fall through to dense.
-            from ..ops.fused_attention import (fused_fits, fused_fwd_fits,
-                                               fused_qkv_attention,
-                                               fused_qkv_attention_xbwd)
-            if fused_fits(n, self.dim_head, self.heads):
-                fn = fused_qkv_attention           # Pallas fwd + Pallas bwd
-            elif fused_fwd_fits(n, self.dim_head, self.heads):
-                # shapes whose backward busts scoped VMEM (medium h·d):
-                # Pallas fwd + boundary-free XLA bwd
-                fn = fused_qkv_attention_xbwd
-            else:
-                fn = None
-            if fn is not None:
+        # init runs the dense path: the params are identical and eager pallas
+        # execution during un-jitted init is needlessly slow
+        ready = not self.is_initializing()
+        ring = self.sp_mesh is not None and ready
+        kernel = (self.tier if ready and not ring and key_mask is None
+                  else "dense")
+        if kernel == "fused":
+            from ..ops.fused_attention import fused_fits, fused_qkv_attention
+            # not a second policy: the tier was chosen from cfg.seq_len, and
+            # the RUNTIME n must fit too or the Mosaic compile fails
+            if (self.causal and not self.stable
+                    and fused_fits(n, self.dim_head, self.heads)):
+                # the operand is the qkv projection's own (b, n, 3·h·d)
+                # layout; head split/merge live inside the kernel. Rotary
+                # rides the same layout: applied on the (b, n, 3h, d) VIEW —
+                # a reshape, not the head-split transpose the dense path pays
                 qkv = self.to_qkv(x)
                 if rotary is not None:
                     rot = rotary[:n][:, None]          # (n, 1, rot_dim)
                     qkv = apply_rotary(
                         rot, qkv.reshape(b, n, 3 * self.heads, self.dim_head)
                     ).reshape(b, n, -1)
-                out = fn(qkv, np_mask, self.heads, None, None,
-                         mask_spec).astype(x.dtype)
+                out = fused_qkv_attention(qkv, np_mask, self.heads, None, None,
+                                          mask_spec).astype(x.dtype)
                 return self.drop(self.to_out(out),
                                  deterministic=deterministic)
         q, k, v = self._split(self.to_qkv(x), n)
         if rotary is not None:
             rot = rotary[:n][None, None]
             q, k, v = (apply_rotary(rot, t) for t in (q, k, v))
-        if self.sp_mesh is not None and not self.is_initializing():
+        if ring:
             # sequence-parallel ring attention: full causal plus structured
             # (axial/conv) sparse masks, whose element test is a pure function
             # of global (qpos, kpos) the ring evaluates per chunk pair —
@@ -197,20 +190,7 @@ class Attention(nn.Module):
                                  zigzag=True,
                                  mask_spec=mask_spec if np_mask is not None
                                  else None)
-        elif (self.use_pallas == "persist" and key_mask is None
-              and self.causal and not self.stable
-              and not self.is_initializing()):
-            # whole-sequence VMEM-resident kernel: the mid-length tier where
-            # block-grid flash loses to dense (ops/persistent_attention.py)
-            from ..ops.persistent_attention import persistent_attention
-            out = persistent_attention(q, k, v, np_mask).astype(x.dtype)
-        elif (self.use_pallas in (True, "flash") and key_mask is None
-              and not self.is_initializing()):
-            # (init uses the dense path: params are identical and eager pallas
-            # execution during un-jitted init is needlessly slow. NOT a bare
-            # truthiness test: a "persist" request whose gate above rejected
-            # it — stable/non-causal — must fall to dense, not to the flash
-            # kernel that loses to dense at these lengths)
+        elif kernel == "flash":
             from ..ops.flash_attention import flash_attention
             out = flash_attention(q, k, v, mask=np_mask, mask_spec=mask_spec,
                                   causal=self.causal)
@@ -481,13 +461,12 @@ class Transformer(nn.Module):
         fmap = c.image_fmap_size
         img_seq = fmap * fmap
         self.text_len = c.seq_len + 1 - img_seq if c.causal else 0
-        # "auto" resolves against the measured v5e crossover: flash kernels
-        # for seq ≥ 2048 on TPU, dense below (ops/flash_attention.py)
-        from ..ops.flash_attention import resolve_use_pallas
         blk = c.block
-        use_pallas = resolve_use_pallas(c.use_pallas, c.seq_len,
-                                        dim_head=c.dim_head, heads=c.heads,
-                                        attention=blk.attention)
+        # chosen once, from the configured length: the model keeps its tier
+        # at every runtime length. Latent attention has no kernel path
+        # (MLAttention: two head widths) and is built without asking.
+        tier = (attention_tier(c.use_pallas, c.seq_len, c.heads, c.dim_head)
+                if blk.attention == "mha" else "dense")
 
         attn_types = tuple(c.attn_types) or ("full",)
         type_per_layer = list(islice(cycle(attn_types), c.depth))
@@ -570,7 +549,7 @@ class Transformer(nn.Module):
                         f"attn_types do not match shared_attn_ids (ind={ind}, "
                         f'attn_type="{t}", reused="{prev_t}")')
             else:
-                attn = self._make_attention(f"attn_{aid}", use_pallas)
+                attn = self._make_attention(f"attn_{aid}", tier)
                 shared_attn[aid] = (attn, t)
             if fid in shared_ff:
                 ff = shared_ff[fid]
@@ -591,14 +570,14 @@ class Transformer(nn.Module):
         self.ff_layers = ff_layers
 
     # -- the block's kinds (config.BlockConfig) -----------------------------
-    def _make_attention(self, name: str, use_pallas):
+    def _make_attention(self, name: str, tier: str):
         c, blk = self.cfg, self.cfg.block
         if blk.attention == "mha":
             if blk.positions != "dalle_axial":
                 raise ValueError("mha takes the dalle_axial rotary table")
             return Attention(c.dim, c.heads, c.dim_head, c.attn_dropout,
                              causal=c.causal, stable=c.stable,
-                             use_pallas=use_pallas,
+                             tier=tier,
                              softmax_f32=c.attn_softmax_f32,
                              sp_mesh=self.sp_mesh, name=name)
         if blk.positions != "seq_yarn" or not c.causal or self.sp_mesh:

@@ -79,6 +79,43 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return jnp.einsum("bhij,bhjd->bhid", attn, v)
 
 
+# the configured length from which the block-grid flash kernels
+# (ops/flash_attention.py) take over from dense on the TPU
+FLASH_MIN_SEQ = 2048
+
+
+def attention_tier(use_pallas, seq_len: int, heads: int, dim_head: int,
+                   backend: Optional[str] = None) -> str:
+    """The training-attention tier of a multi-head model: "dense" (``attend``
+    above), "fused" (ops/fused_attention.py) or "flash"
+    (ops/flash_attention.py). The one place that decides; called once per
+    model (``Transformer.setup``) with the CONFIGURED length, so a model
+    keeps its tier at every runtime length.
+
+    ``use_pallas`` is the config's field. "auto" chooses from what it
+    observes: dense off the TPU (the kernels run in interpret mode there),
+    flash from ``FLASH_MIN_SEQ`` up, fused where its backward fits the
+    raised scoped-VMEM ceiling (``fused_fits``), dense otherwise. "off" or
+    ``False`` is dense everywhere — the reference the kernels are tested
+    against. Both thresholds are readings from before the benchmark
+    (PERF.md §7 names the cells that would confirm or move them)."""
+    if use_pallas is False or use_pallas == "off":
+        return "dense"
+    if use_pallas != "auto":
+        raise ValueError(f'use_pallas must be "auto" or "off" (False), got '
+                         f"{use_pallas!r}: the tier is chosen from the "
+                         f"model's shape and the backend, not named")
+    # asked only here: reading "off" must not start the XLA client
+    if backend is None:
+        backend = jax.default_backend()
+    if backend != "tpu":
+        return "dense"
+    if seq_len >= FLASH_MIN_SEQ:
+        return "flash"
+    from .fused_attention import fused_fits
+    return "fused" if fused_fits(seq_len, dim_head, heads) else "dense"
+
+
 def _quantize_int8(x):
     """Per-(b, h, position) symmetric int8 quantization over the head dim.
     Returns (q int8, scale f32 with a trailing singleton dim)."""
@@ -224,12 +261,6 @@ def cached_attend(q: jnp.ndarray, cache: KVCache, length, *,
     """
     from .decode_attention import decode_attend_kernel, decode_kernel_supported
     if use_kernel is None:
-        # only the single-block kernel auto-selects. The chunked long-cache
-        # variant (decode_attend_kernel_chunked) measured parity-at-best
-        # with dense XLA at S=1280 AND S=2560 (r5, both dtypes), and its
-        # tail-skipping clamped index maps saved no measurable DMA — the
-        # r4 S=512 negative generalizes. It stays available for explicit
-        # use / future toolchains; dense remains the long-cache default.
         use_kernel = (jax.default_backend() == "tpu"
                       and decode_kernel_supported(q, cache, stable=stable))
     if use_kernel:

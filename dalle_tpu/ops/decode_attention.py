@@ -1,9 +1,10 @@
 """Pallas single-token decode attention — the hot op of batched generation.
 
-Profiling the b64 DALL·E-small decode loop on v5e (NEXT.md r4) shows XLA's
-lowering of cached attention (dequant-multiply + dot as kLoop fusions over
-the int8 cache) at ~100 us/layer-step against a ~44 us HBM roofline — 67% of
-the whole decode loop. Alternatives measured on-chip before landing here:
+A profile of the b64 DALL·E-small decode loop on a v5e (before the
+benchmark; no cell measures decode yet) showed XLA's lowering of cached
+attention (dequant-multiply + dot as kLoop fusions over the int8 cache) at
+~100 us/layer-step against a ~44 us HBM roofline — 67% of the whole decode
+loop. Alternatives measured on-chip before landing here:
 post-scale dequant restructures and int8 MXU dots in XLA (equal or worse),
 a per-(b,h)-program Pallas kernel (3x worse — per-program DMA overhead),
 and per-head in-kernel dots (1.7x worse — M=1 MXU staging). The winning
@@ -170,7 +171,7 @@ def decode_kernel_supported(q, cache, *, stable: bool) -> bool:
 
 # ---------------------------------------------------------------------------
 # windowed multi-token variant with PER-ROW lengths: the speculative verify
-# step and the serving engine's per-row decode/refill (NEXT.md r6 item 2)
+# step and the serving engine's per-row decode/refill
 # ---------------------------------------------------------------------------
 # Same program shape as the single-token kernel — ONE program per batch row,
 # one contiguous (S, 2·h·d) DMA, all dots on the MXU — but the query block
@@ -308,176 +309,6 @@ def decode_window_kernel_supported(q, cache, *, stable: bool,
             and S % 128 == 0 and S >= 128
             and (hd2 // 2) % 128 == 0 and d % 8 == 0
             and vmem_bytes <= _VMEM_BUDGET)
-
-
-# ---------------------------------------------------------------------------
-# chunked long-cache variant: grid (b, n_blk) with tail skipping
-# ---------------------------------------------------------------------------
-# The r4 measurement parked this shape at S=512 (4 blocks): per-grid-step
-# overhead (~30 us) swamped the skipped DMA. The r5 revisit (VERDICT r4 #5,
-# scripts/bench_decode_chunked.py) measured it at the long caches its own
-# analysis predicted would win — S=1280 (b64 h8 d64, 5-10 blocks, both
-# dtypes) and S=2560 (b16 h14 d128, where the single-block kernel's merged
-# block no longer fits) — and the answer is NEGATIVE there too: parity at
-# best with dense XLA, and the clamped-index tail skip saved no measurable
-# DMA at 25% occupancy (dense was FASTER at short lengths). So this variant
-# does NOT auto-select; it is kept for explicit use and future toolchains.
-# Design: grid (b, n_blk); index maps clamped to the last needed block
-# (scalar-prefetched length) so beyond-length grid steps re-fetch the
-# previous block (DMA elided) and their compute is masked to a no-op;
-# online softmax accumulates in VMEM scratch across blocks.
-
-def _decode_kernel_chunked(len_ref, q_ref, kv_ref, sc_ref, row_ref, o_ref,
-                           m_scr, l_scr, acc_scr, *, scale, heads, blk):
-    h = heads
-    ik = pl.program_id(1)
-    n_blk = pl.num_programs(1)
-    hd = kv_ref.shape[2] // 2
-    d = hd // h
-    dot_dt = (jnp.float32 if kv_ref.dtype == jnp.float32 else jnp.bfloat16)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0].astype(jnp.float32) * scale                   # (h, d)
-    qt = jnp.concatenate([q] * h, axis=1)                      # (h, h*d)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (h, hd), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (h, hd), 0)
-    bd = (lane // d) == row
-    qbd = jnp.where(bd, qt, 0.0).astype(dot_dt)
-
-    k = kv_ref[0, :, :hd].astype(dot_dt)                       # (blk, h*d)
-    s = jax.lax.dot_general(qbd, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (h, blk)
-    if sc_ref is not None:
-        s = s * sc_ref[0, :h]
-    # GLOBAL positions from the UNclamped program id: beyond-length blocks
-    # (whose content is the re-fetched previous block) mask to all-invalid
-    kpos = ik * blk + jax.lax.broadcasted_iota(jnp.int32, (h, blk), 1)
-    valid = kpos < len_ref[0]
-    if row_ref is not None:
-        valid &= row_ref[0] != 0
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_old = m_scr[...]                                         # (h, 128)
-    m_blk = jnp.max(s, axis=-1, keepdims=True)                 # (h, 1)
-    m_new = jnp.maximum(m_old, m_blk)                          # (h, 128)
-    corr = jnp.exp(m_old[:, :1] - m_new[:, :1])                # (h, 1)
-    p = jnp.where(valid, jnp.exp(s - m_new[:, :1]), 0.0)       # (h, blk)
-    l_new = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    if sc_ref is not None:
-        p = p * sc_ref[0, h:]
-    v = kv_ref[0, :, hd:].astype(dot_dt)                       # (blk, h*d)
-    obd = jax.lax.dot_general(p.astype(dot_dt), v,
-                              (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # (h, h*d)
-    acc_scr[...] = acc_scr[...] * corr + jnp.where(bd, obd, 0.0)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
-
-    @pl.when(ik == n_blk - 1)
-    def _finish():
-        gr = jax.lax.broadcasted_iota(jnp.int32, (hd, d), 0)
-        gc = jax.lax.broadcasted_iota(jnp.int32, (hd, d), 1)
-        gather = ((gr % d) == gc).astype(jnp.float32)          # (h*d, d)
-        o = jax.lax.dot_general(acc_scr[...], gather,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        l = l_scr[:, :1]
-        o_ref[0] = (o / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
-
-
-def decode_attend_kernel_chunked(q, cache, length, *,
-                                 blk: int = 256,
-                                 mask_row: Optional[jnp.ndarray] = None,
-                                 scale: Optional[float] = None,
-                                 out_dtype=None,
-                                 interpret: Optional[bool] = None):
-    """Chunked long-cache decode: same contract as decode_attend_kernel, for
-    caches whose merged block exceeds the single-block VMEM budget."""
-    b, h, _, d = q.shape
-    S = cache.kv.shape[1]
-    hd2 = cache.kv.shape[2]
-    assert S % blk == 0, (S, blk)
-    n_blk = S // blk
-    if scale is None:
-        scale = d ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    out_dtype = out_dtype or q.dtype
-    quant = cache.scale is not None
-
-    def last_needed(len_ref):
-        return jnp.maximum((len_ref[0] + blk - 1) // blk - 1, 0)
-
-    def kv_map(ib, ik, len_ref):
-        return (ib, jnp.minimum(ik, last_needed(len_ref)), 0)
-
-    qspec = pl.BlockSpec((1, h, d), lambda ib, ik, *_: (ib, 0, 0))
-    in_specs = [qspec, pl.BlockSpec((1, blk, hd2), kv_map)]
-    args = [q[:, :, 0, :], cache.kv]
-    if quant:
-        in_specs += [pl.BlockSpec(
-            (1, 2 * h, blk),
-            lambda ib, ik, len_ref: (ib, 0, jnp.minimum(ik,
-                                                        last_needed(len_ref))))]
-        args += [cache.scale]
-    if mask_row is not None:
-        in_specs += [pl.BlockSpec(
-            (1, blk),
-            lambda ib, ik, len_ref: (0, jnp.minimum(ik,
-                                                    last_needed(len_ref))))]
-        args += [mask_row.astype(jnp.int32)[None, :]]
-
-    def kern(len_ref, *refs):
-        q_ref, kv_ref = refs[0], refs[1]
-        nxt = 2
-        sc_ref = row_ref = None
-        if quant:
-            sc_ref = refs[nxt]
-            nxt += 1
-        if mask_row is not None:
-            row_ref = refs[nxt]
-            nxt += 1
-        _decode_kernel_chunked(len_ref, q_ref, kv_ref, sc_ref, row_ref,
-                               refs[nxt], *refs[nxt + 1:],
-                               scale=scale, heads=h, blk=blk)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, n_blk),
-        in_specs=in_specs,
-        out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32),
-                        pltpu.VMEM((h, 128), jnp.float32),
-                        pltpu.VMEM((h, h * d), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), out_dtype),
-        interpret=interpret,
-        name="decode_attn_chunked",
-    )(jnp.asarray(length, jnp.int32).reshape(1), *args)
-    return out[:, :, None, :]
-
-
-def decode_kernel_chunk_supported(q, cache, *, stable: bool,
-                                  blk: int = 256) -> bool:
-    """Gate for the chunked variant: engages where the single-block kernel's
-    VMEM budget is exceeded but per-block tiles still tile the lanes."""
-    b, h, i, d = q.shape
-    S, hd2 = cache.kv.shape[1], cache.kv.shape[2]
-    itemsize = jnp.dtype(cache.kv.dtype).itemsize
-    vmem = blk * hd2 * itemsize + blk * 4 + (2 * h * blk * 4
-                                             if cache.kv.dtype == jnp.int8
-                                             else 0)
-    return (i == 1 and not stable and S % blk == 0 and S // blk >= 2
-            and (hd2 // 2) % 128 == 0 and d % 8 == 0
-            and vmem <= _VMEM_BUDGET)
 
 
 def decode_attend_window_paged(q, cache, starts, *,

@@ -29,7 +29,6 @@ exercises them on CPU (tests/test_flash_attention.py).
 from __future__ import annotations
 
 import functools
-import logging
 from typing import NamedTuple, Optional
 
 import jax
@@ -464,98 +463,8 @@ def sparsity_fraction(n: int, block_q: int = 128, block_k: int = 128,
     return float(lists.k_cnt.sum()) / float(nq * nk)
 
 
-# measured fwd+bwd crossover on v5e (scripts/bench_flash.py, NEXT.md table):
-# dense wins below ~2k seq (flash ~0.9-1.0x at 512-1040), flash wins above
-# (1.4-1.5x full at 2281, up to 4.3x for structured sparse at 4352)
-PALLAS_AUTO_MIN_SEQ = 2048
-
-
-@functools.lru_cache(maxsize=None)
-def _say_once(msg: str) -> None:
-    logging.getLogger(__name__).info(msg)
-
-
-def resolve_use_pallas(setting, seq_len: int, backend: Optional[str] = None,
-                       dim_head: int = 64, heads: int = 8,
-                       attention: str = "mha"):
-    """Resolve a config's ``use_pallas`` ("auto" | "fused" | "persist" | on |
-    off, bools and their string forms accepted for config round-trips) into
-    the per-model mode: "flash" | "fused" | "persist" | False.
-
-    "auto" applies the measured crossover on TPU: the block-grid flash
-    kernels for seq ≥ 2048 (the r2-measured crossover — 1.4-4.3x over
-    dense), dense below (and always dense off-TPU, where the kernels run
-    interpret-mode). The VMEM-persistent whole-sequence kernel
-    (ops/persistent_attention.py) is opt-in via "persist": it beats dense
-    1.6x as a standalone op at n=513 but loses ~19% END-TO-END — the
-    pallas-call boundary breaks XLA's layout fusion around it
-    (docs/PERF_SMALL.md r4 addendum). "fused" selects its r5 successor
-    (ops/fused_attention.py) whose boundary is the qkv projection's own
-    (b, n, 3·h·d) layout.
-
-    ``attention="mla"`` (latent attention: a query/key width that differs
-    from the value width, one rotary key part shared across heads) is the
-    dense tier whatever the setting and the length: every kernel here
-    assumes one ``dim_head``. Said once in the log."""
-    if attention == "mla":
-        _say_once(f"use_pallas={setting!r}: latent attention (mla) runs the "
-                  f"dense tier at every length; the flash, fused and "
-                  f"persistent kernels assume one head width")
-        return False
-    from .fused_attention import fused_fits, fused_fwd_fits
-    from .persistent_attention import persistent_fits
-    if setting is True:
-        return "flash"
-    if setting is False:
-        return False
-    s = str(setting).lower()
-    # only the backend-dependent branches may query the backend: resolving a
-    # plain "on"/"off" string must not initialize the XLA client as a side
-    # effect of config parsing
-    if s == "auto":
-        if backend is None:
-            backend = jax.default_backend()
-        if backend != "tpu":
-            return False
-        if seq_len >= PALLAS_AUTO_MIN_SEQ:
-            return "flash"
-        # mid-length tier: the fused-boundary kernel measures 0.458 vs
-        # 0.391 MFU on DALL·E-small and 0.638 vs 0.523 on medium (the
-        # merged backward compiles under the RAISED Mosaic vmem ceiling —
-        # PERF_SMALL r5 addenda). fused_fits stops where the win stops:
-        # the flagship h·d=1792 shape measured parity and stays dense.
-        if fused_fits(seq_len, dim_head, heads):
-            return "fused"
-        return False
-    if s in ("fused", "persist"):
-        if backend is None:
-            backend = jax.default_backend()
-        if backend != "tpu":
-            return False
-        # explicit "fused" also admits the fwd-kernel/XLA-bwd tier
-        # (Attention picks the concrete variant from the runtime shape)
-        gate, fits = (("fused_fwd_fits",
-                       fused_fwd_fits(seq_len, dim_head, heads))
-                      if s == "fused" else
-                      ("persistent_fits", persistent_fits(seq_len, dim_head)))
-        if not fits:
-            # an explicit tier is a request, not a hint: running dense in
-            # its place would report the kernel's name over XLA's numbers
-            raise ValueError(
-                f"use_pallas={s!r} cannot be honoured on the TPU: {gate} "
-                f"rejects seq_len={seq_len}, heads={heads}, "
-                f"dim_head={dim_head} — use \"auto\" to let the code choose")
-        return s
-    if s in ("1", "true", "on", "yes"):
-        return "flash"
-    if s in ("0", "false", "off", "no", "none"):
-        return False
-    raise ValueError(
-        f"use_pallas must be auto/fused/persist/on/off, got {setting!r}")
-
-
 def _auto_block(n: int, has_mask: bool) -> int:
-    """Measured v5e defaults (scripts/bench_flash.py, fwd+bwd, bf16):
+    """Block sizes read on a v5e before the benchmark (fwd+bwd, bf16):
     mask-free kernels carry no element-mask operand so bigger blocks fit;
     masked kernels hold a (block, n_pad) int32 mask row and hit the 16M
     scoped-VMEM limit earlier as n grows."""

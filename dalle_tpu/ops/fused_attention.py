@@ -1,18 +1,15 @@
 """Fused-boundary whole-sequence attention: qkv lands as (b, n, 3·h·d).
 
-The r4 VMEM-persistent kernel (ops/persistent_attention.py) won 1.6x
-standalone and halved in-model attention time, yet LOST 19% end-to-end:
-its custom-call boundary forced the (b, h, n, d) head-split layout to
-materialize, costing ~60 ms/step of XLA loop-fusion/formatting/slice work
-that the dense path folds into the attention einsums (docs/PERF_SMALL.md
-r4 addendum). This kernel moves the boundary to where the data already
-is: the operand is the qkv projection's own output layout (b, n, 3·h·d)
-and the result is the pre-to_out merged layout (b, n, h·d) — the head
-split/merge, scaling, causal mask, and softmax all live INSIDE the
-kernel, so XLA sees a matmul → custom-call → matmul chain with no layout
-work between. Rotary stays outside but is applied on the (b, n, 3h, d)
-VIEW of the projection output (a reshape, not a transpose — see
-models/transformer.py Attention.__call__).
+A kernel whose operands are per-head (b, h, n, d) tensors makes XLA
+materialize the head split around the custom call, which the dense path
+folds into its einsums. This kernel puts the boundary where the data
+already is: the operand is the qkv projection's own output layout
+(b, n, 3·h·d) and the result is the pre-to_out merged layout (b, n, h·d) —
+the head split/merge, scaling, causal mask, and softmax all live INSIDE
+the kernel, so XLA sees a matmul → custom-call → matmul chain with no
+layout work between. Rotary stays outside but is applied on the
+(b, n, 3h, d) VIEW of the projection output (a reshape, not a transpose —
+see models/transformer.py Attention.__call__).
 
 Grid: one program per batch row (the decode kernel's "fewer, bigger
 programs" lesson — ops/decode_attention.py), heads unrolled inside.
@@ -259,82 +256,3 @@ fused_qkv_attention.defvjp(
     lambda qkv, mask, heads, scale, interpret, mask_spec:
         _fused_fwd(qkv, mask, heads, scale, interpret, mask_spec),
     _fused_bwd)
-
-
-# ---------------------------------------------------------------------------
-# fwd-kernel / XLA-backward tier: shapes whose BACKWARD busts scoped VMEM
-# ---------------------------------------------------------------------------
-# The forward's live set (~2x qkv window + 1 score tile) fits well past the
-# backward's (medium h·d=1024 forward ≈ 12.8M vs backward 25.68M per the
-# compiler). For those shapes this variant keeps the Pallas forward and
-# computes the backward with plain XLA einsums straight off the saved
-# merged-layout operand — no custom call in the backward at all, so XLA is
-# free to fold the per-head slicing/merging into the einsums (the r4 60 ms
-# boundary tax was a property of materializing (b, h, n, d) AROUND an
-# opaque kernel, not of the dense math itself).
-
-def fused_fwd_fits(n: int, dim_head: int, heads: int) -> bool:
-    """Forward-pass VMEM bound (2x (qkv + out) bf16 windows + score tiles
-    + the always-shipped int8 validity-table window) against the raised
-    Mosaic ceiling — the gate for the fwd-kernel/XLA-bwd tier."""
-    hd = heads * dim_head
-    bytes_ = 18 * n * hd + 8 * n * n + 2 * n * n
-    return bytes_ <= _VMEM_RAISED_BUDGET
-
-
-def _dense_bwd(mask, heads, scale, interpret, mask_spec, res, do):
-    """Backward in plain XLA from the merged (b, n, 3·h·d) residual. The
-    Pallas forward's OUTPUT rides along in the residuals so delta =
-    rowsum(O·dO) needs no recompute — dropping one of the three O(n²·d)
-    products this backward would otherwise pay."""
-    qkv, out = res
-    b, n, hd3 = qkv.shape
-    hd = hd3 // 3
-    d = hd // heads
-    if scale is None:
-        scale = d ** -0.5
-    qkv16 = qkv.astype(jnp.bfloat16)
-    sh = (b, n, heads, d)
-    q, k, v = [t.reshape(sh).transpose(0, 2, 1, 3)
-               for t in jnp.split(qkv16, 3, axis=-1)]       # (b,h,n,d)
-    do16 = do.astype(jnp.bfloat16).reshape(b, n, heads, d).transpose(0, 2, 1, 3)
-    s = jnp.einsum("bhid,bhjd->bhij",
-                   (q.astype(jnp.float32) * scale).astype(jnp.bfloat16),
-                   k).astype(jnp.float32)
-    valid = jnp.asarray(validity_table(n, mask, mask_spec)) != 0
-    s = jnp.where(valid, s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    p = e / jnp.sum(e, axis=-1, keepdims=True)
-    p16 = p.astype(jnp.bfloat16)
-    dp = jnp.einsum("bhid,bhjd->bhij", do16, v).astype(jnp.float32)
-    delta = jnp.sum(
-        (out.astype(jnp.float32) * do.astype(jnp.float32)).reshape(
-            b, n, heads, d).transpose(0, 2, 1, 3),
-        axis=-1, keepdims=True)
-    ds = (p * (dp - delta)).astype(jnp.bfloat16)
-    dq = jnp.einsum("bhij,bhjd->bhid", ds, k).astype(jnp.float32) * scale
-    dk = jnp.einsum("bhij,bhid->bhjd", ds, q).astype(jnp.float32) * scale
-    dv = jnp.einsum("bhij,bhid->bhjd", p16, do16).astype(jnp.float32)
-    merge = (lambda t: t.transpose(0, 2, 1, 3).reshape(b, n, hd))
-    dqkv = jnp.concatenate([merge(dq), merge(dk), merge(dv)],
-                           axis=-1).astype(qkv.dtype)
-    return (dqkv,)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
-def fused_qkv_attention_xbwd(qkv, mask=None, heads: int = 8,
-                             scale: Optional[float] = None,
-                             interpret: Optional[bool] = None,
-                             mask_spec=None):
-    """fused_qkv_attention with the Pallas forward and an XLA backward —
-    the tier for shapes where only the backward busts scoped VMEM."""
-    return _fused_fwd(qkv, mask, heads, scale, interpret, mask_spec)[0]
-
-
-def _fused_fwd_save_out(qkv, mask, heads, scale, interpret, mask_spec):
-    out, _ = _fused_fwd(qkv, mask, heads, scale, interpret, mask_spec)
-    return out, (qkv, out)
-
-
-fused_qkv_attention_xbwd.defvjp(_fused_fwd_save_out, _dense_bwd)

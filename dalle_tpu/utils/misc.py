@@ -122,7 +122,7 @@ def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
     The min-time/min-size thresholds are dropped to zero: cold-start cares
     about the long tail of small programs too, and the cache is
     content-addressed so over-writing is idempotent. Shared by every train
-    and serve CLI (scripts/_common.add_compile_cache_args), bench.py and
+    and serve CLI (scripts/_common.add_compile_cache_args) and
     chip_smoke.py, and re-exported by dalle_tpu.gateway.aot for the serving
     cold-start story (docs/SERVING.md)."""
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")

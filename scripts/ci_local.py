@@ -195,21 +195,6 @@ def run_chaos_smoke_stage() -> int:
     return subprocess.run(cmd, cwd=ROOT, env=env).returncode
 
 
-def run_bench_check_stage() -> None:
-    """ADVISORY perf-regression sentry: diff the newest BENCH_r*/
-    MULTICHIP_r* round against the prior one with a tolerance band
-    (scripts/bench_check.py). Advisory because this sandbox's CPU-mesh
-    numbers jitter with box load — a REGRESSED verdict is a prompt to
-    look at the diff, not a build failure (run with --strict on real
-    hardware). The stage therefore never gates the test tiers."""
-    cmd = [sys.executable, os.path.join(ROOT, "scripts", "bench_check.py")]
-    print(f"== [bench_check, advisory] {' '.join(cmd[1:])}")
-    r = subprocess.run(cmd, cwd=ROOT)
-    if r.returncode != 0:
-        print("ci_local: bench_check reported issues (ADVISORY — not "
-              "gating)")
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--changed-only", action="store_true",
@@ -279,8 +264,6 @@ def main():
         print("ci_local: FAILED (chaos smoke) — test tiers not run")
         return 1
 
-    run_bench_check_stage()
-
     wf = yaml.safe_load(open(os.path.join(ROOT, ".github/workflows/ci.yml")))
     job = wf["jobs"]["test"]
     failures = 0
@@ -320,9 +303,6 @@ def main():
             continue
         if "scripts/chaos_smoke.py" in cmd:
             print(f"-- [skip] {name}: already run in the chaos smoke stage")
-            continue
-        if "scripts/bench_check.py" in cmd:
-            print(f"-- [skip] {name}: already run in the bench_check stage")
             continue
         if any(m in cmd for m in NETWORK_MARKERS):
             # the editable-install smoke is half network, half local: keep

@@ -17,7 +17,7 @@ int8->bf16 dequant per tile; see ops/quantize_weights.py).
 
 Run (CPU mesh): XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     JAX_PLATFORMS=cpu python scripts/eval_decode_precisions.py --small
-Run (TPU, recorded in NEXT.md): python scripts/eval_decode_precisions.py
+Run (TPU): python scripts/eval_decode_precisions.py
 """
 
 import argparse

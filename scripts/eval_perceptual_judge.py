@@ -4,8 +4,7 @@ judges NONE of the arms trained on (VERDICT r3 next #4).
 
 Arms (identical small VQGANs on synthetic shapes, disc off, same data order):
   * tiny@0.22      — the shipped tiny-LPIPS at scale-matched weight (its
-                     metric is ~4.5x stronger per unit weight than ones-init;
-                     NEXT.md r3)
+                     metric is ~4.5x stronger per unit weight than ones-init)
   * onesinit@1.0   — the offline ones-init fallback ('vgg' with no weights)
   * none           — no perceptual term (pixel + quant losses only)
 

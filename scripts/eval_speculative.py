@@ -16,7 +16,7 @@ tokens), then measures batched generation at b64:
   * acceptance: rounds used / mean committed per round.
 
 Reference bar: the strictly sequential generate_images loop
-(dalle_pytorch/dalle_pytorch.py:523-546). Run on TPU (numbers → NEXT.md):
+(dalle_pytorch/dalle_pytorch.py:523-546). Run on TPU:
     python scripts/eval_speculative.py
 CPU smoke: XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     JAX_PLATFORMS=cpu python scripts/eval_speculative.py --small

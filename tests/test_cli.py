@@ -146,29 +146,6 @@ def test_train_clip_and_rerank_generation(shapes_dir, tmp_path):
     assert len(pngs) == 2
 
 
-def test_bench_check_empty_newest_round_is_new_not_missing(tmp_path, capsys):
-    """bench_check satellite: a newest round with no metric records (fresh
-    clone / placeholder) reads as a NEW baseline — one quiet line, never a
-    wall of per-metric MISSING verdicts — and stays advisory (exit 0)."""
-    import json as _json
-    bench_check = _load("bench_check")
-    old = {"parsed": {"metric": "tok_per_sec", "value": 100.0},
-           "tail": ""}
-    (tmp_path / "BENCH_r01.json").write_text(_json.dumps(old))
-    (tmp_path / "BENCH_r02.json").write_text(_json.dumps({"tail": ""}))
-    rc = bench_check.main(["--root", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "MISSING" not in out
-    assert "NEW" in out and "fresh baseline" in out
-    # with metrics on both sides the diff still works as before
-    new = {"parsed": {"metric": "tok_per_sec", "value": 50.0}, "tail": ""}
-    (tmp_path / "BENCH_r02.json").write_text(_json.dumps(new))
-    rc = bench_check.main(["--root", str(tmp_path), "--strict"])
-    out = capsys.readouterr().out
-    assert rc == 1 and "REGRESSED" in out
-
-
 # -- the one compile-cache rule (utils/misc.enable_compilation_cache) --------
 
 @pytest.fixture

@@ -90,6 +90,11 @@ def test_supported_gate():
     assert not decode_kernel_supported(jnp.zeros((1, 2, 1, 16)),
                                        KVCache.init(1, 2, 256, 16),
                                        stable=False)
+    # merged K+V block beyond the per-program VMEM budget: 14 x 128 heads at
+    # S=2560 is 17.9MB and stays on the dense path
+    assert not decode_kernel_supported(
+        jnp.zeros((2, 14, 1, 128), jnp.bfloat16),
+        KVCache.init(2, 14, 2560, 128, jnp.bfloat16), stable=False)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
@@ -163,55 +168,3 @@ def test_window_supported_gate():
     big = KVCache.init(2, 14, 2560, 128, jnp.bfloat16)
     assert not decode_window_kernel_supported(
         jnp.zeros((2, 14, 5, 128), jnp.bfloat16), big, stable=False)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
-def test_chunked_kernel_matches_dense(dtype):
-    """Chunked long-cache variant (online softmax across S-blocks +
-    tail-skipping clamped index maps) ≡ dense, at a length that leaves
-    several blocks beyond the tail."""
-    from dalle_tpu.ops.decode_attention import decode_attend_kernel_chunked
-    rng = np.random.RandomState(2)
-    b, h, S, d = 2, 4, 1280, 64
-    cache = _cache(rng, b, h, S, d, dtype)
-    q = jnp.asarray(rng.standard_normal((b, h, 1, d)), jnp.float32)
-    for length in (135, 640, 1280):
-        dense = cached_attend(q, cache, jnp.int32(length), use_kernel=False)
-        kern = decode_attend_kernel_chunked(q, cache, jnp.int32(length),
-                                            blk=256, interpret=True)
-        np.testing.assert_allclose(np.asarray(kern), np.asarray(dense),
-                                   rtol=2e-2, atol=2e-2,
-                                   err_msg=f"length={length}")
-
-
-def test_chunked_kernel_mask_row():
-    from dalle_tpu.ops.decode_attention import decode_attend_kernel_chunked
-    rng = np.random.RandomState(3)
-    b, h, S, d = 2, 2, 512, 64
-    cache = _cache(rng, b, h, S, d, jnp.float32)
-    q = jnp.asarray(rng.standard_normal((b, h, 1, d)), jnp.float32)
-    mask = jnp.asarray(rng.rand(S, S) > 0.4)
-    length, qpos = jnp.int32(300), jnp.int32(299)
-    dense = cached_attend(q, cache, length, static_mask=mask, qpos=qpos,
-                          use_kernel=False)
-    row = jax.lax.dynamic_index_in_dim(mask, qpos, 0, keepdims=False)
-    kern = decode_attend_kernel_chunked(q, cache, length, mask_row=row,
-                                        blk=128, interpret=True)
-    np.testing.assert_allclose(np.asarray(kern), np.asarray(dense),
-                               rtol=2e-2, atol=2e-2)
-
-
-def test_chunk_gate_tiers():
-    """Single-block keeps its budgeted tier; the chunked gate picks up the
-    long caches beyond it."""
-    from dalle_tpu.ops.decode_attention import (_VMEM_BUDGET,
-                                                decode_kernel_chunk_supported)
-    q = jnp.zeros((2, 14, 1, 128), jnp.bfloat16)
-    # flagship-head long cache: S=2560 at h*d=1792 -> merged block 17.9MB
-    big = KVCache.init(2, 14, 2560, 128, jnp.bfloat16)
-    assert not decode_kernel_supported(q, big, stable=False)
-    assert decode_kernel_chunk_supported(q, big, stable=False)
-    # short cache stays on the single-block kernel
-    q8 = jnp.zeros((2, 8, 1, 64), jnp.bfloat16)
-    small = KVCache.init(2, 8, 512, 64, jnp.bfloat16)
-    assert decode_kernel_supported(q8, small, stable=False)

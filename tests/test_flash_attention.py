@@ -116,30 +116,28 @@ def test_jit_and_vmap_compatible():
 
 
 def test_use_pallas_auto_policy():
-    """use_pallas='auto' pins the measured v5e crossovers (NEXT.md table):
-    flash at seq ≥ 2048 on TPU, the fused-boundary kernel at mid lengths
-    where it fits (r5), dense otherwise and off-TPU; explicit on/off and
-    legacy bool config round-trips override."""
-    from dalle_tpu.ops.flash_attention import resolve_use_pallas
-    assert resolve_use_pallas("auto", 4352, backend="tpu") == "flash"
-    assert resolve_use_pallas("auto", 2048, backend="tpu") == "flash"
-    assert resolve_use_pallas("auto", 512, backend="tpu") == "fused"
+    """use_pallas='auto': flash from seq 2048 up on the TPU, below that the
+    fused kernel where it fits and dense where it does not, dense off the
+    TPU; 'off' and False are dense everywhere; nothing else is taken."""
+    from dalle_tpu.ops.attention import FLASH_MIN_SEQ, attention_tier
+    assert FLASH_MIN_SEQ == 2048
+    assert attention_tier("auto", 4352, 8, 64, backend="tpu") == "flash"
+    assert attention_tier("auto", 2048, 8, 64, backend="tpu") == "flash"
+    assert attention_tier("auto", 2047, 8, 64, backend="tpu") == "dense"
+    assert attention_tier("auto", 512, 8, 64, backend="tpu") == "fused"
     # shapes whose fused backward busts scoped VMEM stay dense
-    assert not resolve_use_pallas("auto", 512, backend="tpu",
-                                  dim_head=128, heads=14)
-    assert not resolve_use_pallas("auto", 4352, backend="cpu")
-    assert resolve_use_pallas("on", 128, backend="cpu")
-    assert resolve_use_pallas(True, 128)
-    assert not resolve_use_pallas(False, 99999)
-    assert not resolve_use_pallas("off", 99999, backend="tpu")
-    assert not resolve_use_pallas("False", 99999, backend="tpu")
-    with pytest.raises(ValueError):
-        resolve_use_pallas("sometimes", 128)
+    assert attention_tier("auto", 512, 14, 128, backend="tpu") == "dense"
+    assert attention_tier("auto", 4352, 8, 64, backend="cpu") == "dense"
+    assert attention_tier(False, 99999, 8, 64) == "dense"
+    assert attention_tier("off", 99999, 8, 64, backend="tpu") == "dense"
+    with pytest.raises(ValueError, match="auto.*off"):
+        attention_tier("sometimes", 128, 8, 64)
 
 
-def test_transformer_use_pallas_matches_dense():
-    """cfg.use_pallas flips the full-sequence path onto the flash kernel; the
-    result must match the dense masked path."""
+def test_transformer_use_pallas_matches_dense(monkeypatch):
+    """The flash tier flips the full-sequence path onto the flash kernel;
+    the result must match the dense masked path. (The chooser answers dense
+    off the TPU and below 2048, so the test substitutes it.)"""
     from dalle_tpu.config import TransformerConfig
     from dalle_tpu.models.transformer import Transformer
 
@@ -147,10 +145,12 @@ def test_transformer_use_pallas_matches_dense():
               image_fmap_size=8, attn_types=("full", "axial_row"),
               rotary_emb=False)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 81, 32))
-    m_dense = Transformer(TransformerConfig(**kw))
+    m_dense = Transformer(TransformerConfig(**kw, use_pallas="off"))
     params = m_dense.init(jax.random.PRNGKey(1), x)
     y_dense = m_dense.apply(params, x)
-    m_flash = Transformer(TransformerConfig(**kw, use_pallas=True))
+    monkeypatch.setattr("dalle_tpu.models.transformer.attention_tier",
+                        lambda *a, **k2: "flash")
+    m_flash = Transformer(TransformerConfig(**kw))
     y_flash = m_flash.apply(params, x)
     np.testing.assert_allclose(np.asarray(y_flash), np.asarray(y_dense),
                                rtol=2e-4, atol=2e-4)

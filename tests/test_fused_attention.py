@@ -112,67 +112,25 @@ def test_grouped_store_path():
                                rtol=5e-2, atol=5e-2)
 
 
-def test_xbwd_matches_autodiff():
-    """The fwd-kernel/XLA-backward tier (medium shapes): same contract as
-    the full kernel — fwd ≡ dense, custom bwd ≡ dense autodiff. Also via
-    a structured spec."""
-    from dalle_tpu.ops.attn_masks import build_mask
-    from dalle_tpu.ops.fused_attention import fused_qkv_attention_xbwd
-    rng = np.random.RandomState(4)
-    qkv = jnp.asarray(rng.standard_normal((2, 48, 3 * 2 * 16)), jnp.float32)
-    do = jnp.asarray(rng.standard_normal((2, 48, 2 * 16)), jnp.float32)
-    out = fused_qkv_attention_xbwd(qkv, None, 2, None, True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(_dense(qkv, 2)),
-                               rtol=2e-2, atol=2e-2)
-    gk = jax.grad(lambda a: jnp.sum(
-        fused_qkv_attention_xbwd(a, None, 2, None, True) * do))(qkv)
-    gd = jax.grad(lambda a: jnp.sum(_dense(a, 2) * do))(qkv)
-    np.testing.assert_allclose(np.asarray(gk), np.asarray(gd),
-                               rtol=5e-2, atol=5e-2)
-    n, text_len, fmap = 20, 4, 4
-    qkv = jnp.asarray(rng.standard_normal((2, n, 3 * 2 * 16)), jnp.float32)
-    do = jnp.asarray(rng.standard_normal((2, n, 2 * 16)), jnp.float32)
-    mask = build_mask("axial_row", text_len, fmap)
-    spec = ("axial", text_len, fmap, 0)
-    gs = jax.grad(lambda a: jnp.sum(
-        fused_qkv_attention_xbwd(a, mask, 2, None, True, spec) * do))(qkv)
-    gd = jax.grad(lambda a: jnp.sum(_dense(a, 2, mask) * do))(qkv)
-    np.testing.assert_allclose(np.asarray(gs), np.asarray(gd),
-                               rtol=5e-2, atol=5e-2)
-
-
 def test_resolve_tiers():
-    from dalle_tpu.ops.flash_attention import resolve_use_pallas
-    assert resolve_use_pallas("fused", 513, backend="tpu") == "fused"
-    # on the TPU an explicit tier that cannot be honoured names its gate
-    with pytest.raises(ValueError, match="fused_fwd_fits"):
-        resolve_use_pallas("fused", 2048, backend="tpu")
-    assert resolve_use_pallas("fused", 513, backend="cpu") is False
-    # auto selects fused where the merged kernel fits under the RAISED
-    # Mosaic vmem ceiling and measured a win: small (0.458 vs 0.391 MFU)
-    # and medium (0.638 vs 0.523 — the 32M-limit backward). The flagship
-    # h·d=1792 shape measured PARITY and stays dense; flash ≥ 2048
-    # unchanged.
-    assert resolve_use_pallas("auto", 513, backend="tpu") == "fused"
-    assert resolve_use_pallas("auto", 513, backend="tpu",
-                              dim_head=64, heads=16) == "fused"
-    assert resolve_use_pallas("auto", 513, backend="tpu",
-                              dim_head=128, heads=14) is False
-    assert resolve_use_pallas("auto", 4096, backend="tpu") == "flash"
+    from dalle_tpu.ops.attention import attention_tier
+    # auto selects fused where the merged backward fits under the RAISED
+    # Mosaic vmem ceiling: small (8 x 64) and medium (16 x 64, the 32M-limit
+    # backward); 14 x 128 does not fit and stays dense; flash from 2048 up
+    assert attention_tier("auto", 513, 8, 64, backend="tpu") == "fused"
+    assert attention_tier("auto", 513, 16, 64, backend="tpu") == "fused"
+    assert attention_tier("auto", 513, 14, 128, backend="tpu") == "dense"
+    assert attention_tier("auto", 4096, 8, 64, backend="tpu") == "flash"
+    assert attention_tier("auto", 513, 8, 64, backend="cpu") == "dense"
     assert fused_fits(513, 64, 8) and not fused_fits(2048, 64, 8)
     assert fused_fits(513, 64, 16) and not fused_fits(513, 128, 14)
-    # explicit "fused" additionally admits the fwd-kernel/XLA-bwd tier
-    # (e.g. the flagship shape, measured at parity)
-    assert resolve_use_pallas("fused", 513, backend="tpu",
-                              dim_head=128, heads=14) == "fused"
-    from dalle_tpu.ops.fused_attention import fused_fwd_fits
-    assert fused_fwd_fits(513, 64, 16) and fused_fwd_fits(513, 128, 14)
 
 
-def test_transformer_fused_mode_matches_dense():
-    """use_pallas='fused' routes the training forward (rotary ON — the
+def test_transformer_fused_mode_matches_dense(monkeypatch):
+    """The fused tier routes the training forward (rotary ON — the
     (b, n, 3h, d)-view rotary application) through the kernel and matches
-    the dense default."""
+    the dense default. Off the TPU the chooser answers dense, so the test
+    substitutes it."""
     from dalle_tpu.config import TransformerConfig
     from dalle_tpu.models.transformer import Transformer
 
@@ -182,19 +140,15 @@ def test_transformer_fused_mode_matches_dense():
     m1 = Transformer(TransformerConfig(use_pallas=False, **kw))
     params = m1.init(jax.random.PRNGKey(1), x)
     ref = m1.apply(params, x)
-    m2 = Transformer(TransformerConfig(use_pallas="fused", **kw))
-    import dalle_tpu.ops.flash_attention as fa
-    orig = fa.resolve_use_pallas
-    fa.resolve_use_pallas = lambda *a, **k2: "fused"
-    try:
-        out = m2.apply(params, x)
-    finally:
-        fa.resolve_use_pallas = orig
+    m2 = Transformer(TransformerConfig(**kw))
+    monkeypatch.setattr("dalle_tpu.models.transformer.attention_tier",
+                        lambda *a, **k2: "fused")
+    out = m2.apply(params, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-2, atol=3e-2)
 
 
-def test_transformer_fused_grads_match_dense():
+def test_transformer_fused_grads_match_dense(monkeypatch):
     """End-to-end grads through the fused kernel ≡ dense autodiff (the
     integration contract VERDICT r4 #1 names)."""
     from dalle_tpu.config import TransformerConfig
@@ -210,14 +164,10 @@ def test_transformer_fused_grads_match_dense():
         return lambda p: jnp.sum(mod.apply(p, x) ** 2)
 
     gd = jax.grad(loss(m1))(params)
-    m2 = Transformer(TransformerConfig(use_pallas="fused", **kw))
-    import dalle_tpu.ops.flash_attention as fa
-    orig = fa.resolve_use_pallas
-    fa.resolve_use_pallas = lambda *a, **k2: "fused"
-    try:
-        gk = jax.grad(loss(m2))(params)
-    finally:
-        fa.resolve_use_pallas = orig
+    m2 = Transformer(TransformerConfig(**kw))
+    monkeypatch.setattr("dalle_tpu.models.transformer.attention_tier",
+                        lambda *a, **k2: "fused")
+    gk = jax.grad(loss(m2))(params)
     for a, b in zip(jax.tree.leaves(gk), jax.tree.leaves(gd)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=6e-2, atol=6e-2)

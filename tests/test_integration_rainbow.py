@@ -109,7 +109,7 @@ def test_rainbow_heldout_generalization(tmp_path):
     caption combinations it never saw. Reference numbers: train ≈ 1.0,
     held-out ≈ 0.3, per-position > 0.8. This framework's full-scale run
     (examples/rainbow_dalle.py defaults, 1×v5e, r5) measured train 0.833 /
-    held-out 0.750 token-exact — recorded in NEXT.md. In-suite scale is
+    held-out 0.750 token-exact. In-suite scale is
     trimmed for the CPU mesh; the band asserts generalization is far above
     the chance floor (1/num_tokens), not the full-scale numbers."""
     import os

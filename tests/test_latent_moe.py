@@ -446,13 +446,22 @@ def test_the_cached_paths_and_the_engine_refuse_the_block_by_name():
         BlockConfig(attention="gqa")
 
 
-def test_latent_attention_is_the_dense_tier_whatever_the_length():
-    from dalle_tpu.ops.flash_attention import resolve_use_pallas
-    for setting in ("auto", "on", "fused", True):
-        for n in (512, 1280, 8192):
-            assert resolve_use_pallas(setting, n, backend="tpu", dim_head=128,
-                                      heads=8, attention="mla") is False
-    assert resolve_use_pallas("auto", 8192, backend="tpu") == "flash"
+def test_latent_attention_is_the_dense_tier_whatever_the_length(monkeypatch):
+    """A latent-attention stack is built without asking for a tier: at any
+    configured length every attention layer is an ``MLAttention``, which
+    has the dense ``attend`` and nothing else."""
+    from dalle_tpu.models.transformer import Transformer
+
+    def asked(*a, **kw):
+        raise AssertionError("the chooser was asked about latent attention")
+    monkeypatch.setattr("dalle_tpu.models.transformer.attention_tier", asked)
+    for fmap in (4, 32, 64):        # sequences of 24, 1032 and 4104
+        cfg = DalleConfig(**{**MODEL, "image_fmap_size": fmap,
+                             "image_size": 8 * fmap})
+        stack = Transformer(cfg.transformer()).bind({})
+        assert len(stack.attn_layers) == cfg.depth
+        assert all(type(layer.fn) is MLAttention
+                   for layer in stack.attn_layers)
 
 
 def test_a_routed_block_is_refused_on_a_mesh_of_several_devices():

@@ -367,7 +367,7 @@ def test_donate_missing_clean_when_donating_or_not_a_step():
     # bench scripts are out of scope by design
     undonated = "import jax\n@jax.jit\ndef step(s, b):\n    return s\n"
     assert lint_source("donate-missing", undonated,
-                       "scripts/bench_sweep.py") == []
+                       "scripts/serve_bench.py") == []
 
 
 # ---------------------------------------------------------------------------
