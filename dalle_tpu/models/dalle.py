@@ -62,12 +62,30 @@ class AxialPositionalEmbedding(nn.Module):
         return emb if n is None else emb[:n]
 
 
-def _ce_chunk_body(mdl, x_c, lbl_c, start: int):
-    """Head + cross-entropy for one sequence chunk — module-first so
-    ``nn.remat`` can lift it (same pattern as transformer._block_body)."""
-    logits = mdl._finish(x_c, (start, x_c.shape[1]))
+def loss_segments(cfg: DalleConfig, chunk: int):
+    """The training loss's head as ``[((r0, r1), (c0, c1))]``, in order: the
+    sequence cut every ``chunk`` positions (0: not at all) and at
+    ``text_seq_len``, so each row window holds text positions only or image
+    positions only, beside the columns of the head the logits mask allows
+    there: the text vocabulary's (per-position pads included) or the
+    codebook's."""
+    n, text_cols = cfg.total_seq_len, cfg.total_tokens - cfg.image_vocab_size
+    edges = sorted({*range(0, n, chunk or n), cfg.text_seq_len, n})
+    return [((r0, r1), (0, text_cols) if r1 <= cfg.text_seq_len
+             else (text_cols, cfg.total_tokens))
+            for r0, r1 in zip(edges, edges[1:])]
+
+
+def _ce_segment(mdl, x, labels, kernel, bias):
+    """Head + cross-entropy of one segment against its own vocabulary's
+    columns of the head; ``labels`` count from the first of them.
+    Module-first so ``nn.remat`` can lift it (same pattern as
+    transformer._block_body)."""
+    if mdl.cfg.stable:
+        x = mdl.norm_by_max(x)
+    logits = mdl.final_norm(x) @ kernel + bias
     return optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), lbl_c)
+        logits.astype(jnp.float32), labels)
 
 
 class DALLE(nn.Module):
@@ -179,6 +197,15 @@ class DALLE(nn.Module):
             tok = tok + self.image_pos_emb()[first_pos:first_pos + n]
         return tok
 
+    def _head_leaves(self):
+        """The head's two leaves as ``(kernel (dim, total_tokens), bias)``."""
+        if self.cfg.share_input_output_emb:
+            return self.shared_emb.T, self.logits_bias
+        if self.is_initializing():
+            self.head(jnp.zeros((1, self.cfg.dim)))   # declares the leaves
+        leaves = self.head.variables["params"]
+        return leaves["kernel"], leaves["bias"]
+
     def _stabilize(self, tokens):
         if self.cfg.stable:  # α-blend trick (reference :615-617)
             alpha = 0.1
@@ -225,8 +252,10 @@ class DALLE(nn.Module):
         if not return_loss:
             return self._finish(out, (0, tokens.shape[1]))
 
-        labels = jnp.concatenate(
-            [text_b[:, 1:], image_ids + self.num_text_tokens], axis=1)
+        # each position's label within its own vocabulary (the logits mask
+        # lets a text position predict text tokens only, an image position
+        # codes only: the loss never builds the columns it would forbid)
+        labels = jnp.concatenate([text_b[:, 1:], image_ids], axis=1)
         n = tokens.shape[1]
         if c.loss_chunk > 0 and n % c.loss_chunk != 0:
             raise ValueError(
@@ -234,21 +263,21 @@ class DALLE(nn.Module):
                 f"{n} — a silent fall-back would rematerialize the full "
                 f"(b, n, vocab) logits the option exists to avoid")
         with jax.named_scope("loss"):   # the vocabulary head and the CE
-            if c.loss_chunk > 0 and not self.is_initializing():
-                # chunked head+CE under remat: full (b, n, vocab) logits never
-                # hit HBM — each chunk's logits are recomputed in backward
-                parts = []
-                for i in range(0, n, c.loss_chunk):
-                    body = nn.remat(_ce_chunk_body, prevent_cse=False,
-                                    static_argnums=(3,))
-                    parts.append(body(self, out[:, i:i + c.loss_chunk],
-                                      labels[:, i:i + c.loss_chunk], i))
-                ce = jnp.concatenate(parts, axis=1)
-            else:
-                logits = self._finish(out, (0, n))
-                logits32 = logits.astype(jnp.float32)
-                ce = optax.softmax_cross_entropy_with_integer_labels(
-                    logits32, labels)
+            # chunked head+CE under remat: a segment's logits are recomputed
+            # in backward and (b, n, vocab) never hits HBM
+            chunked = c.loss_chunk > 0 and not self.is_initializing()
+            body = (nn.remat(_ce_segment, prevent_cse=False) if chunked
+                    else _ce_segment)
+            segments = loss_segments(c, c.loss_chunk if chunked else 0)
+            # the leaves are cut once a step, not once a segment: the
+            # segments' gradients add up at the cuts' shapes and meet the
+            # whole leaf's once
+            kernel, bias = self._head_leaves()
+            heads = {cols: (kernel[:, cols[0]:cols[1]], bias[cols[0]:cols[1]])
+                     for cols in dict.fromkeys(cols for _, cols in segments)}
+            ce = jnp.concatenate(
+                [body(self, out[:, r0:r1], labels[:, r0:r1], *heads[cols])
+                 for (r0, r1), cols in segments], axis=1)
             loss_text = ce[:, :c.text_seq_len].mean()
             loss_img = ce[:, c.text_seq_len:].mean()
             loss = ((loss_text + c.loss_img_weight * loss_img)
@@ -684,6 +713,20 @@ def table_grad_paths(cfg: DalleConfig, dtype, batch: int) -> Dict[str, dict]:
     return {name: {"path": grad_path(rows, cfg.dim, dtype), "rows": rows,
                    "width": cfg.dim, "ids": ids}
             for name, (rows, ids) in tables.items()}
+
+
+def loss_head(cfg: DalleConfig, batch: int) -> dict:
+    """What the training loss's head computes, from shapes alone: its
+    segments (``rows`` of positions against ``cols`` of the vocabulary, the
+    logits of a step being ``batch`` of each), and per sequence the logits
+    it builds beside the full-width head's."""
+    segments = loss_segments(cfg, cfg.loss_chunk)
+    return {"segments": [{"rows": list(rows), "cols": list(cols)}
+                         for rows, cols in segments],
+            "batch": batch,
+            "elements_computed": sum((r1 - r0) * (c1 - c0)
+                                     for (r0, r1), (c0, c1) in segments),
+            "elements_full": cfg.total_seq_len * cfg.total_tokens}
 
 
 def init_dalle(cfg: DalleConfig, key: jax.Array, batch: int = 1, sp_mesh=None):

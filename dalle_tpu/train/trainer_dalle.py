@@ -25,7 +25,7 @@ import numpy as np
 import optax
 
 from ..config import DalleConfig, TrainConfig
-from ..models.dalle import DALLE, init_dalle, table_grad_paths
+from ..models.dalle import DALLE, init_dalle, loss_head, table_grad_paths
 from ..obs import span
 from .base_trainer import BaseTrainer
 from .metrics import ThroughputMeter, count_params, transformer_train_flops
@@ -176,10 +176,13 @@ class DalleTrainer(BaseTrainer):
         self.state = self._create_state(params, self.model.apply)
         use_dropout = (model_cfg.attn_dropout > 0 or model_cfg.ff_dropout > 0)
         dtype = compute_dtype(train_cfg.precision)
-        # the tables' backward is chosen from shapes, once per compile
-        with span("init/build_step", **table_grad_paths(
-                model_cfg, jnp.float32 if dtype is None else dtype,
-                train_cfg.batch_size)):
+        # the tables' backward and the loss's head follow from shapes, once
+        # per compile
+        with span("init/build_step",
+                  head=loss_head(model_cfg, train_cfg.batch_size),
+                  **table_grad_paths(
+                      model_cfg, jnp.float32 if dtype is None else dtype,
+                      train_cfg.batch_size)):
             self.step_fn = make_dalle_train_step(
                 self.model, null_cond_prob=null_cond_prob,
                 use_dropout=use_dropout, dtype=dtype, state=self.state,

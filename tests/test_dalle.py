@@ -9,6 +9,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
 from dalle_tpu import obs
@@ -16,7 +17,8 @@ from dalle_tpu.config import (BlockConfig, ClipConfig, DalleConfig, MeshConfig,
                               OptimConfig, PrecisionConfig, TrainConfig)
 from dalle_tpu.models import dalle as dalle_module
 from dalle_tpu.models.clip import CLIP, init_clip
-from dalle_tpu.models.dalle import DALLE, init_dalle, table_grad_paths
+from dalle_tpu.models.dalle import (DALLE, init_dalle, loss_head,
+                                    table_grad_paths)
 from dalle_tpu.ops import table_lookup
 
 CFG = DalleConfig(num_text_tokens=100, text_seq_len=8, dim=32, depth=2, heads=2,
@@ -504,9 +506,11 @@ def test_trainer_step_loses_two_scatters_where_the_rule_picks_the_product(
     # the span says what was chosen, per table
     assert spans[0][5]["text_emb"] == {"path": "scatter", "rows": 40,
                                        "width": 40, "ids": 72}
+    head = spans[-1][5].pop("head")
     assert spans[-1][5] == {
         "text_emb": {"path": "product", "rows": 40, "width": 40, "ids": 72},
         "image_emb": {"path": "product", "rows": 32, "width": 40, "ids": 128}}
+    assert head == loss_head(steered.model_cfg, 8)
 
 
 def test_sharded_product_backward_matches_one_device(tmp_path, monkeypatch):
@@ -541,3 +545,155 @@ def test_sharded_product_backward_matches_one_device(tmp_path, monkeypatch):
         np.testing.assert_allclose(a, b, atol=2.0 ** -6 * np.abs(b).max(),
                                    err_msg=table)
         assert np.abs(b).max() > 0
+
+
+# -- the training loss's head, per segment of positions -----------------------
+
+def _masked_full_width_loss(mdl, text, image_ids):
+    """The loss as the full-width head computes it: every position against
+    all ``total_tokens`` columns, the static mask written over the forbidden
+    ones (``_finish``), one cross-entropy over the whole row."""
+    c = mdl.cfg
+    text_b = mdl.remap_and_bos(text)
+    tokens = jnp.concatenate(
+        [mdl.embed_text(text_b), mdl.embed_image(image_ids)],
+        axis=1)[:, :c.total_seq_len]
+    out = mdl.transformer(mdl._stabilize(tokens), deterministic=True)
+    labels = jnp.concatenate(
+        [text_b[:, 1:], image_ids + mdl.num_text_tokens], axis=1)
+    ce = optax.softmax_cross_entropy_with_integer_labels(
+        mdl._finish(out, (0, c.total_seq_len)).astype(jnp.float32), labels)
+    loss_text = ce[:, :c.text_seq_len].mean()
+    loss_img = ce[:, c.text_seq_len:].mean()
+    loss = ((loss_text + c.loss_img_weight * loss_img)
+            / (c.loss_img_weight + 1))
+    return loss, {"loss_text": loss_text, "loss_img": loss_img}
+
+
+# 8 text + 16 image positions; 58 text columns (50 + 8 pads) + 44 codes
+_SEG = dict(num_text_tokens=50, text_seq_len=8, dim=32, depth=2, heads=4,
+            dim_head=8, image_vocab_size=44, image_fmap_size=4, image_size=32)
+# tied with an rmsnorm block is no configuration: DalleConfig refuses it
+SEGMENT_HEADS = {
+    "untied-layernorm": {},
+    "tied-layernorm": dict(share_input_output_emb=True),
+    "untied-rmsnorm": dict(block=BlockConfig(feed_forward="swiglu", **_MLA)),
+    "untied-layernorm-stable": dict(stable=True),
+}
+# 0: the two halves; 4 divides text_seq_len; 6 divides 24 and [6, 12) straddles 8
+SEGMENT_CASES = [(head, chunk) for head in ("untied-layernorm",
+                                            "tied-layernorm", "untied-rmsnorm")
+                 for chunk in (0, 4, 6)] + [("untied-layernorm-stable", 6)]
+
+
+@pytest.mark.parametrize("head, chunk", SEGMENT_CASES)
+def test_segmented_loss_is_the_masked_full_width_loss(head, chunk):
+    """float32: the loss, its two halves and every leaf's gradient are those
+    of the masked full-width head up to the order of summation."""
+    cfg = DalleConfig(**_SEG, loss_chunk=chunk, **SEGMENT_HEADS[head])
+    model, params = init_dalle(cfg, jax.random.PRNGKey(1), batch=2)
+    rng = np.random.default_rng(11)
+    text = jnp.asarray(rng.integers(0, 50, (3, 8)), jnp.int32)   # 0s: pads
+    ids = jnp.asarray(rng.integers(0, 44, (3, 16)), jnp.int32)
+    # a head that starts at zero bias would hide a bias cut at the wrong column
+    params = jax.tree.map(
+        lambda v: v + 0.1 * jax.random.normal(jax.random.PRNGKey(2), v.shape),
+        params)
+
+    def both(loss_fn):
+        return jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(params)
+
+    (loss, aux), grads = both(lambda p: model.apply(p, text, ids,
+                                                    return_loss=True))
+    (ref_loss, ref_aux), ref_grads = both(
+        lambda p: nn.apply(_masked_full_width_loss, model)(p, text, ids))
+    assert float(loss) == pytest.approx(float(ref_loss), abs=1e-6)
+    for half in ("loss_text", "loss_img"):
+        assert float(aux[half]) == pytest.approx(float(ref_aux[half]),
+                                                 abs=1e-6)
+    ours, theirs = _leaves(grads), _leaves(ref_grads)
+    assert ours.keys() == theirs.keys()
+    for name, g in ours.items():
+        assert np.abs(np.asarray(theirs[name])).max() > 0, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(theirs[name]),
+                                   rtol=0, atol=1e-6, err_msg=name)
+
+
+def _avals(jaxpr):
+    """Every value an equation of ``jaxpr`` or of a jaxpr nested in it makes."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize("chunk", [0, 6])
+def test_loss_step_builds_no_full_width_logits(chunk, tied):
+    """In the loss and its gradient nothing with a batch and a position axis
+    is ``total_tokens`` wide: the head's leaves, their casts and their
+    gradients are, and the widest logits are one vocabulary's."""
+    cfg = DalleConfig(**_SEG, loss_chunk=chunk, share_input_output_emb=tied)
+    model, params = init_dalle(cfg, jax.random.PRNGKey(0), batch=2)
+    text = jnp.ones((3, 8), jnp.int32)
+    ids = jnp.zeros((3, 16), jnp.int32)
+    ours, full_width = [
+        {a.shape for a in _avals(jax.make_jaxpr(jax.value_and_grad(f))(
+            params).jaxpr) if getattr(a, "ndim", 0) >= 3}
+        for f in (lambda p: model.apply(p, text, ids, return_loss=True)[0],
+                  lambda p: nn.apply(_masked_full_width_loss, model)(
+                      p, text, ids)[0])]
+    assert (3, 24, cfg.total_tokens) in full_width           # the control
+    assert not {s for s in ours if s[-1] == cfg.total_tokens}
+    # what is built: each vocabulary's logits, over its own positions only
+    text_rows = {s[1] for s in ours if s[0] == 3 and s[-1] == 58}
+    code_rows = {s[1] for s in ours if s[0] == 3 and s[-1] == 44}
+    assert text_rows and max(text_rows) <= 8
+    assert code_rows and max(code_rows) <= 16
+
+
+@pytest.mark.parametrize("config, batch, segments, computed, full", [
+    ("dalle_small", 64, 2, 4_722_688, 9_445_376),
+    ("deepseek_v2_share16", 8, 10, 9_568_256, 16_384_000),
+    ("rudalle_malevich", 4, 9, 10_502_144, 28_459_008),
+])
+def test_loss_head_for_the_benchmarks_real_shapes(config, batch, segments,
+                                                  computed, full):
+    with open(os.path.join(BENCH_CONFIGS, f"{config}.json")) as f:
+        cfg = DalleConfig(**json.load(f)["model"])
+    got = loss_head(cfg, batch)
+    assert (len(got["segments"]), got["batch"], got["elements_computed"],
+            got["elements_full"]) == (segments, batch, computed, full)
+    text_cols = cfg.num_text_tokens + cfg.text_seq_len
+    for s in got["segments"]:      # in order, none across text_seq_len
+        text = s["rows"][1] <= cfg.text_seq_len
+        assert text or s["rows"][0] >= cfg.text_seq_len
+        assert s["cols"] == ([0, text_cols] if text
+                             else [text_cols, cfg.total_tokens])
+    assert [s["rows"][0] for s in got["segments"]][1:] == \
+        [s["rows"][1] for s in got["segments"]][:-1]
+
+
+def test_build_step_span_carries_the_loss_head(tmp_path):
+    """dalle_small's sequence and vocabularies (at a width of 16, one layer):
+    half of the full-width head's logits, as two segments."""
+    from dalle_tpu.parallel.mesh import build_mesh
+    from dalle_tpu.train.trainer_dalle import DalleTrainer
+    cfg = DalleConfig(num_text_tokens=10000, text_seq_len=256, dim=16,
+                      depth=1, heads=2, dim_head=8, image_size=128,
+                      image_vocab_size=8192, image_fmap_size=16)
+    tc = TrainConfig(batch_size=64, checkpoint_dir=str(tmp_path),
+                     preflight_checkpoint=False, mesh=MeshConfig())
+    tracer = obs.configure()
+    try:
+        DalleTrainer(cfg, tc, mesh=build_mesh(MeshConfig(),
+                                              devices=jax.devices()[:1]))
+        spans = [s for s in tracer.snapshot_spans()
+                 if s[0] == "init/build_step"]
+    finally:
+        obs.disable()
+    assert spans[-1][5]["head"] == {
+        "segments": [{"rows": [0, 256], "cols": [0, 10256]},
+                     {"rows": [256, 512], "cols": [10256, 18448]}],
+        "batch": 64, "elements_computed": 4_722_688,
+        "elements_full": 9_445_376}
