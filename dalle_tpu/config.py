@@ -234,12 +234,26 @@ class BlockConfig(ConfigBase):
     ``rmsnorm``, and ``seq_yarn`` positions (rotary over 0..n-1 with YaRN's
     frequency blend). They have no biases. Which heads and experts a chip
     holds is not the block's business: ``heads_held`` / ``experts_held`` on
-    the model's config."""
-    attention: str = "mha"             # mha | mla
+    the model's config.
+
+    A stack of more than one attention kind gives ``attention_layers``, a
+    cyclic per-layer tuple (as ``TransformerConfig.attn_types`` is for mask
+    kinds). Its further kinds are Solar-Open2's (``model_type: solar_open2``):
+    ``gqa_gated`` (``heads`` query heads over ``num_key_value_heads`` key and
+    value heads, a sigmoid gate on the attention output) and ``kda`` (Kimi
+    delta attention, arXiv:2510.26692: ``linear_num_heads`` heads of
+    ``linear_head_dim`` behind causal convolutions of
+    ``short_conv_kernel_size``, per-channel decay and output gates through
+    ``linear_gate_rank`` latents); ``positions: "none"`` adds no positional
+    term anywhere; the router scores by ``scoring_func`` and, with
+    ``norm_topk_prob``, renormalises a token's weights to sum 1."""
+    attention: str = "mha"             # mha | mla | gqa_gated | kda
+    # cyclic per-layer attention kinds; () is ``attention`` in every layer
+    attention_layers: Tuple[str, ...] = ()
     feed_forward: str = "geglu"        # geglu | swiglu | moe
     norm: str = "layernorm"            # layernorm | rmsnorm
     layerscale: bool = True
-    positions: str = "dalle_axial"     # dalle_axial | seq_yarn
+    positions: str = "dalle_axial"     # dalle_axial | seq_yarn | none
     first_dense_layers: int = 0
     rms_norm_eps: float = 1e-6
     # mla
@@ -248,6 +262,13 @@ class BlockConfig(ConfigBase):
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # gqa_gated (0 = one key/value head a query head)
+    num_key_value_heads: int = 0
+    # kda
+    linear_num_heads: int = 0
+    linear_head_dim: int = 0
+    short_conv_kernel_size: int = 4
+    linear_gate_rank: int = 0
     # seq_yarn (yarn_factor 1 is plain rotary)
     rope_theta: float = 10000.0
     yarn_factor: float = 1.0
@@ -265,28 +286,43 @@ class BlockConfig(ConfigBase):
     n_group: int = 1
     topk_group: int = 1
     routed_scaling_factor: float = 1.0
+    scoring_func: str = "softmax"      # softmax | sigmoid
+    norm_topk_prob: bool = False
 
-    KINDS = {"attention": ("mha", "mla"),
+    KINDS = {"attention": ("mha", "mla", "gqa_gated", "kda"),
              "feed_forward": ("geglu", "swiglu", "moe"),
              "norm": ("layernorm", "rmsnorm"),
-             "positions": ("dalle_axial", "seq_yarn")}
+             "positions": ("dalle_axial", "seq_yarn", "none"),
+             "scoring_func": ("softmax", "sigmoid")}
 
     def __post_init__(self):
         for field_name, kinds in self.KINDS.items():
             if getattr(self, field_name) not in kinds:
                 raise ValueError(f"block.{field_name} must be one of {kinds}, "
                                  f"got {getattr(self, field_name)!r}")
+        for kind in self.attention_layers:
+            if kind not in self.KINDS["attention"]:
+                raise ValueError(
+                    f"block.attention_layers holds {kind!r}, not one of "
+                    f"{self.KINDS['attention']}")
+
+    @property
+    def attention_kinds(self) -> Tuple[str, ...]:
+        """One period of the stack's attention kinds; layer ``i`` is kind
+        ``i % len``."""
+        return tuple(self.attention_layers) or (self.attention,)
 
     @property
     def is_default(self) -> bool:
-        return (self.attention, self.feed_forward, self.norm,
+        return (self.attention_kinds, self.feed_forward, self.norm,
                 self.positions, self.layerscale) == (
-                    "mha", "geglu", "layernorm", "dalle_axial", True)
+                    ("mha",), "geglu", "layernorm", "dalle_axial", True)
 
     @property
     def name(self) -> str:
-        """The block kind, for messages: ``mla+moe``."""
-        return f"{self.attention}+{self.feed_forward}"
+        """The block kind, for messages: ``mla+moe``, ``gqa_gated/kda+moe``."""
+        kinds = "/".join(dict.fromkeys(self.attention_kinds))
+        return f"{kinds}+{self.feed_forward}"
 
 
 @dataclass(frozen=True)
@@ -392,7 +428,7 @@ class DalleConfig(ConfigBase):
                 f"pre-norm stack: reversible, shift_tokens, sandwich_norm, "
                 f"stable, shared layers, tied embeddings and sparse "
                 f"attn_types belong to the default block")
-        if b.attention == "mla" and self.dim_head != b.v_head_dim:
+        if "mla" in b.attention_kinds and self.dim_head != b.v_head_dim:
             raise ValueError(f"mla: dim_head ({self.dim_head}) is the value "
                              f"head width, block.v_head_dim ({b.v_head_dim})")
 

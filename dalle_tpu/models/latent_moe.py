@@ -191,6 +191,8 @@ class MoEFeedForward(nn.Module):
     routed_scale: float
     n_shared: int
     first_expert: int = 0
+    scoring: str = "softmax"       # softmax | sigmoid
+    norm_topk: bool = False        # a token's weights renormalised to sum 1
 
     def setup(self):
         e, d, f = self.experts_held, self.dim, self.inner
@@ -210,15 +212,20 @@ class MoEFeedForward(nn.Module):
 
     def route(self, rows):
         """(weights (t, top_k) float32, expert indices (t, top_k)): the
-        router's product and softmax run in float32, as the source's gate
-        does, whatever the compute type."""
+        router's product and scores (a softmax over the experts, or a
+        sigmoid each) run in float32, as the source's gate does, whatever
+        the compute type. With ``norm_topk`` a token's weights are divided
+        by their sum over its ``top_k`` choices, held here or not."""
         logits = jnp.einsum(
             "td,de->te", rows.astype(jnp.float32),
             self.router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
-        weights, idx = group_limited_top_k(
-            jax.nn.softmax(logits, axis=-1), self.n_group, self.topk_group,
-            self.top_k)
+        scores = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        weights, idx = group_limited_top_k(scores, self.n_group,
+                                           self.topk_group, self.top_k)
+        if self.norm_topk:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         return weights * self.routed_scale, idx
 
     def __call__(self, x, deterministic: bool = True):

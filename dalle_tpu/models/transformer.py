@@ -37,11 +37,17 @@ from ..config import TransformerConfig
 from ..ops.attention import (KVCache, attend, attention_tier, cached_attend,
                              cached_attend_window)
 from ..ops.attn_masks import build_mask
+from ..ops.kda import CHUNK, chunks_of
 from ..ops.quantize_weights import QDense
 from ..ops.rotary import (apply_rotary, dalle_pos_emb, seq_yarn_table,
                           yarn_mscale)
+from .hybrid_attention import GatedGQAttention, KimiDeltaAttention
 from .latent_moe import (MLAttention, MoEFeedForward, RMSNorm,
                          SwiGLUFeedForward)
+
+# how a counter of the layers that count is reduced over depth (the rest
+# are summed)
+_WORST_LAYER = {"moe_load_max_over_mean": jnp.max, "kda_logdecay_min": jnp.min}
 
 
 def _block_body(mdl, x, key_mask, ind: int, deterministic: bool):
@@ -49,14 +55,42 @@ def _block_body(mdl, x, key_mask, ind: int, deterministic: bool):
     (flax replays dropout rngs inside the recompute automatically, replacing
     the reference's manual RNG save/restore, reversible.py:20-50)."""
     t = mdl.mask_keys[ind]
-    x = x + mdl.attn_layers[ind](x, key_mask=key_mask, rotary=mdl.rotary,
-                                 np_mask=mdl.np_masks[t],
-                                 mask_spec=mdl.mask_specs[t],
-                                 deterministic=deterministic)
-    y = mdl.ff_layers[ind](x, deterministic=deterministic)
-    # a feed-forward that counts (MoEFeedForward) returns (output, counters)
+    y = mdl.attn_layers[ind](x, key_mask=key_mask, rotary=mdl.rotary,
+                             np_mask=mdl.np_masks[t],
+                             mask_spec=mdl.mask_specs[t],
+                             deterministic=deterministic)
+    # a layer that counts (KimiDeltaAttention, MoEFeedForward) returns
+    # (output, counters)
     y, counters = y if isinstance(y, tuple) else (y, {})
+    x = x + y
+    y = mdl.ff_layers[ind](x, deterministic=deterministic)
+    if isinstance(y, tuple):
+        y, counters = y[0], {**counters, **y[1]}
     return x + y, counters
+
+
+def stack_layers(c: TransformerConfig) -> dict:
+    """What the stack is built from, from the configuration alone: every
+    layer's attention kind, the tier chosen for the softmax layers, and per
+    kind its heads (``kv_heads`` for grouped keys and values; ``chunk`` and
+    ``chunks`` a sequence for the chunked recurrence)."""
+    blk = c.block
+    kinds = list(islice(cycle(blk.attention_kinds), c.depth))
+    out = {"kinds": kinds, "tier": (
+        attention_tier(c.use_pallas, c.seq_len, c.heads, c.dim_head)
+        if {"mha", "gqa_gated"} & set(kinds) else "dense")}
+    for kind in dict.fromkeys(kinds):
+        if kind == "kda":
+            out[kind] = {"heads": blk.linear_num_heads,
+                         "head_dim": blk.linear_head_dim, "chunk": CHUNK,
+                         "chunks": chunks_of(c.seq_len)}
+        elif kind == "gqa_gated":
+            out[kind] = {"heads": c.heads, "head_dim": c.dim_head,
+                         "kv_heads": blk.num_key_value_heads or c.heads}
+        else:
+            out[kind] = {"heads": c.heads_held or c.heads,
+                         "head_dim": c.dim_head}
+    return out
 
 
 def layerscale_init_eps(layer_index_1based: int) -> float:
@@ -462,11 +496,12 @@ class Transformer(nn.Module):
         img_seq = fmap * fmap
         self.text_len = c.seq_len + 1 - img_seq if c.causal else 0
         blk = c.block
-        # chosen once, from the configured length: the model keeps its tier
-        # at every runtime length. Latent attention has no kernel path
-        # (MLAttention: two head widths) and is built without asking.
-        tier = (attention_tier(c.use_pallas, c.seq_len, c.heads, c.dim_head)
-                if blk.attention == "mha" else "dense")
+        # chosen once, from the configured length, for the softmax layers of
+        # one head width: the model keeps its tier at every runtime length.
+        # Latent attention has no kernel path (MLAttention: two head widths)
+        # and linear attention no scores: they are built without asking.
+        layers = stack_layers(c)
+        self.attn_kinds, tier = layers["kinds"], layers["tier"]
 
         attn_types = tuple(c.attn_types) or ("full",)
         type_per_layer = list(islice(cycle(attn_types), c.depth))
@@ -531,7 +566,7 @@ class Transformer(nn.Module):
                     f"sin would be scaled by {cos_sin_scale}, which "
                     f"apply_rotary does not do")
             self.rotary = jnp.asarray(angles)
-        elif c.rotary_emb and c.causal:
+        elif blk.positions == "dalle_axial" and c.rotary_emb and c.causal:
             self.rotary = jnp.asarray(
                 dalle_pos_emb(self.text_len, fmap, c.dim_head))
 
@@ -549,7 +584,8 @@ class Transformer(nn.Module):
                         f"attn_types do not match shared_attn_ids (ind={ind}, "
                         f'attn_type="{t}", reused="{prev_t}")')
             else:
-                attn = self._make_attention(f"attn_{aid}", tier)
+                attn = self._make_attention(f"attn_{aid}", tier,
+                                            self.attn_kinds[ind])
                 shared_attn[aid] = (attn, t)
             if fid in shared_ff:
                 ff = shared_ff[fid]
@@ -570,9 +606,24 @@ class Transformer(nn.Module):
         self.ff_layers = ff_layers
 
     # -- the block's kinds (config.BlockConfig) -----------------------------
-    def _make_attention(self, name: str, tier: str):
+    def _make_attention(self, name: str, tier: str, kind: str):
         c, blk = self.cfg, self.cfg.block
-        if blk.attention == "mha":
+        if kind in ("gqa_gated", "kda"):
+            if blk.positions != "none" or not c.causal or self.sp_mesh:
+                raise ValueError(f"{kind} is causal, takes no positional "
+                                 f"term (positions: none) and has no "
+                                 f"sequence-parallel path")
+            if kind == "kda":
+                return KimiDeltaAttention(
+                    c.dim, blk.linear_num_heads, blk.linear_head_dim,
+                    conv_size=blk.short_conv_kernel_size,
+                    gate_rank=blk.linear_gate_rank, eps=blk.rms_norm_eps,
+                    name=name)
+            return GatedGQAttention(
+                c.dim, c.heads, blk.num_key_value_heads or c.heads,
+                c.dim_head, tier=tier, softmax_f32=c.attn_softmax_f32,
+                name=name)
+        if kind == "mha":
             if blk.positions != "dalle_axial":
                 raise ValueError("mha takes the dalle_axial rotary table")
             return Attention(c.dim, c.heads, c.dim_head, c.attn_dropout,
@@ -606,19 +657,21 @@ class Transformer(nn.Module):
             n_routed_experts=blk.n_routed_experts, n_group=blk.n_group,
             topk_group=blk.topk_group, top_k=blk.num_experts_per_tok,
             routed_scale=blk.routed_scaling_factor,
-            n_shared=blk.n_shared_experts, name=name)
+            n_shared=blk.n_shared_experts, scoring=blk.scoring_func,
+            norm_topk=blk.norm_topk_prob, name=name)
 
     def _refuse_cached(self, what: str):
         """The cached paths are written for multi-head keys and values in a
         ``KVCache`` / ``PagedKVCache``; the block kinds without that layout
         are refused by name, not run wrongly."""
         blk = self.cfg.block
-        if blk.attention != "mha" or blk.feed_forward == "moe":
+        if set(blk.attention_kinds) != {"mha"} or blk.feed_forward == "moe":
             raise NotImplementedError(
                 f"{what}: the {blk.name} block has no cached decode path "
-                f"(latent keys and values have no KVCache layout, and a "
-                f"routed layer returns counters); it trains through "
-                f"Transformer.__call__ only")
+                f"(latent keys and values, grouped key/value heads, a "
+                f"recurrent state and its convolution tail have no KVCache "
+                f"layout, and a routed layer returns counters); it trains "
+                f"through Transformer.__call__ only")
 
     def _dense_mask(self, t):
         m = self.np_masks[t]
@@ -635,8 +688,8 @@ class Transformer(nn.Module):
 
         ``return_aux``: also return the layers' counters, reduced over depth
         (``moe_rows_held`` and ``moe_rows_dropped`` summed,
-        ``moe_load_max_over_mean`` of the worst layer); {} for a stack whose
-        layers count nothing."""
+        ``moe_load_max_over_mean`` and ``kda_logdecay_min`` of the worst
+        layer); {} for a stack whose layers count nothing."""
         c = self.cfg
         if c.reversible:
             out = self._call_reversible(x, key_mask, deterministic)
@@ -659,9 +712,10 @@ class Transformer(nn.Module):
                 counted.append(counters)
         if not return_aux:
             return x
-        aux = {k: (jnp.max if k == "moe_load_max_over_mean" else jnp.sum)(
-            jnp.stack([layer[k] for layer in counted]))
-            for k in (counted[0] if counted else {})}
+        names = dict.fromkeys(k for layer in counted for k in layer)
+        aux = {k: _WORST_LAYER.get(k, jnp.sum)(
+            jnp.stack([layer[k] for layer in counted if k in layer]))
+            for k in names}
         return x, aux
 
     def _call_reversible(self, x, key_mask, deterministic: bool):

@@ -244,8 +244,9 @@ class DecodeEngine:
             raise NotImplementedError(
                 f"the serve engine decodes the default mha+geglu block only; "
                 f"the {c.block.name} block has no cached decode path yet "
-                f"(latent keys and values through KVCache / PagedKVCache and "
-                f"the decode kernels)")
+                f"(latent or grouped keys and values through KVCache / "
+                f"PagedKVCache and the decode kernels; a recurrent state and "
+                f"its convolution tail beside them)")
         attn_types = tuple(c.attn_types) or ("full",)
         if any(t != "full" for t in attn_types) or c.shift_tokens:
             # same constraint set as speculative decode: per-row windows

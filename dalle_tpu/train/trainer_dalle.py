@@ -26,6 +26,7 @@ import optax
 
 from ..config import DalleConfig, TrainConfig
 from ..models.dalle import DALLE, init_dalle, loss_head, table_grad_paths
+from ..models.transformer import stack_layers
 from ..obs import span
 from .base_trainer import BaseTrainer
 from .metrics import ThroughputMeter, count_params, transformer_train_flops
@@ -180,6 +181,7 @@ class DalleTrainer(BaseTrainer):
         # per compile
         with span("init/build_step",
                   head=loss_head(model_cfg, train_cfg.batch_size),
+                  layers=stack_layers(model_cfg.transformer()),
                   **table_grad_paths(
                       model_cfg, jnp.float32 if dtype is None else dtype,
                       train_cfg.batch_size)):

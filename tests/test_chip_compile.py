@@ -148,6 +148,17 @@ def test_flash_fwd_bwd_long(one_chip):
     _mosaic_text(_flash_loss(None), *qkv)
 
 
+def test_flash_fwd_bwd_solar2_softmax_layer(one_chip):
+    """train_solar2_ep32_fit's softmax layer as ``GatedGQAttention`` calls
+    the flash tier: batch 2, the 8 key/value heads repeated to the 64 query
+    heads of 128, 4352 positions (17 blocks of 256). The three kernels by the
+    names the benchmark's rooflines read."""
+    qkv = [_sds(one_chip, (2, 64, 4352, 128), jnp.bfloat16)] * 3
+    text = _mosaic_text(_flash_loss(None), *qkv)
+    for kernel in ("flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"):
+        assert kernel in text, kernel
+
+
 @pytest.mark.parametrize("masked", [False, True],
                          ids=["mask_free", "axial_masked"])
 def test_flash_fwd_bwd_512(one_chip, masked):

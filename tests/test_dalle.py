@@ -507,6 +507,11 @@ def test_trainer_step_loses_two_scatters_where_the_rule_picks_the_product(
     assert spans[0][5]["text_emb"] == {"path": "scatter", "rows": 40,
                                        "width": 40, "ids": 72}
     head = spans[-1][5].pop("head")
+    # (what the stack is built from rides the same span since PR 32)
+    assert spans[-1][5].pop("layers") == {
+        "kinds": ["mha"] * steered.model_cfg.depth, "tier": "dense",
+        "mha": {"heads": steered.model_cfg.heads,
+                "head_dim": steered.model_cfg.dim_head}}
     assert spans[-1][5] == {
         "text_emb": {"path": "product", "rows": 40, "width": 40, "ids": 72},
         "image_emb": {"path": "product", "rows": 32, "width": 40, "ids": 128}}
