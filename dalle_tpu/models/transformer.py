@@ -69,11 +69,64 @@ def _block_body(mdl, x, key_mask, ind: int, deterministic: bool):
     return x + y, counters
 
 
+def _text_len(c: TransformerConfig) -> int:
+    return c.seq_len + 1 - c.image_fmap_size ** 2 if c.causal else 0
+
+
+def layer_masks(c: TransformerConfig):
+    """Per layer the key of its static mask, and per key the mask (None for
+    'full': plain causal, handled in attend) and its structured spec.
+
+    Masks are kept as NUMPY (the pallas path needs host-side masks for
+    block-list construction; the dense path converts per-trace, folded by
+    XLA). Deterministic mask types share one entry per type; 'sparse' gets
+    a per-LAYER entry with seed = sparse_mask_seed + layer_index, so each
+    sparse layer draws its own random-block pattern (DeepSpeed
+    VariableSparsityConfig parity — one shared pattern would silently
+    narrow the reference semantics)."""
+    text_len, fmap = _text_len(c), c.image_fmap_size
+    type_per_layer = list(islice(cycle(tuple(c.attn_types) or ("full",)),
+                                 c.depth))
+    mask_keys = [f"sparse_{ind}" if t == "sparse" else t
+                 for ind, t in enumerate(type_per_layer)]
+    masks: Dict[str, Optional[np.ndarray]] = {}
+    specs: Dict[str, Optional[tuple]] = {}
+    for ind, (mk, t) in enumerate(zip(mask_keys, type_per_layer)):
+        if mk in masks:
+            continue
+        if t == "full" or not c.causal:
+            masks[mk], specs[mk] = None, None
+            continue
+        masks[mk] = build_mask(
+            t, text_len, fmap, kernel_size=c.sparse_attn_kernel,
+            block=c.sparse_block_size,
+            num_random_blocks=c.sparse_num_random_blocks,
+            seed=c.sparse_mask_seed + ind)
+        # structured-mask specs: the pallas kernels compute axial/conv
+        # element visibility from iotas instead of loading a mask table
+        # (ops/flash_attention.py elem_fn_from_spec)
+        if t in ("axial_row", "axial_col"):
+            specs[mk] = ("axial", text_len, fmap,
+                         0 if t == "axial_row" else 1)
+        elif t == "conv_like":
+            specs[mk] = ("conv", text_len, fmap, c.sparse_attn_kernel, 1)
+        elif t == "sparse":
+            # block-aligned random-block pattern: kernel tiles coincide
+            # with the pattern's block grid, no element mask needed
+            specs[mk] = ("block", c.sparse_block_size)
+        else:
+            specs[mk] = None
+    return mask_keys, masks, specs
+
+
 def stack_layers(c: TransformerConfig) -> dict:
     """What the stack is built from, from the configuration alone: every
     layer's attention kind, the tier chosen for the softmax layers, and per
     kind its heads (``kv_heads`` for grouped keys and values; ``chunk`` and
-    ``chunks`` a sequence for the chunked recurrence)."""
+    ``chunks`` a sequence for the chunked recurrence). Under ``fused``, where
+    that is the tier: how many score blocks of the square the kernel forms
+    (``ops.fused_attention.block_plan`` of each distinct table of the stack,
+    summed) and the products of its backward."""
     blk = c.block
     kinds = list(islice(cycle(blk.attention_kinds), c.depth))
     out = {"kinds": kinds, "tier": (
@@ -90,6 +143,14 @@ def stack_layers(c: TransformerConfig) -> dict:
         else:
             out[kind] = {"heads": c.heads_held or c.heads,
                          "head_dim": c.dim_head}
+    if out["tier"] == "fused":
+        from ..ops.fused_attention import block_plan, validity_table
+        _, masks, specs = layer_masks(c)
+        plans = [block_plan(validity_table(c.seq_len, masks[k], specs[k]))
+                 for k in masks]
+        out["fused"] = {"score_blocks": [sum(p.computed for p in plans),
+                                         sum(p.of for p in plans)],
+                        "products_bwd": 5}
     return out
 
 
@@ -493,8 +554,7 @@ class Transformer(nn.Module):
     def setup(self):
         c = self.cfg
         fmap = c.image_fmap_size
-        img_seq = fmap * fmap
-        self.text_len = c.seq_len + 1 - img_seq if c.causal else 0
+        self.text_len = _text_len(c)
         blk = c.block
         # chosen once, from the configured length, for the softmax layers of
         # one head width: the model keeps its tier at every runtime length.
@@ -507,45 +567,7 @@ class Transformer(nn.Module):
         type_per_layer = list(islice(cycle(attn_types), c.depth))
         attn_ids = list(islice(cycle(c.shared_attn_ids or range(c.depth)), c.depth))
         ff_ids = list(islice(cycle(c.shared_ff_ids or range(c.depth)), c.depth))
-
-        # static masks (None for 'full' — plain causal handled in attend);
-        # kept as NUMPY (the pallas path needs host-side masks for block-list
-        # construction; the dense path converts per-trace, folded by XLA).
-        # Deterministic mask types share one entry per type; 'sparse' gets a
-        # per-LAYER entry with seed = sparse_mask_seed + layer_index, so each
-        # sparse layer draws its own random-block pattern (DeepSpeed
-        # VariableSparsityConfig parity — one shared pattern would silently
-        # narrow the reference semantics)
-        mask_keys = [f"sparse_{ind}" if t == "sparse" else t
-                     for ind, t in enumerate(type_per_layer)]
-        masks: Dict[str, Optional[np.ndarray]] = {}
-        specs: Dict[str, Optional[tuple]] = {}
-        for ind, (mk, t) in enumerate(zip(mask_keys, type_per_layer)):
-            if mk in masks:
-                continue
-            if t == "full" or not c.causal:
-                masks[mk], specs[mk] = None, None
-                continue
-            masks[mk] = build_mask(
-                t, self.text_len, fmap, kernel_size=c.sparse_attn_kernel,
-                block=c.sparse_block_size,
-                num_random_blocks=c.sparse_num_random_blocks,
-                seed=c.sparse_mask_seed + ind)
-            # structured-mask specs: the pallas kernels compute axial/conv
-            # element visibility from iotas instead of loading a mask table
-            # (ops/flash_attention.py elem_fn_from_spec)
-            if t in ("axial_row", "axial_col"):
-                specs[mk] = ("axial", self.text_len, fmap,
-                             0 if t == "axial_row" else 1)
-            elif t == "conv_like":
-                specs[mk] = ("conv", self.text_len, fmap,
-                             c.sparse_attn_kernel, 1)
-            elif t == "sparse":
-                # block-aligned random-block pattern: kernel tiles coincide
-                # with the pattern's block grid, no element mask needed
-                specs[mk] = ("block", c.sparse_block_size)
-            else:
-                specs[mk] = None
+        mask_keys, masks, specs = layer_masks(c)
         self.np_masks = masks
         self.mask_specs = specs
         self.mask_keys = mask_keys
