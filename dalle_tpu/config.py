@@ -246,7 +246,25 @@ class BlockConfig(ConfigBase):
     ``short_conv_kernel_size``, per-channel decay and output gates through
     ``linear_gate_rank`` latents); ``positions: "none"`` adds no positional
     term anywhere; the router scores by ``scoring_func`` and, with
-    ``norm_topk_prob``, renormalises a token's weights to sum 1."""
+    ``norm_topk_prob``, renormalises a token's weights to sum 1.
+
+    One stack may also mix ``kda`` with ``mla`` layers (``model_type:
+    bailing_hybrid``): ``positions: "seq_yarn"`` then turns the latent
+    layers' rotary parts and the linear layers take no notice of it.
+    ``q_lora_rank: 0`` projects the queries directly; ``qk_norm`` puts a
+    learned RMSNorm of a head's width on every query and key ahead of the
+    rotation; ``attention_gate: "head_wise"`` multiplies each head's output
+    by a sigmoid of the layer's input, one gate a head. For ``kda``,
+    ``linear_gate_rank: 0`` makes the decay's and the output gate's
+    projections whole (no latent), ``kda_lower_bound`` < 0 bounds the
+    log-decay from below, ``g = lower_bound * sigmoid(exp(A) (W_f x + b))``
+    (0: ``g = -exp(A) softplus(W_f x + b)``), and ``kda_beta_max`` is
+    beta's range, ``beta = kda_beta_max * sigmoid(W_beta x)``. The router's
+    ``topk_method``: ``group_limited_greedy`` (a group's score is its best
+    expert's, and the scores select and weigh) or ``noaux_tc`` (DeepSeek-V3,
+    arXiv:2412.19437: a per-expert bias, a leaf no gradient reaches, is
+    added to the scores that select, a group's score is the sum of its two
+    best, and the unbiased scores weigh)."""
     attention: str = "mha"             # mha | mla | gqa_gated | kda
     # cyclic per-layer attention kinds; () is ``attention`` in every layer
     attention_layers: Tuple[str, ...] = ()
@@ -256,19 +274,23 @@ class BlockConfig(ConfigBase):
     positions: str = "dalle_axial"     # dalle_axial | seq_yarn | none
     first_dense_layers: int = 0
     rms_norm_eps: float = 1e-6
-    # mla
+    # mla (q_lora_rank 0: queries without a latent)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    qk_norm: bool = False
+    attention_gate: str = "none"       # none | head_wise
     # gqa_gated (0 = one key/value head a query head)
     num_key_value_heads: int = 0
     # kda
     linear_num_heads: int = 0
     linear_head_dim: int = 0
     short_conv_kernel_size: int = 4
-    linear_gate_rank: int = 0
+    linear_gate_rank: int = 0          # 0: whole projections, no latent
+    kda_lower_bound: float = 0.0       # < 0: the bounded decay
+    kda_beta_max: float = 2.0
     # seq_yarn (yarn_factor 1 is plain rotary)
     rope_theta: float = 10000.0
     yarn_factor: float = 1.0
@@ -288,12 +310,15 @@ class BlockConfig(ConfigBase):
     routed_scaling_factor: float = 1.0
     scoring_func: str = "softmax"      # softmax | sigmoid
     norm_topk_prob: bool = False
+    topk_method: str = "group_limited_greedy"   # | noaux_tc
 
     KINDS = {"attention": ("mha", "mla", "gqa_gated", "kda"),
              "feed_forward": ("geglu", "swiglu", "moe"),
              "norm": ("layernorm", "rmsnorm"),
              "positions": ("dalle_axial", "seq_yarn", "none"),
-             "scoring_func": ("softmax", "sigmoid")}
+             "scoring_func": ("softmax", "sigmoid"),
+             "attention_gate": ("none", "head_wise"),
+             "topk_method": ("group_limited_greedy", "noaux_tc")}
 
     def __post_init__(self):
         for field_name, kinds in self.KINDS.items():
@@ -413,6 +438,12 @@ class DalleConfig(ConfigBase):
     block: BlockConfig = BlockConfig()
     heads_held: int = 0
     experts_held: int = 0
+    # multi-token prediction (DeepSeek-V3, arXiv:2412.19437 section 2.2):
+    # ``mtp_depth`` blocks (0 or 1) of one mla + moe layer behind the stack
+    # predict the token after the next through the main head;
+    # loss = main + mtp_loss_weight * the block's
+    mtp_depth: int = 0
+    mtp_loss_weight: float = 0.1
 
     def __post_init__(self):
         if isinstance(self.block, dict):     # DalleConfig(**a JSON object)
@@ -431,6 +462,14 @@ class DalleConfig(ConfigBase):
         if "mla" in b.attention_kinds and self.dim_head != b.v_head_dim:
             raise ValueError(f"mla: dim_head ({self.dim_head}) is the value "
                              f"head width, block.v_head_dim ({b.v_head_dim})")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth {self.mtp_depth}: one "
+                             f"multi-token-prediction block or none")
+        if self.mtp_depth and (b.feed_forward != "moe"
+                               or "mla" not in b.attention_kinds):
+            raise ValueError(
+                f"the multi-token-prediction block is a latent-attention "
+                f"layer and a routed layer: the {b.name} block has not both")
 
     @property
     def image_seq_len(self) -> int:
@@ -461,6 +500,16 @@ class DalleConfig(ConfigBase):
             block=self.block, heads_held=self.heads_held,
             experts_held=self.experts_held,
         )
+
+
+    def mtp_transformer(self) -> TransformerConfig:
+        """The multi-token-prediction block as a stack of its own: one
+        latent-attention layer and one routed layer at the model's widths,
+        positions and share."""
+        return dataclasses.replace(
+            self.transformer(), depth=1, block=dataclasses.replace(
+                self.block, attention="mla", attention_layers=(),
+                first_dense_layers=0))
 
 
 @dataclass(frozen=True)

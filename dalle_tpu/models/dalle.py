@@ -38,7 +38,7 @@ from ..ops.sampling import (gumbel_sample, gumbel_sample_rows,
                             prob_mask_like, top_k_filter)
 from ..ops.table_lookup import grad_path, take_rows
 from .latent_moe import RMSNorm
-from .transformer import DivideMax, Transformer
+from .transformer import DivideMax, Transformer, reduce_counters
 
 MASK_VALUE = -1e9  # max_neg/2-style fill for the logits mask
 
@@ -62,16 +62,21 @@ class AxialPositionalEmbedding(nn.Module):
         return emb if n is None else emb[:n]
 
 
-def loss_segments(cfg: DalleConfig, chunk: int):
+def loss_segments(cfg: DalleConfig, chunk: int, shift: int = 0):
     """The training loss's head as ``[((r0, r1), (c0, c1))]``, in order: the
     sequence cut every ``chunk`` positions (0: not at all) and at
     ``text_seq_len``, so each row window holds text positions only or image
     positions only, beside the columns of the head the logits mask allows
     there: the text vocabulary's (per-position pads included) or the
-    codebook's."""
-    n, text_cols = cfg.total_seq_len, cfg.total_tokens - cfg.image_vocab_size
-    edges = sorted({*range(0, n, chunk or n), cfg.text_seq_len, n})
-    return [((r0, r1), (0, text_cols) if r1 <= cfg.text_seq_len
+    codebook's. ``shift``: position ``i`` predicts the label of position
+    ``i + shift`` (the multi-token-prediction pass: 1), so there are
+    ``shift`` fewer rows and the boundary sits ``shift`` positions
+    earlier."""
+    n = cfg.total_seq_len - shift
+    text, text_cols = (cfg.text_seq_len - shift,
+                       cfg.total_tokens - cfg.image_vocab_size)
+    edges = sorted({*range(0, n, chunk or n), text, n})
+    return [((r0, r1), (0, text_cols) if r1 <= text
              else (text_cols, cfg.total_tokens))
             for r0, r1 in zip(edges, edges[1:])]
 
@@ -86,6 +91,23 @@ def _ce_segment(mdl, x, labels, kernel, bias):
     logits = mdl.final_norm(x) @ kernel + bias
     return optax.softmax_cross_entropy_with_integer_labels(
         logits.astype(jnp.float32), labels)
+
+
+def _ce_segment_mtp(mdl, x, labels, kernel, bias):
+    """``_ce_segment`` behind the multi-token-prediction block's own final
+    norm (the head's two leaves are the main pass's)."""
+    logits = mdl.mtp_final_norm(x) @ kernel + bias
+    return optax.softmax_cross_entropy_with_integer_labels(
+        logits.astype(jnp.float32), labels)
+
+
+def _mtp_merge(mdl, hidden, tokens):
+    """h'_i = W_eh [RMSNorm(h_i) ; RMSNorm(e_{i+1})] for i = 0 .. n - 2:
+    the stack's output before the final norm beside the embedded input one
+    position on. Module-first for ``nn.remat``."""
+    return mdl.mtp_merge(jnp.concatenate(
+        [mdl.mtp_norm_h(hidden[:, :-1]), mdl.mtp_norm_e(tokens[:, 1:])],
+        axis=-1))
 
 
 class DALLE(nn.Module):
@@ -126,6 +148,18 @@ class DALLE(nn.Module):
             RMSNorm(c.block.rms_norm_eps, name="final_norm")
             if c.block.norm == "rmsnorm" else nn.LayerNorm(name="final_norm"))
         self.norm_by_max = DivideMax(axis=-1)
+        if c.mtp_depth:
+            # one more latent-attention + routed layer behind the stack
+            # (DeepSeek-V3, arXiv:2412.19437 section 2.2), built as a stack
+            # of depth 1: its tier, rotary table, remat and counters are
+            # ``Transformer``'s
+            eps = c.block.rms_norm_eps
+            self.mtp_norm_h = RMSNorm(eps, name="mtp_norm_h")
+            self.mtp_norm_e = RMSNorm(eps, name="mtp_norm_e")
+            self.mtp_merge = nn.Dense(c.dim, use_bias=False, name="mtp_merge")
+            self.mtp_block = Transformer(c.mtp_transformer(),
+                                         name="mtp_block")
+            self.mtp_final_norm = RMSNorm(eps, name="mtp_final_norm")
 
         # static (seq, total_tokens) allow-mask: text positions predict text
         # tokens, image positions image tokens (reference :428-439, inverted
@@ -282,8 +316,46 @@ class DALLE(nn.Module):
             loss_img = ce[:, c.text_seq_len:].mean()
             loss = ((loss_text + c.loss_img_weight * loss_img)
                     / (c.loss_img_weight + 1))
-        return loss, {"loss_text": loss_text, "loss_img": loss_img,
-                      **counters}
+        aux = {"loss_text": loss_text, "loss_img": loss_img}
+        if c.mtp_depth:
+            loss_mtp, mtp_counters = self._mtp_loss(out, tokens, labels,
+                                                    heads, deterministic)
+            loss = loss + c.mtp_loss_weight * loss_mtp
+            aux["loss_mtp"] = loss_mtp
+            counters = reduce_counters([counters, mtp_counters])
+        return loss, {**aux, **counters}
+
+    def _mtp_loss(self, hidden, tokens, labels, heads, deterministic: bool):
+        """The multi-token-prediction pass: position ``i`` of the block's
+        output predicts ``labels[i + 1]`` through the main head's leaves
+        (``heads``: the cut kernels and biases by column range). The same
+        text 1 : image 7 weighted cross-entropy over its n - 1 positions,
+        each against the vocabulary of the position it predicts, chunked
+        and rematerialised like the main head. Returns (loss, the block's
+        counters)."""
+        c = self.cfg
+        remat = c.use_remat and not self.is_initializing()
+        with jax.named_scope("mtp/merge"):
+            merge = (nn.remat(_mtp_merge, prevent_cse=False) if remat
+                     else _mtp_merge)
+            x = merge(self, hidden, tokens)
+        with jax.named_scope("mtp/block"):
+            x, counters = self.mtp_block(x, deterministic=deterministic,
+                                         return_aux=True)
+        with jax.named_scope("loss/mtp"):
+            chunked = c.loss_chunk > 0 and not self.is_initializing()
+            body = (nn.remat(_ce_segment_mtp, prevent_cse=False) if chunked
+                    else _ce_segment_mtp)
+            ahead = labels[:, 1:]
+            ce = jnp.concatenate(
+                [body(self, x[:, r0:r1], ahead[:, r0:r1], *heads[cols])
+                 for (r0, r1), cols in loss_segments(
+                     c, c.loss_chunk if chunked else 0, shift=1)], axis=1)
+            text = c.text_seq_len - 1
+            loss = ((ce[:, :text].mean()
+                     + c.loss_img_weight * ce[:, text:].mean())
+                    / (c.loss_img_weight + 1))
+        return loss, counters
 
     # -- generation --------------------------------------------------------
     def _prefill(self, text, image_prime: Optional[jnp.ndarray], batch: int,
@@ -720,13 +792,22 @@ def loss_head(cfg: DalleConfig, batch: int) -> dict:
     segments (``rows`` of positions against ``cols`` of the vocabulary, the
     logits of a step being ``batch`` of each), and per sequence the logits
     it builds beside the full-width head's."""
+    def listed(segments):
+        return [{"rows": list(rows), "cols": list(cols)}
+                for rows, cols in segments]
+
+    def elements(segments):
+        return sum((r1 - r0) * (c1 - c0) for (r0, r1), (c0, c1) in segments)
     segments = loss_segments(cfg, cfg.loss_chunk)
-    return {"segments": [{"rows": list(rows), "cols": list(cols)}
-                         for rows, cols in segments],
-            "batch": batch,
-            "elements_computed": sum((r1 - r0) * (c1 - c0)
-                                     for (r0, r1), (c0, c1) in segments),
-            "elements_full": cfg.total_seq_len * cfg.total_tokens}
+    out = {"segments": listed(segments), "batch": batch,
+           "elements_computed": elements(segments),
+           "elements_full": cfg.total_seq_len * cfg.total_tokens}
+    if cfg.mtp_depth:
+        # the multi-token-prediction pass's own segments, one row fewer
+        ahead = loss_segments(cfg, cfg.loss_chunk, shift=1)
+        out["mtp"] = {"segments": listed(ahead),
+                      "elements_computed": elements(ahead)}
+    return out
 
 
 def init_dalle(cfg: DalleConfig, key: jax.Array, batch: int = 1, sp_mesh=None):

@@ -9,7 +9,8 @@ No biases but the decay's, no positional term anywhere.
     q, k, v = SiLU(conv(W x))            causal depthwise, one filter a channel
     q <- q / |q| * d^-1/2,  k <- k / |k|
     g = -exp(A_h) softplus(W_f_up W_f_down x + b)      per key channel, float32
-    beta = 2 sigmoid(W_beta x)                         per head
+        (or, bounded: lower_bound * sigmoid(exp(A_h) (W_f x + b)))
+    beta = beta_max sigmoid(W_beta x)                  per head, beta_max 2
     S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
     y = W_o [RMSNorm_d(S_t^T q_t) * sigmoid(W_g_up W_g_down x)]
 
@@ -34,23 +35,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import attend
-from ..ops.kda import CHUNK, kda_chunked
-from .latent_moe import _dense
-
-# init runs un-jitted and its values are thrown away: the cores see this
-# many leading positions there (every parameter's shape is the same)
-INIT_POSITIONS = CHUNK
-
-
-def _seen(mdl, x):
-    """``x`` (b, n, dim) as a layer's core sees it: whole, or its leading
-    ``INIT_POSITIONS`` while the module initialises."""
-    return x[:, :INIT_POSITIONS] if mdl.is_initializing() else x
-
-
-def _to_length(y, n: int):
-    """Zeros behind what ``_seen`` kept, back to ``n`` positions."""
-    return jnp.pad(y, ((0, 0), (0, n - y.shape[1]), (0, 0)))
+from ..ops.kda import kda_chunked
+from .latent_moe import _dense, _seen, _to_length
 
 
 def causal_conv(x, w):
@@ -87,21 +73,27 @@ def _mixed(mdl, x):
                    for t, w in ((q, mdl.conv_q), (k, mdl.conv_k),
                                 (v, mdl.conv_v)))
     with jax.named_scope("attn/kda_gates"):
-        f = mdl.f_up(mdl.f_down(x)).reshape(shape)
-        beta = 2.0 * jax.nn.sigmoid(mdl.w_beta(x).astype(jnp.float32))
+        f = mdl.f_up(mdl.f_down(x) if mdl.gate_rank else x).reshape(shape)
+        beta = mdl.beta_max * jax.nn.sigmoid(
+            mdl.w_beta(x).astype(jnp.float32))
     return q, k, v, f, beta
 
 
 class KimiDeltaAttention(nn.Module):
     """Returns (output, {"kda_logdecay_min": ...}): the most negative
     cumulative log-decay over a chunk, which says how far the chunked form
-    is from float32's range."""
+    is from float32's range. ``gate_rank`` 0 (Ling's ``no_kda_lora``) makes
+    ``W_f`` and ``W_g`` whole projections, leaves ``f_up`` and ``g_up``
+    alone; ``lower_bound`` < 0 is the bounded decay (``ops.kda._log_decay``)
+    and ``beta_max`` beta's range."""
     dim: int
     heads: int
     dim_head: int
     conv_size: int = 4
     gate_rank: int = 128
     eps: float = 1e-5
+    lower_bound: float = 0.0
+    beta_max: float = 2.0
 
     def setup(self):
         inner = self.heads * self.dim_head
@@ -116,7 +108,9 @@ class KimiDeltaAttention(nn.Module):
         self.w_q, self.w_k, self.w_v = (_dense(inner, n) for n in "qkv")
         self.conv_q, self.conv_k, self.conv_v = (
             conv_filter(f"conv_{n}") for n in "qkv")
-        self.f_down = _dense(self.gate_rank, "f_down")
+        if self.gate_rank:
+            self.f_down = _dense(self.gate_rank, "f_down")
+            self.g_down = _dense(self.gate_rank, "g_down")
         self.f_up = _dense(inner, "f_up")
         self.a_log = self.param(
             "a_log", lambda key, shape: jnp.log(
@@ -124,7 +118,6 @@ class KimiDeltaAttention(nn.Module):
             (self.heads,))
         self.decay_bias = self.param("decay_bias", _decay_bias_init, (inner,))
         self.w_beta = _dense(self.heads, "beta")
-        self.g_down = _dense(self.gate_rank, "g_down")
         self.g_up = _dense(inner, "g_up")
         self.o_norm_scale = self.param("o_norm", nn.initializers.ones,
                                        (self.dim_head,))
@@ -153,9 +146,11 @@ class KimiDeltaAttention(nn.Module):
         o, logdecay_min = kda_chunked(
             q, k, v, f, beta, a_log=self.a_log,
             bias=self.decay_bias.reshape(h, d),
-            norm_scale=self.o_norm_scale, eps=self.eps)
+            norm_scale=self.o_norm_scale, eps=self.eps,
+            lower_bound=self.lower_bound)
         with jax.named_scope("attn/out"):
-            gate = jax.nn.sigmoid(self.g_up(self.g_down(x)))
+            gate = jax.nn.sigmoid(
+                self.g_up(self.g_down(x) if self.gate_rank else x))
             y = self.o(o.reshape(b, seen, h * d) * gate)
         return _to_length(y, n), {"kda_logdecay_min": logdecay_min}
 

@@ -14,6 +14,13 @@ partial results across chips (two all-reduces a layer), one chip has nothing
 to exchange with and nothing stands in for it. With every head and expert
 held these are the uncut layers.
 
+``MLAttention`` and the router also take Ling-3.0's forms (``model_type:
+bailing_hybrid``): queries without a latent, a learned RMSNorm on every
+head's query and key, a head-wise sigmoid gate on the output, the flash tier
+for its two head widths; ``noaux_tc`` routing (DeepSeek-V3,
+arXiv:2412.19437): a per-expert bias that selects and never weighs, groups
+scored by their two best.
+
 Training forward only: latent keys and values have no cache layout yet
 (``Transformer``'s cached paths refuse these kinds by name).
 """
@@ -30,7 +37,23 @@ import jax.numpy as jnp
 from ..ops.attention import attend
 from ..ops.grouped_matmul import (combine_rows, gather_rows,
                                   grouped_matmul)
+from ..ops.kda import CHUNK
 from ..ops.rotary import apply_rotary
+
+# init runs un-jitted and its values are thrown away: the attention cores
+# see this many leading positions there (every parameter's shape is the same)
+INIT_POSITIONS = CHUNK
+
+
+def _seen(mdl, x):
+    """``x`` (b, n, dim) as a layer's core sees it: whole, or its leading
+    ``INIT_POSITIONS`` while the module initialises."""
+    return x[:, :INIT_POSITIONS] if mdl.is_initializing() else x
+
+
+def _to_length(y, n: int):
+    """Zeros behind what ``_seen`` kept, back to ``n`` positions."""
+    return jnp.pad(y, ((0, 0), (0, n - y.shape[1]), (0, 0)))
 
 
 class RMSNorm(nn.Module):
@@ -68,13 +91,19 @@ class SwiGLUFeedForward(nn.Module):
 
 class MLAttention(nn.Module):
     """Multi-head latent attention over the held heads. Queries come through
-    a ``q_lora_rank`` latent; keys and values through a ``kv_lora_rank``
-    latent that is normed and expanded per head, plus one rotary key part
-    (``qk_rope_head_dim``) computed once and shared by all heads. A head's
-    query and key are ``qk_nope_head_dim + qk_rope_head_dim`` wide, its value
-    ``v_head_dim``: ``attend`` takes the two widths as they come. Always the
-    dense tier: the fused and flash kernels assume one head width, so
-    ``Transformer.setup`` builds this layer without asking for a tier."""
+    a ``q_lora_rank`` latent (0: one direct projection, ``q``); keys and
+    values through a ``kv_lora_rank`` latent that is normed and expanded per
+    head, plus one rotary key part (``qk_rope_head_dim``) computed once and
+    shared by all heads. A head's query and key are ``qk_nope_head_dim +
+    qk_rope_head_dim`` wide, its value ``v_head_dim``: ``attend`` and the
+    flash kernels take the two widths as they come. ``qk_norm``: a learned
+    RMSNorm of a head's whole width on every query and key, ahead of the
+    rotation (``q_head_norm``, ``k_head_norm``). ``gate`` ``head_wise``:
+    each head's output times a sigmoid of its own projection of the layer's
+    input, one number a head. ``tier`` is what
+    ``ops.attention.attention_tier`` chose for the stack's softmax layers:
+    ``flash`` is the flash kernels, anything else dense (the fused kernel
+    takes one merged qkv of one head width)."""
     dim: int
     heads_held: int
     heads_total: int
@@ -86,40 +115,62 @@ class MLAttention(nn.Module):
     softmax_scale: float
     eps: float = 1e-6
     softmax_f32: bool = True
+    qk_norm: bool = False
+    gate: str = "none"             # none | head_wise
+    tier: str = "dense"
 
     def setup(self):
         h = self.heads_held
         if not 0 < h <= self.heads_total:
             raise ValueError(f"heads_held {h} of {self.heads_total} heads")
-        self.q_a = _dense(self.q_lora_rank, "q_a")
-        self.q_norm = RMSNorm(self.eps, name="q_norm")
-        self.q_b = _dense(h * (self.qk_nope_head_dim + self.qk_rope_head_dim),
-                          "q_b")
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        if self.q_lora_rank:
+            self.q_a = _dense(self.q_lora_rank, "q_a")
+            self.q_norm = RMSNorm(self.eps, name="q_norm")
+            self.q_b = _dense(h * qk, "q_b")
+        else:
+            self.q = _dense(h * qk, "q")
         self.kv_a = _dense(self.kv_lora_rank + self.qk_rope_head_dim, "kv_a")
         self.kv_norm = RMSNorm(self.eps, name="kv_norm")
         self.kv_b = _dense(h * (self.qk_nope_head_dim + self.v_head_dim),
                            "kv_b")
+        if self.qk_norm:
+            self.q_head_norm = RMSNorm(self.eps, name="q_head_norm")
+            self.k_head_norm = RMSNorm(self.eps, name="k_head_norm")
+        if self.gate == "head_wise":
+            self.w_gate = _dense(h, "gate")
         # the held heads' rows of the whole projection
         self.o = _dense(self.dim, "o")
+
+    def _rotated(self, rot, t):
+        """``t`` (b, h, n, qk) with its last ``qk_rope_head_dim`` turned by
+        the angles ``rot`` (n, qk_rope_head_dim)."""
+        dn = self.qk_nope_head_dim
+        return jnp.concatenate(
+            [t[..., :dn], apply_rotary(rot[None, None], t[..., dn:])],
+            axis=-1)
 
     def __call__(self, x, *, key_mask=None, rotary=None, np_mask=None,
                  mask_spec=None, deterministic: bool = True):
         if np_mask is not None:
             raise ValueError("mla runs full causal attention, no static mask")
+        length = x.shape[1]
+        x = _seen(self, x)       # init needs shapes, not every head's scores
         b, n, _ = x.shape
         h, dn, dv = self.heads_held, self.qk_nope_head_dim, self.v_head_dim
         rot = rotary[:n]
         with jax.named_scope("attn/mla_q"):
-            q = self.q_b(self.q_norm(self.q_a(x)))
+            q = (self.q_b(self.q_norm(self.q_a(x))) if self.q_lora_rank
+                 else self.q(x))
             q = q.reshape(b, n, h, -1).transpose(0, 2, 1, 3)
-            q = jnp.concatenate(
-                [q[..., :dn],
-                 apply_rotary(rot[None, None], q[..., dn:])],
-                axis=-1)
+            if not self.qk_norm:
+                q = self._rotated(rot, q)
         with jax.named_scope("attn/mla_kv"):
             kv = self.kv_a(x)
-            k_rope = apply_rotary(rot[None],
-                                  kv[..., self.kv_lora_rank:])  # (b, n, dr)
+            # (b, n, dr): turned here, or with the whole key behind its norm
+            k_rope = (kv[..., self.kv_lora_rank:] if self.qk_norm
+                      else apply_rotary(rot[None],
+                                        kv[..., self.kv_lora_rank:]))
             kv = self.kv_b(self.kv_norm(kv[..., :self.kv_lora_rank]))
             kv = kv.reshape(b, n, h, dn + dv).transpose(0, 2, 1, 3)
             k = jnp.concatenate(
@@ -127,27 +178,58 @@ class MLAttention(nn.Module):
                  jnp.broadcast_to(k_rope[:, None], (b, h) + k_rope.shape[1:])],
                 axis=-1)
             v = kv[..., dn:]
+        if self.qk_norm:
+            with jax.named_scope("attn/mla_norm"):
+                q = self._rotated(rot, self.q_head_norm(q))
+                k = self._rotated(rot, self.k_head_norm(k))
         with jax.named_scope("attn_core"):
-            out = attend(q, k, v, causal=True, key_mask=key_mask,
-                         softmax_f32=self.softmax_f32,
-                         scale=self.softmax_scale)
+            if (self.tier == "flash" and key_mask is None
+                    and not self.is_initializing()):
+                from ..ops.flash_attention import flash_attention
+                out = flash_attention(q, k, v, causal=True,
+                                      scale=self.softmax_scale)
+            else:
+                out = attend(q, k, v, causal=True, key_mask=key_mask,
+                             softmax_f32=self.softmax_f32,
+                             scale=self.softmax_scale)
+        if self.gate == "head_wise":
+            with jax.named_scope("attn/gate"):
+                gate = jax.nn.sigmoid(self.w_gate(x))           # (b, n, h)
+                out = out * gate.transpose(0, 2, 1)[..., None]
         with jax.named_scope("attn/out"):
-            out = out.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
-            return self.o(out)
+            out = self.o(out.transpose(0, 2, 1, 3).reshape(b, n, h * dv))
+            return _to_length(out, length) if n < length else out
 
 
-def group_limited_top_k(scores, n_group: int, topk_group: int, top_k: int):
-    """DeepSeek-V2's ``group_limited_greedy``: the experts are ``n_group``
-    groups in index order; a group's score is its best expert's; only the
-    ``topk_group`` best groups stay eligible, and the ``top_k`` best of
-    their experts are taken. Returns (weights, indices), the weights being
-    the scores themselves (nothing is renormalised)."""
+def group_limited_top_k(scores, n_group: int, topk_group: int, top_k: int,
+                        *, group_score: str = "best", bias=None):
+    """Group-limited routing: the experts are ``n_group`` groups in index
+    order; only the ``topk_group`` best groups stay eligible, and the
+    ``top_k`` best of their experts are taken. Returns (weights, indices),
+    the weights being the scores themselves (nothing is renormalised).
+
+    ``group_score`` is a group's score: ``best``, its best expert's
+    (DeepSeek-V2's ``group_limited_greedy``), or ``best2``, the sum of its
+    two best (DeepSeek-V3's ``noaux_tc``). A ``bias`` (one number an expert)
+    is added to the scores that select, groups and experts, and never to
+    the weights, which stay the chosen experts' own scores."""
     t, e = scores.shape
-    best = scores.reshape(t, n_group, e // n_group).max(-1)
+    select = scores if bias is None else scores + bias
+    grouped = select.reshape(t, n_group, e // n_group)
+    if group_score == "best":
+        best = grouped.max(-1)
+    elif group_score == "best2":
+        best = jax.lax.top_k(grouped, 2)[0].sum(-1)
+    else:
+        raise ValueError(f"group_score {group_score!r}: best | best2")
     _, groups = jax.lax.top_k(best, topk_group)
     allowed = jnp.any(jax.nn.one_hot(groups, n_group, dtype=bool), axis=-2)
     allowed = jnp.repeat(allowed, e // n_group, axis=-1)
-    return jax.lax.top_k(jnp.where(allowed, scores, 0.0), top_k)
+    if bias is None:
+        return jax.lax.top_k(jnp.where(allowed, scores, 0.0), top_k)
+    # a biased score may be negative: outside the kept groups nothing is
+    _, idx = jax.lax.top_k(jnp.where(allowed, select, -jnp.inf), top_k)
+    return jnp.take_along_axis(scores, idx, axis=-1), idx
 
 
 # rows of the sorted-by-expert buffer over what uniform routing sends to the
@@ -193,6 +275,9 @@ class MoEFeedForward(nn.Module):
     first_expert: int = 0
     scoring: str = "softmax"       # softmax | sigmoid
     norm_topk: bool = False        # a token's weights renormalised to sum 1
+    # group_limited_greedy | noaux_tc (a bias that selects, groups by their
+    # two best: ``group_limited_top_k``)
+    topk_method: str = "group_limited_greedy"
 
     def setup(self):
         e, d, f = self.experts_held, self.dim, self.inner
@@ -204,6 +289,12 @@ class MoEFeedForward(nn.Module):
             1.0, "fan_in", "normal", in_axis=1, out_axis=2, batch_axis=0)
         self.router = self.param("router", nn.initializers.lecun_normal(),
                                  (d, self.n_routed_experts))
+        # no gradient reaches the bias (``route``); how a training run moves
+        # it towards balance is that run's business, not the layer's
+        self.router_bias = (
+            self.param("router_bias", nn.initializers.zeros,
+                       (self.n_routed_experts,))
+            if self.topk_method == "noaux_tc" else None)
         self.e_gate = self.param("e_gate", init, (e, d, f))
         self.e_up = self.param("e_up", init, (e, d, f))
         self.e_down = self.param("e_down", init, (e, f, d))
@@ -215,15 +306,20 @@ class MoEFeedForward(nn.Module):
         router's product and scores (a softmax over the experts, or a
         sigmoid each) run in float32, as the source's gate does, whatever
         the compute type. With ``norm_topk`` a token's weights are divided
-        by their sum over its ``top_k`` choices, held here or not."""
+        by their sum over its ``top_k`` choices, held here or not. Under
+        ``noaux_tc`` the bias helps choose and the weights are the unbiased
+        scores of the chosen."""
         logits = jnp.einsum(
             "td,de->te", rows.astype(jnp.float32),
             self.router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
         scores = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
                   else jax.nn.softmax(logits, axis=-1))
-        weights, idx = group_limited_top_k(scores, self.n_group,
-                                           self.topk_group, self.top_k)
+        bias = (None if self.router_bias is None else jax.lax.stop_gradient(
+            self.router_bias.astype(jnp.float32)))
+        weights, idx = group_limited_top_k(
+            scores, self.n_group, self.topk_group, self.top_k,
+            group_score="best" if bias is None else "best2", bias=bias)
         if self.norm_topk:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         return weights * self.routed_scale, idx

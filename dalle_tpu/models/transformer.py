@@ -50,6 +50,15 @@ from .latent_moe import (MLAttention, MoEFeedForward, RMSNorm,
 _WORST_LAYER = {"moe_load_max_over_mean": jnp.max, "kda_logdecay_min": jnp.min}
 
 
+def reduce_counters(counted) -> dict:
+    """The counters of several layers (or stacks) as one dict: summed, but
+    for those of ``_WORST_LAYER``, which keep the worst."""
+    names = dict.fromkeys(k for layer in counted for k in layer)
+    return {k: _WORST_LAYER.get(k, jnp.sum)(
+        jnp.stack([layer[k] for layer in counted if k in layer]))
+        for k in names}
+
+
 def _block_body(mdl, x, key_mask, ind: int, deterministic: bool):
     """One attn+ff residual pair — module-first so ``nn.remat`` can lift it
     (flax replays dropout rngs inside the recompute automatically, replacing
@@ -123,7 +132,8 @@ def stack_layers(c: TransformerConfig) -> dict:
     """What the stack is built from, from the configuration alone: every
     layer's attention kind, the tier chosen for the softmax layers, and per
     kind its heads (``kv_heads`` for grouped keys and values; ``chunk`` and
-    ``chunks`` a sequence for the chunked recurrence). Under ``fused``, where
+    ``chunks`` a sequence for the chunked recurrence; ``qk_dim`` and
+    ``v_dim`` for latent attention's two head widths). Under ``fused``, where
     that is the tier: how many score blocks of the square the kernel forms
     (``ops.fused_attention.block_plan`` of each distinct table of the stack,
     summed) and the products of its backward."""
@@ -131,7 +141,10 @@ def stack_layers(c: TransformerConfig) -> dict:
     kinds = list(islice(cycle(blk.attention_kinds), c.depth))
     out = {"kinds": kinds, "tier": (
         attention_tier(c.use_pallas, c.seq_len, c.heads, c.dim_head)
-        if {"mha", "gqa_gated"} & set(kinds) else "dense")}
+        if {"mha", "gqa_gated", "mla"} & set(kinds) else "dense")}
+    if out["tier"] == "fused" and "mha" not in kinds:
+        out["tier"] = "dense"    # the fused kernel takes mha's merged qkv
+
     for kind in dict.fromkeys(kinds):
         if kind == "kda":
             out[kind] = {"heads": blk.linear_num_heads,
@@ -140,6 +153,11 @@ def stack_layers(c: TransformerConfig) -> dict:
         elif kind == "gqa_gated":
             out[kind] = {"heads": c.heads, "head_dim": c.dim_head,
                          "kv_heads": blk.num_key_value_heads or c.heads}
+        elif kind == "mla":
+            out[kind] = {"heads": c.heads_held or c.heads,
+                         "qk_dim": (blk.qk_nope_head_dim
+                                    + blk.qk_rope_head_dim),
+                         "v_dim": blk.v_head_dim}
         else:
             out[kind] = {"heads": c.heads_held or c.heads,
                          "head_dim": c.dim_head}
@@ -556,10 +574,9 @@ class Transformer(nn.Module):
         fmap = c.image_fmap_size
         self.text_len = _text_len(c)
         blk = c.block
-        # chosen once, from the configured length, for the softmax layers of
-        # one head width: the model keeps its tier at every runtime length.
-        # Latent attention has no kernel path (MLAttention: two head widths)
-        # and linear attention no scores: they are built without asking.
+        # chosen once, from the configured length, for the softmax layers:
+        # the model keeps its tier at every runtime length. Linear attention
+        # has no scores and is built without asking.
         layers = stack_layers(c)
         self.attn_kinds, tier = layers["kinds"], layers["tier"]
 
@@ -631,7 +648,11 @@ class Transformer(nn.Module):
     def _make_attention(self, name: str, tier: str, kind: str):
         c, blk = self.cfg, self.cfg.block
         if kind in ("gqa_gated", "kda"):
-            if blk.positions != "none" or not c.causal or self.sp_mesh:
+            # a linear layer beside latent ones takes no notice of their
+            # rotary table
+            positions = {"none", "seq_yarn"} if (
+                kind == "kda" and "mla" in blk.attention_kinds) else {"none"}
+            if blk.positions not in positions or not c.causal or self.sp_mesh:
                 raise ValueError(f"{kind} is causal, takes no positional "
                                  f"term (positions: none) and has no "
                                  f"sequence-parallel path")
@@ -640,7 +661,8 @@ class Transformer(nn.Module):
                     c.dim, blk.linear_num_heads, blk.linear_head_dim,
                     conv_size=blk.short_conv_kernel_size,
                     gate_rank=blk.linear_gate_rank, eps=blk.rms_norm_eps,
-                    name=name)
+                    lower_bound=blk.kda_lower_bound,
+                    beta_max=blk.kda_beta_max, name=name)
             return GatedGQAttention(
                 c.dim, c.heads, blk.num_key_value_heads or c.heads,
                 c.dim_head, tier=tier, softmax_f32=c.attn_softmax_f32,
@@ -665,7 +687,8 @@ class Transformer(nn.Module):
             softmax_scale=(blk.qk_nope_head_dim
                            + blk.qk_rope_head_dim) ** -0.5 * m * m,
             eps=blk.rms_norm_eps,
-            softmax_f32=c.attn_softmax_f32, name=name)
+            softmax_f32=c.attn_softmax_f32, qk_norm=blk.qk_norm,
+            gate=blk.attention_gate, tier=tier, name=name)
 
     def _make_feed_forward(self, name: str, ind: int):
         c, blk = self.cfg, self.cfg.block
@@ -680,7 +703,8 @@ class Transformer(nn.Module):
             topk_group=blk.topk_group, top_k=blk.num_experts_per_tok,
             routed_scale=blk.routed_scaling_factor,
             n_shared=blk.n_shared_experts, scoring=blk.scoring_func,
-            norm_topk=blk.norm_topk_prob, name=name)
+            norm_topk=blk.norm_topk_prob, topk_method=blk.topk_method,
+            name=name)
 
     def _refuse_cached(self, what: str):
         """The cached paths are written for multi-head keys and values in a
@@ -734,11 +758,7 @@ class Transformer(nn.Module):
                 counted.append(counters)
         if not return_aux:
             return x
-        names = dict.fromkeys(k for layer in counted for k in layer)
-        aux = {k: _WORST_LAYER.get(k, jnp.sum)(
-            jnp.stack([layer[k] for layer in counted if k in layer]))
-            for k in names}
-        return x, aux
+        return x, reduce_counters(counted)
 
     def _call_reversible(self, x, key_mask, deterministic: bool):
         """Unbind each layer into (pure fn, params) pairs and run the

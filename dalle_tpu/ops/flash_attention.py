@@ -143,8 +143,8 @@ def _fwd_kernel(ids_ref, cnt_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, lse_ref = rest
     iq = pl.program_id(2)
-    bq, d = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[0, 0].astype(jnp.float32) * scale                    # (bq, d)
+    bq, d = q_ref.shape[2], v_ref.shape[3]          # d: the value width
+    q = q_ref[0, 0].astype(jnp.float32) * scale                    # (bq, dk)
     qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
 
     def body(t, carry):
@@ -272,7 +272,9 @@ def _bwd_dkv_kernel(ids_ref, cnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         return dk, dv
 
     z = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, cnt_ref[jk], body, (z, z))
+    zv = (z if dv_ref.shape[3] == d
+          else jnp.zeros((bk, dv_ref.shape[3]), jnp.float32))
+    dk, dv = jax.lax.fori_loop(0, cnt_ref[jk], body, (z, zv))
     # q was pre-scaled inside body, so dk = dS^T (scale·Q) is already complete
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
@@ -329,12 +331,15 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
     def pad(t):
         return jnp.pad(t, ((0, 0), (0, 0), (0, n_pad - n), (0, 0)))
 
+    # q and k are ``d`` wide, v and o (and their gradients) ``dv``: latent
+    # attention's keys carry a rotary part its values have not
     def _fwd_call(q, k, v, scale):
         b, h, _, d = q.shape
+        dv = v.shape[-1]
         in_specs = [
             _qblock_spec(d, block_q),
             _full_spec(n_pad, d),
-            _full_spec(n_pad, d),
+            _full_spec(n_pad, dv),
         ]
         operands = [k_ids, k_cnt, q, k, v]
         if has_mask:
@@ -346,7 +351,7 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
             grid=(b, h, nq),
             in_specs=in_specs,
             out_specs=[
-                _qblock_spec(d, block_q),
+                _qblock_spec(dv, block_q),
                 pl.BlockSpec((1, 1, block_q, 128),
                              lambda ib, ih, i, *_: (ib, ih, i, 0)),
             ],
@@ -357,7 +362,7 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
                               elem_fn=elem_fn),
             grid_spec=grid_spec,
             out_shape=[
-                jax.ShapeDtypeStruct((b, h, n_pad, d), q.dtype),
+                jax.ShapeDtypeStruct((b, h, n_pad, dv), q.dtype),
                 jax.ShapeDtypeStruct((b, h, n_pad, 128), jnp.float32),
             ],
             interpret=interpret,
@@ -377,6 +382,7 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
     def flash_bwd(scale, res, g):
         qp, kp, vp, o, lse = res
         b, h, _, d = qp.shape
+        dv = vp.shape[-1]
         gp = pad(g)
         delta = jnp.sum(gp.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1)                                   # (b,h,n_pad)
@@ -386,8 +392,8 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
         dq_in_specs = [
             _qblock_spec(d, block_q),
             _full_spec(n_pad, d),
-            _full_spec(n_pad, d),
-            _qblock_spec(d, block_q),
+            _full_spec(n_pad, dv),
+            _qblock_spec(dv, block_q),
             lse_qspec,
             lse_qspec,
         ]
@@ -412,15 +418,16 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
             name="flash_attn_dq",
         )(*dq_operands)
 
-        kblock_spec = pl.BlockSpec((1, 1, block_k, d),
-                                   lambda ib, ih, j, *_: (ib, ih, j, 0))
+        def kblock_spec(width):
+            return pl.BlockSpec((1, 1, block_k, width),
+                                lambda ib, ih, j, *_: (ib, ih, j, 0))
         lse_fullspec = pl.BlockSpec((1, 1, n_pad, 128),
                                     lambda ib, ih, j, *_: (ib, ih, 0, 0))
         dkv_in_specs = [
             _full_spec(n_pad, d),
-            kblock_spec,
-            kblock_spec,
-            _full_spec(n_pad, d),
+            kblock_spec(d),
+            kblock_spec(dv),
+            _full_spec(n_pad, dv),
             lse_fullspec,
             lse_fullspec,
         ]
@@ -433,8 +440,15 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
             num_scalar_prefetch=2,
             grid=(b, h, nk),
             in_specs=dkv_in_specs,
-            out_specs=[kblock_spec, kblock_spec],
+            out_specs=[kblock_spec(d), kblock_spec(dv)],
         )
+        # whole rows of q, dO, lse and delta stay resident beside a key
+        # block: with keys wider than a lane tile (latent attention's 192)
+        # that passes Mosaic's default scoped-VMEM ceiling of 16M by 0.4M at
+        # 4352 positions. The ceiling is a compiler default, not hardware
+        # (ops/fused_attention.py asks for the same 32M)
+        wide = ({"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=32 * 1024 * 1024)} if d > 128 else {})
         dk, dv = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                               n_valid=n, causal=causal, has_mask=has_mask,
@@ -442,10 +456,11 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
             grid_spec=dkv_grid,
             out_shape=[
                 jax.ShapeDtypeStruct((b, h, n_pad, d), qp.dtype),
-                jax.ShapeDtypeStruct((b, h, n_pad, d), qp.dtype),
+                jax.ShapeDtypeStruct((b, h, n_pad, dv), qp.dtype),
             ],
             interpret=interpret,
             name="flash_attn_dkv",
+            **wide,
         )(*dkv_operands)
         return dq[:, :, :n], dk[:, :, :n], dv[:, :, :n]
 
@@ -484,6 +499,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     scale: Optional[float] = None,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """Flash attention over (b, h, n, d) with optional static (n, n) bool mask.
+    ``q`` and ``k`` share one width and ``v`` may have another (latent
+    attention: 192 against 128); the output is ``v``'s.
 
     Replaces reference dense attention (attention.py:58-99) AND the DeepSpeed
     block-sparse kernel (attention.py:339-398): blocks with no visible entry

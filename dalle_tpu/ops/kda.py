@@ -181,15 +181,22 @@ def _l2_normalise(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
 
 
-def _log_decay(f, a_log, bias):
-    """g = -exp(A_h) softplus(f + b) in float32: ``f`` (..., h, d), ``a_log``
-    (h,), ``bias`` (h, d)."""
+def _log_decay(f, a_log, bias, lower_bound: float = 0.0):
+    """The log-decay in float32 from ``f`` (..., h, d), ``a_log`` (h,) and
+    ``bias`` (h, d): g = -exp(A_h) softplus(f + b), or, with a
+    ``lower_bound`` < 0, the public implementation's bounded gate
+    g = lower_bound * sigmoid(exp(A_h) (f + b)), in (lower_bound, 0): a
+    chunk's cumulative sum is then at least ``CHUNK * lower_bound``."""
     f32 = jnp.float32
+    if lower_bound < 0:
+        return lower_bound * jax.nn.sigmoid(
+            jnp.exp(a_log.astype(f32))[:, None]
+            * (f.astype(f32) + bias.astype(f32)))
     return (-jnp.exp(a_log.astype(f32))[:, None]
             * jax.nn.softplus(f.astype(f32) + bias.astype(f32)))
 
 
-def _within_chunks(q, k, v, f, beta, a_log, bias):
+def _within_chunks(q, k, v, f, beta, a_log, bias, lower_bound):
     """Everything of some chunks that does not depend on the state: what the
     scan over the chunks' states reads, and the chunks' most negative
     cumulative log-decay. Inputs (chunks, b, h, CHUNK, ...)."""
@@ -201,8 +208,8 @@ def _within_chunks(q, k, v, f, beta, a_log, bias):
     q = _l2_normalise(q) * q.shape[-1] ** -0.5
     k, beta = _l2_normalise(k), beta.astype(f32)[..., None]
     # (CHUNK, d) behind the heads for the head's own A and bias
-    g = jnp.swapaxes(_log_decay(jnp.swapaxes(f, -3, -2), a_log, bias),
-                     -3, -2)
+    g = jnp.swapaxes(_log_decay(jnp.swapaxes(f, -3, -2), a_log, bias,
+                                lower_bound), -3, -2)
     cum = jnp.cumsum(g, axis=-2)
     last = cum[..., -1:, :]
     diag, lows = _decayed_grams(q, k, cum, dtype)
@@ -222,13 +229,15 @@ def _within_chunks(q, k, v, f, beta, a_log, bias):
          jnp.exp(last[..., 0, :])), jnp.min(last)))
 
 
-def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps):
+def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps,
+                lower_bound: float = 0.0):
     """The layer's heads over whole sequences, from the projections' outputs
     to the normalised read-out. ``q``, ``k``: (b, n, h, d_k), not yet
     normalised (here each is divided by its norm, ``q`` times d_k^-1/2);
     ``v``: (b, n, h, d_v); ``f``: (b, n, h, d_k), the decay's pre-activation
-    (the log-decay is ``_log_decay(f, a_log, bias)``, ``a_log`` (h,), ``bias``
-    (h, d_k)); ``beta``: (b, n, h). Returns ``o`` (b, n, h, d_v) in ``v``'s
+    (the log-decay is ``_log_decay(f, a_log, bias, lower_bound)``, ``a_log``
+    (h,), ``bias`` (h, d_k), ``lower_bound`` the decay's form); ``beta``:
+    (b, n, h). Returns ``o`` (b, n, h, d_v) in ``v``'s
     type, RMS-normalised over a head's width in float32 (``norm_scale``
     (d_v,), ``eps``), and the most negative cumulative log-decay over a
     chunk (a float32 scalar: how far the chunked form is from float32's
@@ -264,8 +273,10 @@ def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps):
 
     with jax.named_scope("attn/kda_chunk"):
         xs, lows = jax.lax.map(
-            jax.checkpoint(lambda x: _within_chunks(*x, a_log, bias)),
-            # behind the end no gate: softplus of the least number is 0
+            jax.checkpoint(lambda x: _within_chunks(*x, a_log, bias,
+                                                    lower_bound)),
+            # behind the end no gate: softplus (or the bounded form's
+            # sigmoid) of the least number is 0
             (chunked(q), chunked(k), chunked(v),
              chunked(f, jnp.finfo(f.dtype).min), chunked(beta)))
         xs = jax.tree.map(lambda x: x.reshape((nc,) + x.shape[2:]), xs)

@@ -77,10 +77,16 @@ def test_a_dense_cells_stack_on_the_tpu(monkeypatch, name, tier):
 
 def test_the_latent_cells_stack_is_built_without_a_tier(monkeypatch):
     """``deepseek_v2_share16`` at its own widths (depth cut to 1, shapes
-    only): the chooser is never asked, the layers are ``MLAttention``."""
-    def asked(*args, **kw):
-        raise AssertionError("the chooser was asked about latent attention")
-    monkeypatch.setattr("dalle_tpu.models.transformer.attention_tier", asked)
+    only), built for the TPU: latent attention asks like every softmax
+    layer (PR 34), and whatever the chooser answers at 1280 positions, under
+    ``FLASH_MIN_SEQ``, the layer is the dense tier it was."""
+    from dalle_tpu.models import transformer
+    chooser, answers = transformer.attention_tier, []
+
+    def on_tpu(*args, **kw):
+        answers.append(chooser(*args, backend="tpu"))
+        return answers[-1]
+    monkeypatch.setattr("dalle_tpu.models.transformer.attention_tier", on_tpu)
     cfg = cell_config("deepseek_v2_share16", depth=1)
     stack = Transformer(cfg.transformer())
     x = jax.ShapeDtypeStruct((1, cfg.total_seq_len, cfg.dim), jnp.float32)
@@ -88,4 +94,6 @@ def test_the_latent_cells_stack_is_built_without_a_tier(monkeypatch):
     assert {"q_a", "q_b", "kv_a", "kv_b"} <= set(shapes["attn_0"])
     bound = stack.bind({})
     assert [type(layer.fn) for layer in bound.attn_layers] == [MLAttention]
-    assert not hasattr(MLAttention, "tier")
+    assert answers and "flash" not in answers
+    assert [layer.fn.tier for layer in bound.attn_layers] == ["dense"]
+    assert transformer.stack_layers(cfg.transformer())["tier"] == "dense"

@@ -159,6 +159,21 @@ def test_flash_fwd_bwd_solar2_softmax_layer(one_chip):
         assert kernel in text, kernel
 
 
+def test_flash_fwd_bwd_ling3_latent_layer(one_chip):
+    """train_ling3_ep32_fit's latent layers as ``MLAttention`` calls the
+    flash tier: batch 2, 32 heads, queries and keys of 192 (128 + the rotary
+    64), values of 128, 4352 positions and the multi-token-prediction
+    block's 4351 (padded to 17 blocks of 256 alike). 192 is no multiple of
+    the 128 lanes: Mosaic takes it as the array's whole last dimension; the
+    ``dkv`` kernel asks for the raised scoped-VMEM ceiling."""
+    for n in (4352, 4351):
+        q = _sds(one_chip, (2, 32, n, 192), jnp.bfloat16)
+        v = _sds(one_chip, (2, 32, n, 128), jnp.bfloat16)
+        text = _mosaic_text(_flash_loss(None), q, q, v)
+        for kernel in ("flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"):
+            assert kernel in text, kernel
+
+
 @pytest.mark.parametrize("masked", [False, True],
                          ids=["mask_free", "axial_masked"])
 def test_flash_fwd_bwd_512(one_chip, masked):

@@ -270,3 +270,39 @@ def test_block_aligned_spec_matches_table(n, B):
     for a, b in zip(gt, gs):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("n", [128, 96, 150],
+                         ids=["whole_blocks", "one_short_block", "ragged"])
+@pytest.mark.parametrize("dk,dv", [(24, 16), (8, 16)],
+                         ids=["keys_wider", "values_wider"])
+def test_two_head_widths_match_dense(n, dk, dv):
+    """Latent attention's queries and keys are wider than its values (192
+    against 128; 24 against 16 here): the one family of kernels takes the
+    two widths as they come, forward and the three gradients, against
+    ``attend``, at a length that is a block multiple and at two that are
+    not, under a scale that is not the default's."""
+    ks = jax.random.split(jax.random.PRNGKey(n + dk), 3)
+    q, k = (jax.random.normal(key, (B, H, n, dk)) for key in ks[:2])
+    v = jax.random.normal(ks[2], (B, H, n, dv))
+    scale = 0.7 * dk ** -0.5
+
+    def dense(q, k, v):
+        return attend(q, k, v, causal=True, scale=scale)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               block_q=32, block_k=32)
+    out = flash(q, k, v)
+    assert out.shape == (B, H, n, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    w = jax.random.normal(jax.random.PRNGKey(7), out.shape)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), err_msg=name,
+                                   rtol=3e-5, atol=3e-5)

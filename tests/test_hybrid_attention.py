@@ -18,7 +18,7 @@ from benchmarks.kinds import train
 from benchmarks.reference import solar_open2 as ref
 from dalle_tpu.config import (BlockConfig, DalleConfig, OptimConfig,
                               PrecisionConfig, TrainConfig)
-from dalle_tpu.models import hybrid_attention
+from dalle_tpu.models import latent_moe
 from dalle_tpu.models.dalle import DALLE, init_dalle
 from dalle_tpu.models.hybrid_attention import (GatedGQAttention,
                                                KimiDeltaAttention)
@@ -303,7 +303,7 @@ def test_init_sees_a_prefix_and_builds_every_leaf_at_its_size(monkeypatch):
     """Un-jitted init runs the cores on the first positions only: the
     parameter tree is that of a full-length trace."""
     cfg = DalleConfig(**{**MODEL, "depth": 4})
-    monkeypatch.setattr(hybrid_attention, "INIT_POSITIONS", 16)
+    monkeypatch.setattr(latent_moe, "INIT_POSITIONS", 16)
     _, short = init_dalle(cfg, jax.random.PRNGKey(0))
     model = DALLE(cfg)
     text, ids = a_batch(1)

@@ -447,21 +447,30 @@ def test_the_cached_paths_and_the_engine_refuse_the_block_by_name():
 
 
 def test_latent_attention_is_the_dense_tier_whatever_the_length(monkeypatch):
-    """A latent-attention stack is built without asking for a tier: at any
-    configured length every attention layer is an ``MLAttention``, which
-    has the dense ``attend`` and nothing else."""
+    """A latent-attention stack asks for its tier like every softmax layer
+    (PR 34): ``flash`` from ``FLASH_MIN_SEQ`` up, where the flash kernels
+    take its two head widths, and the dense ``attend`` at every shorter
+    length whatever the chooser answers there (the fused kernel takes mha's
+    merged qkv). Off the TPU it is dense at any length, as before."""
+    from dalle_tpu.models import transformer
     from dalle_tpu.models.transformer import Transformer
+    chooser = transformer.attention_tier
 
-    def asked(*a, **kw):
-        raise AssertionError("the chooser was asked about latent attention")
-    monkeypatch.setattr("dalle_tpu.models.transformer.attention_tier", asked)
-    for fmap in (4, 32, 64):        # sequences of 24, 1032 and 4104
+    def built(fmap):
         cfg = DalleConfig(**{**MODEL, "image_fmap_size": fmap,
                              "image_size": 8 * fmap})
         stack = Transformer(cfg.transformer()).bind({})
         assert len(stack.attn_layers) == cfg.depth
         assert all(type(layer.fn) is MLAttention
                    for layer in stack.attn_layers)
+        return {layer.fn.tier for layer in stack.attn_layers}
+    for fmap in (4, 32, 64):        # sequences of 24, 1032 and 4104
+        assert built(fmap) == {"dense"}
+    monkeypatch.setattr(
+        "dalle_tpu.models.transformer.attention_tier",
+        lambda *a, **kw: chooser(*a, backend="tpu"))
+    assert [built(fmap) for fmap in (4, 32, 64)] == [
+        {"dense"}, {"dense"}, {"flash"}]
 
 
 def test_a_routed_block_is_refused_on_a_mesh_of_several_devices():
