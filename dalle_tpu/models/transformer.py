@@ -136,7 +136,10 @@ def stack_layers(c: TransformerConfig) -> dict:
     ``v_dim`` for latent attention's two head widths). Under ``fused``, where
     that is the tier: how many score blocks of the square the kernel forms
     (``ops.fused_attention.block_plan`` of each distinct table of the stack,
-    summed) and the products of its backward."""
+    summed) and the products of its backward. Under ``flash``, where that is
+    the tier: per softmax layer the score blocks its kernels walk
+    (``ops.flash_attention.flash_block_counts``: ``visited``, of them
+    ``full`` that no mask cuts, of ``total`` in the square)."""
     blk = c.block
     kinds = list(islice(cycle(blk.attention_kinds), c.depth))
     out = {"kinds": kinds, "tier": (
@@ -169,6 +172,19 @@ def stack_layers(c: TransformerConfig) -> dict:
         out["fused"] = {"score_blocks": [sum(p.computed for p in plans),
                                          sum(p.of for p in plans)],
                         "products_bwd": 5}
+    if out["tier"] == "flash":
+        from ..ops.flash_attention import flash_block_counts
+        keys, masks, specs = layer_masks(c)
+        # only mha hands the kernels its static mask; the other softmax
+        # kinds are plain causal. One count per distinct table
+        tables = {i: keys[i] if kind == "mha" else None
+                  for i, kind in enumerate(kinds)
+                  if kind in ("mha", "gqa_gated", "mla")}
+        counts = {key: flash_block_counts(
+            c.seq_len, causal=c.causal, mask=masks.get(key),
+            mask_spec=specs.get(key)) for key in set(tables.values())}
+        out["flash"] = [{"layer": i, **counts[key]}
+                        for i, key in tables.items()]
     return out
 
 

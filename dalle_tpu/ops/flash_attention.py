@@ -8,16 +8,44 @@ the framework represents as static masks (ops/attn_masks.py).
 
 Design (one kernel family, sparsity by block skipping):
   * Tiled online-softmax flash attention: q blocks stream against k/v blocks,
-    accumulating (acc, running max, running sum) — O(n) memory, MXU-shaped
-    (block_q × d) @ (d × block_k) matmuls in fp32 accumulation.
+    accumulating (acc, running max, running sum) — O(n) memory.
+  * Products in the inputs' own type: q, k, v and dO go to the MXU as they
+    arrive (bfloat16 in the training cells, float32 in the CPU tests), p and
+    dS are rounded to that type once, just before their products, and every
+    product sums in float32 (`_dot`); running maximum and sum, lse, delta and
+    the accumulators stay float32. The softmax scale multiplies the float32
+    scores, never a bfloat16 q. No flag: the type is read from the input.
+    (Measured on the v5e, PR 35: Mosaic already multiplied float32 operands
+    in one bfloat16 pass, so this alone moved nothing; it halves the vregs
+    the operands hold.)
   * Any static (seq, seq) boolean mask is lowered host-side to *block lists*:
     for each q block, the list of k blocks with any visible entry (and the
     transpose for the backward dk/dv kernel). The lists ride scalar prefetch
     (SMEM, `PrefetchScalarGridSpec`) and the kernel loops only over listed
     blocks — inactive blocks are never touched, which is exactly the DeepSpeed
     variable-sparsity skip, retiled to the 128-lane TPU geometry.
-  * Element-level masking inside a visited block is recomputed from the mask
-    constant + causal iota compare, fused into the softmax epilogue by Mosaic.
+  * Element-level masking inside a visited block is recomputed from iotas,
+    the mask table or the structured `elem_fn`, in EVERY visited block. A
+    second loop body without it for the wholly visible blocks (136 of the 153
+    visited for a causal 4352 at 256; `flash_block_counts` counts them) was
+    measured on the v5e at PR 35 and left out: the three kernels together read
+    1.1 % slower with it (dq and dk/dv 1-4 % slower for the longer program,
+    the forward 1-5 % faster): the mask's selects hide behind the products
+    and the exponentials.
+  * Orientation — what did move the kernels (PR 35, v5e readings of the
+    three alone with the next entry's overlap: 42.3 -> 34.5 ms at 2 x 64
+    heads of 128, 22.1 -> 19.1 at 2 x 32 of 192 / 128): the forward and the
+    dk/dv kernel hold their scores TRANSPOSED, keys down the sublanes and
+    queries along the lanes. The forward's running maximum and sum are then
+    (1, bq) rows, reduced down the sublanes on the VALU (as (bq, 1) columns
+    they were 32 vregs each and 542 XLU slots a block); the dk/dv kernel's
+    two sums over queries are plain products (no transpose of p and dS) and
+    lse, delta are rows. The dq kernel keeps queries down the sublanes and
+    reads lse and delta as the lane-replicated tiles they arrive as.
+  * MXU overlap in the forward: it forms the NEXT block's scores while the
+    vector units work on this block's softmax, so both products keep the MXU
+    busy together; the last listed block, which has no next one, is taken out
+    of the loop (tried in dq and dk/dv too: slower there).
   * Backward is the standard two-kernel flash backward (dq by q-block rows,
     dk/dv by k-block columns) over the same block lists, wrapped in
     `jax.custom_vjp`; the forward saves only (o, lse).
@@ -52,12 +80,13 @@ class BlockLists(NamedTuple):
     q_cnt: np.ndarray    # (nk,)
 
 
-def build_block_lists(n_pad: int, block_q: int, block_k: int,
-                      mask: Optional[np.ndarray] = None,
-                      causal: bool = True) -> BlockLists:
-    """Lower a (seq, seq) boolean mask (True = may attend) to block lists.
-    ``mask`` may be smaller than n_pad — padded rows/cols count as invisible."""
-    nq, nk = n_pad // block_q, n_pad // block_k
+def _visible_tiles(n_pad: int, block_q: int, block_k: int,
+                   mask: Optional[np.ndarray], causal: bool,
+                   n_valid: Optional[int]) -> np.ndarray:
+    """The (n_pad, n_pad) visibility table as (nq, block_q, nk, block_k)
+    tiles. ``mask`` may be smaller than n_pad — padded rows/cols count as
+    invisible, and so do the keys from ``n_valid`` on (the sequence's own
+    length, where it is not a block multiple)."""
     vis = np.zeros((n_pad, n_pad), dtype=bool)
     if mask is not None:
         # the mask may be larger than the runtime sequence (e.g. built for
@@ -67,9 +96,21 @@ def build_block_lists(n_pad: int, block_q: int, block_k: int,
         vis[:s, :s] = mask[:s, :s]
     else:
         vis[:, :] = True
+    if n_valid is not None:
+        vis[:, n_valid:] = False
     if causal:
         vis &= np.tril(np.ones((n_pad, n_pad), dtype=bool))
-    blk = vis.reshape(nq, block_q, nk, block_k).any(axis=(1, 3))
+    return vis.reshape(n_pad // block_q, block_q, n_pad // block_k, block_k)
+
+
+def build_block_lists(n_pad: int, block_q: int, block_k: int,
+                      mask: Optional[np.ndarray] = None,
+                      causal: bool = True,
+                      n_valid: Optional[int] = None) -> BlockLists:
+    """Lower a (seq, seq) boolean mask (True = may attend) to block lists:
+    a block is listed when any entry of it is visible."""
+    blk = _visible_tiles(n_pad, block_q, block_k, mask, causal,
+                         n_valid).any(axis=(1, 3))
 
     def lists(b):
         rows = [np.nonzero(r)[0] for r in b]
@@ -81,9 +122,7 @@ def build_block_lists(n_pad: int, block_q: int, block_k: int,
             cnt[i] = len(r)
         return ids, cnt
 
-    k_ids, k_cnt = lists(blk)
-    q_ids, q_cnt = lists(blk.T)
-    return BlockLists(k_ids, k_cnt, q_ids, q_cnt)
+    return BlockLists(*lists(blk), *lists(blk.T))
 
 
 def elem_fn_from_spec(spec):
@@ -136,147 +175,184 @@ def elem_fn_from_spec(spec):
 # kernels (grid = (b, h, n_blocks); block lists in SMEM via scalar prefetch)
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(ids_ref, cnt_ref, q_ref, k_ref, v_ref, *rest,
-                scale, block_k, n_valid, causal, has_mask, elem_fn=None):
+def _dot(a, b, contract):
+    """A product of operands in their own type with float32 sums."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))      # a bᵀ
+_NN = ((1,), (0,))      # a b
+_TN = ((0,), (0,))      # aᵀ b
+
+
+def _visible(first_q, first_k, shape, q_axis, n_valid, causal, mask_blk,
+             elem_fn):
+    """Entry-wise visibility inside a visited block; the block's queries run
+    along ``q_axis`` from position ``first_q``, its keys along the other axis
+    from ``first_k``."""
+    qpos = first_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kpos = first_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    valid = kpos < n_valid
+    if causal:
+        valid &= kpos <= qpos
+    if mask_blk is not None:
+        valid &= mask_blk > 0
+    elif elem_fn is not None:
+        valid &= elem_fn(qpos, kpos)
+    return valid
+
+
+def _beside(col, width):
+    """A lane-replicated (rows, 128) column as wide as ``width`` scores."""
+    reps, rest = divmod(width, col.shape[1])
+    return col[:, :1] if rest else jnp.tile(col, (1, reps))
+
+
+def _as_row(col):
+    """A lane-replicated (rows, 128) column as a (1, rows) row."""
+    return col.T[:1]
+
+
+def _fwd_kernel(ids_ref, cnt_ref, q_ref, k_ref, v_ref, *rest, scale, block_k,
+                n_valid, causal, has_mask, elem_fn=None):
+    """One query block against its key blocks, the scores held transposed
+    (keys down the sublanes, queries along the lanes): the running maximum
+    and sum are (1, bq) rows and their reductions run down the sublanes."""
     if has_mask:
-        mask_ref, o_ref, lse_ref = rest
+        mask_ref, o_ref, lse_ref = rest      # mask_ref: (n_pad, bq), [k, q]
     else:
         o_ref, lse_ref = rest
     iq = pl.program_id(2)
     bq, d = q_ref.shape[2], v_ref.shape[3]          # d: the value width
-    q = q_ref[0, 0].astype(jnp.float32) * scale                    # (bq, dk)
-    qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+    q = q_ref[0, 0]                                                # (bq, dk)
+    cnt = cnt_ref[iq]
 
-    def body(t, carry):
-        acc, m, l = carry
+    def scores(t):
+        # the scale goes on the float32 scores: on a bfloat16 q it would be
+        # a rounding the float32 path has not
+        rows = pl.ds(ids_ref[iq, t] * block_k, block_k)
+        return _dot(k_ref[0, 0, rows, :], q, _NT) * scale          # (bk, bq)
+
+    def softmax(t, s, acc, m, l):           # (bk, bq), (d, bq), (1, bq) twice
         jb = ids_ref[iq, t]
-        k = k_ref[0, 0, pl.ds(jb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(jb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        kpos = jb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        valid = kpos < n_valid
-        if causal:
-            valid &= kpos <= qpos
-        if has_mask:
-            valid &= mask_ref[:, pl.ds(jb * block_k, block_k)] > 0
-        elif elem_fn is not None:
-            valid &= elem_fn(qpos, kpos)
+        rows = pl.ds(jb * block_k, block_k)
+        v = v_ref[0, 0, rows, :]
+        valid = _visible(iq * bq, jb * block_k, s.shape, 1, n_valid, causal,
+                         mask_ref[rows, :] if has_mask else None, elem_fn)
         s = jnp.where(valid, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # for a fully-masked row m_new == NEG_INF and exp(s - m_new) would be
-        # exp(0) == 1 — force masked entries to 0 so l stays 0 and the
-        # empty-row guard below fires (valid scores never approach NEG_INF/2)
-        p = jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - m_new), 0.0)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        # for a fully-masked row m_new == NEG_INF and exp(s - m_new) is
+        # exp(0) == 1 — zero p on masked entries so l stays 0 and the
+        # empty-row guard below fires
+        p = jnp.where(valid, p, 0.0)
         corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        l = l * corr + jnp.sum(p, axis=0, keepdims=True)
+        acc = acc * corr + _dot(v, p.astype(v.dtype), _TN)         # (d, bq)
         return acc, m_new, l
 
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, cnt_ref[iq], body, (acc0, m0, l0))
+    def body(t, carry):
+        # the next block's scores have no part in this block's softmax: the
+        # MXU forms them while the vector units work
+        s, *stats = carry
+        return (scores(t + 1), *softmax(t, s, *stats))
+
+    # the last listed block has no next one: it is taken out of the loop.
+    # A row of blocks with nothing listed (cnt == 0, a static mask's) walks
+    # block 0 there and drops what it found: an unlisted block is invisible
+    # whatever the element test says, as it is to the backward's loops
+    last = jnp.maximum(cnt - 1, 0)
+    s, *stats = jax.lax.fori_loop(
+        0, last, body,
+        (scores(0), jnp.zeros((d, bq), jnp.float32),
+         jnp.full((1, bq), NEG_INF, jnp.float32),
+         jnp.zeros((1, bq), jnp.float32)))
+    acc, m, l = softmax(last, s, *stats)
+    acc, l = jnp.where(cnt > 0, acc, 0.0), jnp.where(cnt > 0, l, 0.0)
     safe_l = jnp.where(l > 0, l, 1.0)
-    o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc * (1.0 / safe_l)).T.astype(o_ref.dtype)
     # rows with no visible key get a huge lse so backward p == 0; lane-
     # replicated (bq, 128) layout per the TPU tiling rules
     lse = jnp.where(l > 0, m + jnp.log(safe_l), -NEG_INF)
-    lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:]).astype(jnp.float32)
+    lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[:1:-1]).T
 
 
 def _bwd_dq_kernel(ids_ref, cnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, *rest, scale, block_k, n_valid, causal,
                    has_mask, elem_fn=None):
     if has_mask:
-        mask_ref, dq_ref = rest
+        mask_ref, dq_ref = rest              # mask_ref: (n_pad, bq), [k, q]
     else:
         (dq_ref,) = rest
     iq = pl.program_id(2)
     bq, d = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[0, 0].astype(jnp.float32) * scale
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0][:, :1]
-    delta = delta_ref[0, 0][:, :1]
-    qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+    q = q_ref[0, 0]
+    do = do_ref[0, 0]
+    # lse and delta arrive lane-replicated: a (bq, 128) tile beside every
+    # 128 columns of the scores, no broadcast inside the loop
+    lse = _beside(lse_ref[0, 0], block_k)
+    delta = _beside(delta_ref[0, 0], block_k)
 
     def body(t, dq):
         jb = ids_ref[iq, t]
-        k = k_ref[0, 0, pl.ds(jb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(jb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        kpos = jb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        valid = kpos < n_valid
-        if causal:
-            valid &= kpos <= qpos
-        if has_mask:
-            valid &= mask_ref[:, pl.ds(jb * block_k, block_k)] > 0
-        elif elem_fn is not None:
-            valid &= elem_fn(qpos, kpos)
+        cols = pl.ds(jb * block_k, block_k)
+        k = k_ref[0, 0, cols, :]
+        v = v_ref[0, 0, cols, :]
+        s = _dot(q, k, _NT) * scale                                # (bq, bk)
+        # the one kernel with queries down the sublanes: it turns its slice
+        # of the [k, q] table round
+        valid = _visible(iq * bq, jb * block_k, s.shape, 0, n_valid, causal,
+                         mask_ref[cols, :].T if has_mask else None, elem_fn)
         s = jnp.where(valid, s, NEG_INF)
         p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+        ds = p * (_dot(do, v, _NT) - delta)
+        return dq + _dot(ds.astype(k.dtype), k, _NN)
 
-    dq = jax.lax.fori_loop(0, cnt_ref[iq], body, jnp.zeros((bq, d), jnp.float32))
+    dq = jax.lax.fori_loop(0, cnt_ref[iq], body,
+                           jnp.zeros((bq, d), jnp.float32))
     dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(ids_ref, cnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, *rest, scale, block_q, n_valid, causal,
                     has_mask, elem_fn=None):
+    """One key block against its query blocks, the scores held transposed
+    as in the forward: both sums over queries are then plain products, and
+    lse and delta are (1, bq) rows."""
     if has_mask:
-        mask_ref, dk_ref, dv_ref = rest
+        mask_ref, dk_ref, dv_ref = rest      # mask_ref: (bk, n_pad), [k, q]
     else:
         dk_ref, dv_ref = rest
     jk = pl.program_id(2)
     bk, d = dk_ref.shape[2], dk_ref.shape[3]
-    k = k_ref[0, 0].astype(jnp.float32)                            # (bk, d)
-    v = v_ref[0, 0].astype(jnp.float32)
-    kpos = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
+    k = k_ref[0, 0]                                                # (bk, d)
+    v = v_ref[0, 0]
 
     def body(t, carry):
         dk, dv = carry
         ib = ids_ref[jk, t]
-        q = q_ref[0, 0, pl.ds(ib * block_q, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[0, 0, pl.ds(ib * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(ib * block_q, block_q), :][:, :1]
-        delta = delta_ref[0, 0, pl.ds(ib * block_q, block_q), :][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        qpos = ib * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, bk), 0)
-        valid = kpos < n_valid
-        if causal:
-            valid &= kpos <= qpos
-        if has_mask:
-            valid &= mask_ref[pl.ds(ib * block_q, block_q), :] > 0
-        elif elem_fn is not None:
-            valid &= elem_fn(qpos, kpos)
+        rows = pl.ds(ib * block_q, block_q)
+        q = q_ref[0, 0, rows, :]
+        do = do_ref[0, 0, rows, :]
+        lse = _as_row(lse_ref[0, 0, rows, :])
+        delta = _as_row(delta_ref[0, 0, rows, :])
+        s = _dot(k, q, _NT) * scale                                # (bk, bq)
+        valid = _visible(ib * block_q, jk * bk, s.shape, 1, n_valid, causal,
+                         mask_ref[:, rows] if has_mask else None, elem_fn)
         s = jnp.where(valid, s, NEG_INF)
-        p = jnp.exp(s - lse)                                       # (blkq, bk)
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+        p = jnp.exp(s - lse)
+        dv = dv + _dot(p.astype(do.dtype), do, _NN)
+        ds = p * (_dot(v, do, _NT) - delta)
+        dk = dk + _dot(ds.astype(q.dtype), q, _NN)
         return dk, dv
 
-    z = jnp.zeros((bk, d), jnp.float32)
-    zv = (z if dv_ref.shape[3] == d
-          else jnp.zeros((bk, dv_ref.shape[3]), jnp.float32))
-    dk, dv = jax.lax.fori_loop(0, cnt_ref[jk], body, (z, zv))
-    # q was pre-scaled inside body, so dk = dS^T (scale·Q) is already complete
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+    dk, dv = jax.lax.fori_loop(
+        0, cnt_ref[jk], body,
+        (jnp.zeros((bk, d), jnp.float32),
+         jnp.zeros((bk, dv_ref.shape[3]), jnp.float32)))
+    # the scale the scores carry, once on the sum: dk = scale · dSᵀ Q
+    dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
@@ -305,7 +381,7 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
     else:
         buf, shape = mask_key
         mask_np = np.frombuffer(buf, dtype=bool).reshape(shape)
-    lists = build_block_lists(n_pad, block_q, block_k, mask_np, causal)
+    lists = build_block_lists(n_pad, block_q, block_k, mask_np, causal, n)
     # with no element mask (pure causal / padding handled by iota compares)
     # the kernels take no mask operand at all — the (block_q, n_pad) int32
     # mask row was as much VMEM traffic per grid step as the scores
@@ -319,13 +395,15 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
     # n_pad) int32 table pinned in this lru-cached closure is ~85MB at seq 4k.
     # Keep closure constants as NUMPY: jnp conversion inside a jit trace would
     # capture per-trace tracers in the lru-cached closure (leaked-tracer error)
-    mask_c = None
+    # One table, transposed ([k, q]): the forward and dkv kernels hold their
+    # scores that way, and dq turns its slice round in the kernel
+    mask_t = None
     if has_mask:
-        mask_c = np.zeros((n_pad, n_pad), dtype=np.int32)
+        mask_t = np.zeros((n_pad, n_pad), dtype=np.int32)
         s = min(mask_np.shape[0], n_pad)
-        mask_c[:s, :s] = mask_np[:s, :s]
-    k_ids, k_cnt = lists.k_ids, lists.k_cnt
-    q_ids, q_cnt = lists.q_ids, lists.q_cnt
+        mask_t[:s, :s] = mask_np[:s, :s].T
+    by_q = [lists.k_ids, lists.k_cnt]
+    by_k = [lists.q_ids, lists.q_cnt]
     nq, nk = n_pad // block_q, n_pad // block_k
 
     def pad(t):
@@ -341,11 +419,11 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
             _full_spec(n_pad, d),
             _full_spec(n_pad, dv),
         ]
-        operands = [k_ids, k_cnt, q, k, v]
+        operands = [*by_q, q, k, v]
         if has_mask:
             in_specs.append(
-                pl.BlockSpec((block_q, n_pad), lambda ib, ih, i, *_: (i, 0)))
-            operands.append(mask_c)
+                pl.BlockSpec((n_pad, block_q), lambda ib, ih, i, *_: (0, i)))
+            operands.append(mask_t)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, h, nq),
@@ -397,11 +475,11 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
             lse_qspec,
             lse_qspec,
         ]
-        dq_operands = [k_ids, k_cnt, qp, kp, vp, gp, lse, delta]
+        dq_operands = [*by_q, qp, kp, vp, gp, lse, delta]
         if has_mask:
             dq_in_specs.append(
-                pl.BlockSpec((block_q, n_pad), lambda ib, ih, i, *_: (i, 0)))
-            dq_operands.append(mask_c)
+                pl.BlockSpec((n_pad, block_q), lambda ib, ih, i, *_: (0, i)))
+            dq_operands.append(mask_t)
         dq_grid = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, h, nq),
@@ -431,11 +509,11 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
             lse_fullspec,
             lse_fullspec,
         ]
-        dkv_operands = [q_ids, q_cnt, qp, kp, vp, gp, lse, delta]
+        dkv_operands = [*by_k, qp, kp, vp, gp, lse, delta]
         if has_mask:
             dkv_in_specs.append(
-                pl.BlockSpec((n_pad, block_k), lambda ib, ih, j, *_: (0, j)))
-            dkv_operands.append(mask_c)
+                pl.BlockSpec((block_k, n_pad), lambda ib, ih, j, *_: (j, 0)))
+            dkv_operands.append(mask_t)
         dkv_grid = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, h, nk),
@@ -468,26 +546,74 @@ def _make_flash_fn(n: int, n_pad: int, block_q: int, block_k: int,
     return flash
 
 
+def flash_block_counts(n: int, block_q: Optional[int] = None,
+                       block_k: Optional[int] = None,
+                       mask: Optional[np.ndarray] = None,
+                       causal: bool = True, mask_spec=None) -> dict:
+    """A host-side count of what the kernels walk at ``n`` positions, from
+    the table their lists are built from: the (q, k) blocks ``visited``,
+    those of them wholly visible (``full``: the mask's arithmetic changes
+    nothing there; the kernels do it all the same, see the module's notes),
+    and the square's ``total``. Block sizes left out are the ones
+    ``flash_attention`` would take. A structured ``mask_spec`` that comes
+    without its table is tested entry by entry inside the kernels only, so
+    no block can be called full."""
+    block_q, block_k, mask_spec = _plan_blocks(n, mask, mask_spec,
+                                               block_q, block_k)
+    n_pad = _ceil_to(n, max(block_q, block_k))
+    tiles = _visible_tiles(n_pad, block_q, block_k, mask, causal, n)
+    untabled = mask is None and elem_fn_from_spec(mask_spec) is not None
+    return {"visited": int(tiles.any(axis=(1, 3)).sum()),
+            "full": 0 if untabled else int(tiles.all(axis=(1, 3)).sum()),
+            "total": tiles.shape[0] * tiles.shape[2]}
+
+
 def sparsity_fraction(n: int, block_q: int = 128, block_k: int = 128,
                       mask: Optional[np.ndarray] = None,
                       causal: bool = True) -> float:
     """Fraction of (q,k) blocks actually visited — the compute saving."""
-    n_pad = _ceil_to(n, max(block_q, block_k))
-    lists = build_block_lists(n_pad, block_q, block_k, mask, causal)
-    nq, nk = n_pad // block_q, n_pad // block_k
-    return float(lists.k_cnt.sum()) / float(nq * nk)
+    counts = flash_block_counts(n, block_q, block_k, mask, causal)
+    return counts["visited"] / counts["total"]
 
 
 def _auto_block(n: int, has_mask: bool) -> int:
     """Block sizes read on a v5e before the benchmark (fwd+bwd, bf16):
     mask-free kernels carry no element-mask operand so bigger blocks fit;
     masked kernels hold a (block, n_pad) int32 mask row and hit the 16M
-    scoped-VMEM limit earlier as n grows."""
+    scoped-VMEM limit earlier as n grows. Read again at 4352 positions for
+    PR 35's kernels (v5e, the three kernels alone, 64 x 128 and 32 x 192 /
+    128 heads): 256 x 256 stays. 128-wide blocks cost 1.3-2.1 x the time in
+    every kernel; 512 does not divide 4352, and padded to 4608 the kernels
+    gain 13 % at 128-wide heads and lose 7 % at 192, less than the pad and
+    slice copies around them cost."""
     if has_mask:
         blk = 256 if n <= 2560 else 128
     else:
         blk = 512 if n <= 2560 else 256
     return min(blk, max(128, _ceil_to(n, 128)))
+
+
+def _plan_blocks(n: int, mask, mask_spec, block_q, block_k):
+    """The block sizes a call runs with, and the spec it keeps."""
+    if mask_spec is not None and mask_spec[0] == "block":
+        if int(mask_spec[1]) % 128 != 0:
+            # a non-lane-aligned pattern block (e.g. the reference's size 16,
+            # attention.py:358) would force tiny Mosaic tiles — a lowering
+            # failure/perf cliff on real TPU. Fall back to the tabled
+            # element-mask path, which handles arbitrary masks at 128+ tiles.
+            mask_spec = None
+        else:
+            # block-aligned pattern: kernel tiles must coincide with the
+            # pattern's block grid for the no-element-mask shortcut to be exact
+            block_q = block_k = int(mask_spec[1])
+    # a structured spec carries no element-mask operand: auto blocks use the
+    # roomier mask-free VMEM budget
+    tabled = mask is not None and mask_spec is None
+    if block_q is None:
+        block_q = _auto_block(n, tabled)
+    if block_k is None:
+        block_k = _auto_block(n, tabled)
+    return block_q, block_k, mask_spec
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -513,24 +639,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n = q.shape[2]
-    if mask_spec is not None and mask_spec[0] == "block":
-        if int(mask_spec[1]) % 128 != 0:
-            # a non-lane-aligned pattern block (e.g. the reference's size 16,
-            # attention.py:358) would force tiny Mosaic tiles — a lowering
-            # failure/perf cliff on real TPU. Fall back to the tabled
-            # element-mask path, which handles arbitrary masks at 128+ tiles.
-            mask_spec = None
-        else:
-            # block-aligned pattern: kernel tiles must coincide with the
-            # pattern's block grid for the no-element-mask shortcut to be exact
-            block_q = block_k = int(mask_spec[1])
-    # a structured spec carries no element-mask operand: auto blocks use the
-    # roomier mask-free VMEM budget
-    tabled = mask is not None and mask_spec is None
-    if block_q is None:
-        block_q = _auto_block(n, tabled)
-    if block_k is None:
-        block_k = _auto_block(n, tabled)
+    block_q, block_k, mask_spec = _plan_blocks(n, mask, mask_spec,
+                                               block_q, block_k)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     n_pad = _ceil_to(n, max(block_q, block_k))

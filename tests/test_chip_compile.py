@@ -135,9 +135,10 @@ def test_decode_window_paged_1p4b(one_chip):
         _sds(one_chip, (B,), jnp.int32))
 
 
-def _flash_loss(mask):
+def _flash_loss(mask, mask_spec=None):
     def loss(q, k, v):
-        o = flash_attention(q, k, v, causal=True, mask=mask, interpret=False)
+        o = flash_attention(q, k, v, causal=True, mask=mask,
+                            mask_spec=mask_spec, interpret=False)
         return jnp.sum(o.astype(jnp.float32))
     return jax.grad(loss, argnums=(0, 1, 2))
 
@@ -174,16 +175,22 @@ def test_flash_fwd_bwd_ling3_latent_layer(one_chip):
             assert kernel in text, kernel
 
 
-@pytest.mark.parametrize("masked", [False, True],
-                         ids=["mask_free", "axial_masked"])
-def test_flash_fwd_bwd_512(one_chip, masked):
-    # full-causal mask-free variant and a block-sparse masked variant
+@pytest.mark.parametrize("masked,dtype", [
+    (None, jnp.bfloat16), ("table", jnp.bfloat16), ("spec", jnp.bfloat16),
+    (None, jnp.float32)],
+    ids=["mask_free", "axial_masked", "axial_spec", "mask_free_float32"])
+def test_flash_fwd_bwd_512(one_chip, masked, dtype):
+    """The full-causal mask-free variant, a block-sparse one with its mask as
+    a table (the forward and dkv kernels read its transpose) and as an
+    in-kernel element test, and float32 inputs (the operands' type is the
+    inputs': the same bodies with float32 products)."""
     from dalle_tpu.ops.attn_masks import axial_mask
     n, fmap = 256 + 16 * 16, 16
     mask = (np.asarray(axial_mask(256, fmap, axis=0))[:n, :n]
             if masked else None)
-    qkv = [_sds(one_chip, (2, 2, n, 64), jnp.bfloat16)] * 3
-    _mosaic_text(_flash_loss(mask), *qkv)
+    spec = ("axial", 256, fmap, 0) if masked == "spec" else None
+    qkv = [_sds(one_chip, (2, 2, n, 64), dtype)] * 3
+    _mosaic_text(_flash_loss(mask, spec), *qkv)
 
 
 @pytest.mark.parametrize("k,n", [(5120, 1536), (1536, 5120)],

@@ -679,6 +679,28 @@ def test_loss_head_for_the_benchmarks_real_shapes(config, batch, segments,
         [s["rows"][1] for s in got["segments"]][:-1]
 
 
+def test_build_step_span_carries_the_flash_block_counts(tmp_path,
+                                                        monkeypatch):
+    """On the flash tier ``layers`` says, per softmax layer, the score blocks
+    the kernels walk and how many of them no mask cuts (PR 35):
+    here 8 + 16 positions are one block, cut by the diagonal."""
+    monkeypatch.setattr("dalle_tpu.models.transformer.attention_tier",
+                        lambda *a, **kw: "flash")
+    tracer = obs.configure()
+    try:
+        tr = _tiny_trainer(tmp_path, MeshConfig(), compute="bfloat16",
+                           devices=jax.devices()[:1])
+        spans = [s for s in tracer.snapshot_spans()
+                 if s[0] == "init/build_step"]
+    finally:
+        obs.disable()
+    layers = spans[-1][5]["layers"]
+    assert layers["tier"] == "flash"
+    assert layers["flash"] == [
+        {"layer": i, "visited": 1, "full": 0, "total": 1}
+        for i in range(tr.model_cfg.depth)]
+
+
 def test_build_step_span_carries_the_loss_head(tmp_path):
     """dalle_small's sequence and vocabularies (at a width of 16, one layer):
     half of the full-width head's logits, as two segments."""

@@ -264,6 +264,11 @@ def test_fit_sentry_fires_once_and_report_degrades(vae_trainer, rng,
     assert "MODEL-HEALTH: DEGRADED (codebook-collapse in codebook" in rep
 
 
+# its own ceiling, the module's kept: run by itself this test reads 186
+# compiles, on the parent (18814e7) and on PR 35's tree alike (init_dalle's
+# eager init; jitted init, ROADMAP Queue 1 #5c, is the repair), so under the
+# module's 155 it passed only on a worker an earlier file had warmed
+@pytest.mark.recompile_budget(210)
 def test_dalle_trainer_health_off_by_default(rng):
     import tempfile
     from dalle_tpu.config import (DalleConfig, MeshConfig, PrecisionConfig,

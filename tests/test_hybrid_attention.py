@@ -204,6 +204,17 @@ def test_the_softmax_layers_take_the_tier_chosen_for_the_configured_length(
     assert layers["gqa_gated"] == {"heads": 4, "head_dim": 8, "kv_heads": 2}
     assert layers["kda"] == {"heads": 4, "head_dim": 8, "chunk": 64,
                              "chunks": 2}
+    # the flash kernels' walk, per softmax layer: 72 positions are one
+    # block, cut by the diagonal and the padded tail
+    assert layers["flash"] == [
+        {"layer": i, "visited": 1, "full": 0, "total": 1} for i in (0, 4)]
+    # at the cell's 4352 positions: 17 x 17 blocks of 256, the 136 under
+    # the diagonal wholly visible
+    cell = DalleConfig(**{**MODEL, "depth": 4, "text_seq_len": 256,
+                          "image_fmap_size": 64, "image_size": 512})
+    assert cell.total_seq_len == 4352
+    assert stack_layers(cell.transformer())["flash"] == [
+        {"layer": 0, "visited": 153, "full": 136, "total": 289}]
 
 
 # -- the router and the share ---------------------------------------------------

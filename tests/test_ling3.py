@@ -450,6 +450,13 @@ def test_the_stack_says_its_kinds_its_tier_and_the_two_widths(monkeypatch):
     monkeypatch.setattr("dalle_tpu.models.transformer.attention_tier",
                         lambda *a, **kw: "flash")
     assert stack_layers(cfg)["tier"] == "flash"
+    assert stack_layers(cfg)["flash"] == [
+        {"layer": 2, "visited": 1, "full": 0, "total": 1}]
+    cell = DalleConfig(**{**MODEL, "depth": 6, "text_seq_len": 256,
+                          "image_fmap_size": 64, "image_size": 512})
+    assert stack_layers(cell.transformer())["flash"] == [
+        {"layer": i, "visited": 153, "full": 136, "total": 289}
+        for i in (2, 5)]
     stack = Transformer(cfg).bind({})
     assert [type(layer.fn).__name__ for layer in stack.attn_layers] == [
         "KimiDeltaAttention", "KimiDeltaAttention", "MLAttention"]
