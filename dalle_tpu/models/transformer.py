@@ -25,6 +25,7 @@ TPU-first redesign decisions:
 
 from __future__ import annotations
 
+import contextlib
 from itertools import cycle, islice
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -287,20 +288,27 @@ class Attention(nn.Module):
                 # layout; head split/merge live inside the kernel. Rotary
                 # rides the same layout: applied on the (b, n, 3h, d) VIEW —
                 # a reshape, not the head-split transpose the dense path pays
-                qkv = self.to_qkv(x)
-                if rotary is not None:
-                    rot = rotary[:n][:, None]          # (n, 1, rot_dim)
-                    qkv = apply_rotary(
-                        rot, qkv.reshape(b, n, 3 * self.heads, self.dim_head)
-                    ).reshape(b, n, -1)
-                out = fused_qkv_attention(qkv, np_mask, self.heads, None, None,
-                                          mask_spec).astype(x.dtype)
-                return self.drop(self.to_out(out),
-                                 deterministic=deterministic)
-        q, k, v = self._split(self.to_qkv(x), n)
-        if rotary is not None:
-            rot = rotary[:n][None, None]
-            q, k, v = (apply_rotary(rot, t) for t in (q, k, v))
+                with jax.named_scope("attn/qkv"):
+                    qkv = self.to_qkv(x)
+                    if rotary is not None:
+                        rot = rotary[:n][:, None]          # (n, 1, rot_dim)
+                        qkv = apply_rotary(
+                            rot,
+                            qkv.reshape(b, n, 3 * self.heads, self.dim_head)
+                        ).reshape(b, n, -1)
+                with jax.named_scope("attn_core"):
+                    out = fused_qkv_attention(qkv, np_mask, self.heads, None,
+                                              None, mask_spec).astype(x.dtype)
+                with jax.named_scope("attn/out"):
+                    out = self.to_out(out)
+                return self.drop(out, deterministic=deterministic)
+        with jax.named_scope("attn/qkv"):
+            q, k, v = self._split(self.to_qkv(x), n)
+            if rotary is not None:
+                rot = rotary[:n][None, None]
+                q, k, v = (apply_rotary(rot, t) for t in (q, k, v))
+        # whichever tier runs, its call is the layer's ``attn_core`` on the
+        # device trace (obs/device.py ``scope_layer``)
         if ring:
             # sequence-parallel ring attention: full causal plus structured
             # (axial/conv) sparse masks, whose element test is a pure function
@@ -315,22 +323,25 @@ class Attention(nn.Module):
             from ..parallel.ring_attention import ring_attention
             # zigzag: balanced causal layout + quadrant skipping (exact);
             # kernel='auto' → Pallas chunk kernels on TPU for chunks ≥ 512
-            out = ring_attention(q, k, v, mesh=self.sp_mesh, causal=True,
-                                 zigzag=True,
-                                 mask_spec=mask_spec if np_mask is not None
-                                 else None)
+            with jax.named_scope("attn_core"):
+                out = ring_attention(q, k, v, mesh=self.sp_mesh, causal=True,
+                                     zigzag=True,
+                                     mask_spec=mask_spec if np_mask is not None
+                                     else None)
         elif kernel == "flash":
             from ..ops.flash_attention import flash_attention
-            out = flash_attention(q, k, v, mask=np_mask, mask_spec=mask_spec,
-                                  causal=self.causal)
+            with jax.named_scope("attn_core"):
+                out = flash_attention(q, k, v, mask=np_mask,
+                                      mask_spec=mask_spec, causal=self.causal)
         else:
             static = None if np_mask is None else jnp.asarray(np_mask)
             with jax.named_scope("attn_core"):
                 out = attend(q, k, v, causal=self.causal, key_mask=key_mask,
                              static_mask=static, stable=self.stable,
                              softmax_f32=self.softmax_f32)
-        out = out.transpose(0, 2, 1, 3).reshape(b, n, -1)
-        return self.drop(self.to_out(out), deterministic=deterministic)
+        with jax.named_scope("attn/out"):
+            out = self.to_out(out.transpose(0, 2, 1, 3).reshape(b, n, -1))
+        return self.drop(out, deterministic=deterministic)
 
     def prefill(self, x, cache: KVCache, *, rotary=None, static_mask=None):
         """Full-prefix forward that also fills the KV cache from position 0."""
@@ -536,7 +547,13 @@ class TransformerLayer(nn.Module):
         y = self.norm(x)
         if self.shift:
             y = shift_tokens_full(y, self.text_len, self.image_size)
-        y = self.fn(y, **kw)
+        # the dense MLPs run under the scope ``ff``; every other layer kind
+        # names its own parts (the shared expert, an MLP of the same class
+        # inside ``moe/shared``, stays the routed layer's)
+        dense_mlp = isinstance(self.fn, (GEGLUFeedForward, SwiGLUFeedForward))
+        with (jax.named_scope("ff") if dense_mlp
+              else contextlib.nullcontext()):
+            y = self.fn(y, **kw)
         if isinstance(y, tuple):       # (output, counters): see _block_body
             return self._post(y[0]), y[1]
         return self._post(y)

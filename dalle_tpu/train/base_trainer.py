@@ -19,6 +19,7 @@ from ..config import TrainConfig
 from ..obs import (DeviceTelemetry, StallWatchdog, counter_add,
                    export_chrome_trace, export_spans_jsonl, span)
 from ..obs import configure as obs_configure
+from ..obs.device import capture_program
 from .checkpoints import CheckpointManager
 
 
@@ -69,6 +70,7 @@ class BaseTrainer:
     _fit_phase = None
     _fit_step = None
     _fit_warmup = None
+    _fit_log = staticmethod(print)   # fit()'s ``log``, for _run_step
     # graftpulse (obs/anomaly.py): built by fit() when ObsConfig.health is
     # set; every fetched metrics dict passes through _health_observe once
     health_sentry = None
@@ -469,6 +471,7 @@ class BaseTrainer:
                 depth=tc.device_prefetch)
             batches = prefetcher
         self._fit_late = {}    # t_ckpt_s / t_after_s: land one record late
+        self._fit_log = log
         self._fit_warmup = span("fit/warmup").__enter__()
         meta = self._meta()
         if tc.preflight_checkpoint:
@@ -801,6 +804,22 @@ class BaseTrainer:
                 return
             params, opt_state = restored
             self.state = self.state.replace(params=params, opt_state=opt_state)
+
+    def _run_step(self, jitted, *args):
+        """``jitted(*args)``, a step's dispatch. The first of a fit() (its
+        warm-up is still open) also hands the program just dispatched to
+        ``obs.device.capture_program`` as ``"train/step"``, under the span
+        ``warmup/scopes``, while the device runs that step: the call's own
+        executable and arguments, so nothing compiles or loads twice, and a
+        reader of a device trace can join its operations to the program's
+        ``jax.named_scope``s (docs/OBSERVABILITY.md "Device time by
+        scope")."""
+        out = jitted(*args)
+        if self._fit_warmup is not None:
+            with span("warmup/scopes"):
+                capture_program("train/step", jitted, *args,
+                                log=self._fit_log)
+        return out
 
     def _finish_step(self, metrics, stamp: Optional[dict] = None) -> dict:
         """Post-step bookkeeping: advance the host step and hand back a

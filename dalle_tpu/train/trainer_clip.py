@@ -111,7 +111,8 @@ class CLIPTrainer(BaseTrainer):
         with span("clip/shard_batch"):
             text, images = self._put_batch((text, images))
         with span("clip/step"):
-            self.state, metrics = self.step_fn(self.state, text, images)
+            self.state, metrics = self._run_step(self.step_fn, self.state,
+                                                 text, images)
         return self._finish_step(metrics)
 
     def train_steps(self, texts: np.ndarray, imagess: np.ndarray):
@@ -127,8 +128,8 @@ class CLIPTrainer(BaseTrainer):
         with span("clip/shard_batch", k=k):
             texts, imagess = self._put_batch((texts, imagess), stacked=True)
         with span("clip/steps", k=k):
-            self.state, metrics = self._multi_step_fn(self.state,
-                                                      (texts, imagess))
+            self.state, metrics = self._run_step(
+                self._multi_step_fn, self.state, (texts, imagess))
         self._host_step += k - 1     # _finish_step adds the final +1
         return self._finish_step(metrics)
 

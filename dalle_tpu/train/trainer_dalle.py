@@ -233,7 +233,8 @@ class DalleTrainer(BaseTrainer):
         with span("dalle/shard_batch"):
             text, image_ids = self._put_batch((text, image_ids))
         with span("dalle/step"):
-            self.state, metrics = self.step_fn(self.state, text, image_ids, key)
+            self.state, metrics = self._run_step(
+                self.step_fn, self.state, text, image_ids, key)
         return self._finish_step(metrics)
 
     # -- k steps in one device program ---------------------------------------
@@ -253,7 +254,7 @@ class DalleTrainer(BaseTrainer):
             texts, image_ids = self._put_batch((texts, image_ids),
                                                stacked=True)
         with span("dalle/steps", k=k):
-            self.state, metrics = self._multi_step_fn(self.state, texts,
-                                                      image_ids, keys)
+            self.state, metrics = self._run_step(
+                self._multi_step_fn, self.state, texts, image_ids, keys)
         self._host_step += k - 1     # _finish_step adds the final +1
         return self._finish_step(metrics)

@@ -186,8 +186,8 @@ class VAETrainer(BaseTrainer):
         with span("vae/shard_batch"):
             images = self._put(images, np.float32)
         with span("vae/step"):
-            self.state, metrics = self.step_fn(self.state, images, key,
-                                               jnp.float32(temp))
+            self.state, metrics = self._run_step(
+                self.step_fn, self.state, images, key, jnp.float32(temp))
         # the stamp travels with this step's record: under fit() the record
         # handed back is the previous boundary's
         return self._finish_step(metrics, {"temperature": temp})
@@ -212,8 +212,9 @@ class VAETrainer(BaseTrainer):
         with span("vae/shard_batch", k=k):
             images = self._put(images, np.float32, stacked=True)
         with span("vae/steps", k=k):
-            self.state, metrics = self._multi_step_fn(
-                self.state, (images, keys, jnp.asarray(temps, jnp.float32)))
+            self.state, metrics = self._run_step(
+                self._multi_step_fn, self.state,
+                (images, keys, jnp.asarray(temps, jnp.float32)))
         self._host_step += k - 1     # _finish_step adds the final +1
         return self._finish_step(metrics, {"temperature": float(temps[-1])})
 

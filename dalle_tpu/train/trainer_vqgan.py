@@ -396,12 +396,13 @@ class VQGANTrainer(BaseTrainer):
         if self.loss_mode != "gan":
             t = images if targets is None else self._put(targets, np.float32)
             with span("vqgan/step"):
-                self.state, metrics = self.step_fn(self.state, images, t, key,
-                                                   jnp.float32(temp))
+                self.state, metrics = self._run_step(
+                    self.step_fn, self.state, images, t, key,
+                    jnp.float32(temp))
             return self._finish_step(metrics)
         with span("vqgan/step"):
-            self.state, metrics = self.step_fn(self.state, images, key,
-                                               jnp.float32(temp))
+            self.state, metrics = self._run_step(
+                self.step_fn, self.state, images, key, jnp.float32(temp))
         # the stamp travels with this step's record: under fit() the record
         # handed back is the previous boundary's
         return self._finish_step(
@@ -439,7 +440,8 @@ class VQGANTrainer(BaseTrainer):
         else:
             xs = (images, keys, temps_dev)
         with span("vqgan/steps", k=k):
-            self.state, metrics = self._multi_step_fn(self.state, xs)
+            self.state, metrics = self._run_step(self._multi_step_fn,
+                                                 self.state, xs)
         self._host_step += k - 1     # _finish_step adds the final +1
         return self._finish_step(
             metrics, {"temperature": float(temps[-1])}

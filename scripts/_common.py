@@ -207,12 +207,17 @@ def install_sigusr2_profiler(default_dir: str, args=None) -> bool:
             return False
         outdir = getattr(args, "profiler_dir", None) or default_dir
         capture_s = float(getattr(args, "profiler_capture_s", 5.0))
-    state = {"active": False}
+    state = {"active": False, "path": None}
 
     def _stop():
         import jax
         try:
             jax.profiler.stop_trace()
+            # the step's instruction -> [layer, phase, op_name] table beside
+            # the capture (docs/OBSERVABILITY.md "Device time by scope")
+            from dalle_tpu.obs.device import write_program_scopes
+            write_program_scopes(
+                os.path.join(state["path"], "program_scopes.json"))
         except Exception as exc:  # noqa: BLE001 - a failed stop must not
             # kill the timer thread; the next capture starts a fresh trace
             print(f"[graftscope] profiler stop failed: {exc!r}")
@@ -224,6 +229,7 @@ def install_sigusr2_profiler(default_dir: str, args=None) -> bool:
         state["active"] = True
         import jax
         path = os.path.join(outdir, time.strftime("profile_%Y%m%d_%H%M%S"))
+        state["path"] = path
         os.makedirs(path, exist_ok=True)
         try:
             jax.profiler.start_trace(path)

@@ -105,11 +105,19 @@ def rng():
 # ``test_bench_ling3.py`` asserts everything the four hold but "last", by
 # name and as pins of ORDER (PR 25's eight, then PR 27's five, then PR 32's
 # four, then PR 34's three), so the next appended entry breaks nothing.
+# Since PR 36 nine per-layer metrics of the step's device time by scope list
+# all five cells, so ``test_bench_ling3.py``'s pin, which also asserts that
+# no metric but a cell's own PR's lists one of the three newer cells, cannot
+# hold either. ``test_bench_scopes.py`` asserts everything else it holds, by
+# name and as pins of order, and that those cells are listed by their own
+# PR's metrics and PR 36's nine alone.
 _PINNED_BY_POSITION = tuple(
     f"{module}::{test}"
     for module in ("test_bench_moe.py", "test_bench_hybrid.py")
     for test in ("test_the_entries_are_new_and_sit_at_the_end_of_their_lists",
-                 "test_pr25s_entries_are_listed_as_their_test_pins_them"))
+                 "test_pr25s_entries_are_listed_as_their_test_pins_them")) + (
+    "test_bench_ling3.py::"
+    "test_the_entries_of_every_pr_are_pinned_by_name_and_in_order",)
 
 
 def pytest_collection_modifyitems(items):
@@ -117,5 +125,7 @@ def pytest_collection_modifyitems(items):
         if item.nodeid.endswith(_PINNED_BY_POSITION):
             item.add_marker(pytest.mark.xfail(
                 reason="pins an earlier PR's entries as the last of "
-                       "BENCHMARK.json's lists; the driver takes new entries "
-                       "only at the end (tests/conftest.py)", strict=False))
+                       "BENCHMARK.json's lists, or its cell as listed by no "
+                       "later metric; the driver takes new entries only at "
+                       "the end and PR 36's list every cell "
+                       "(tests/conftest.py)", strict=False))
