@@ -38,7 +38,7 @@ from ..config import TransformerConfig
 from ..ops.attention import (KVCache, attend, attention_tier, cached_attend,
                              cached_attend_window)
 from ..ops.attn_masks import build_mask
-from ..ops.kda import CHUNK, chunks_of
+from ..ops.kda import CHUNK, KDA_SAVED, chunks_of
 from ..ops.quantize_weights import QDense
 from ..ops.rotary import (apply_rotary, dalle_pos_emb, seq_yarn_table,
                           yarn_mscale)
@@ -780,9 +780,13 @@ class Transformer(nn.Module):
                 # real jax.checkpoint per block pair: activations inside the
                 # block are recomputed in backward — the memory lever that
                 # lets batch/depth scale past HBM (complements `reversible`,
-                # which is O(1) in depth rather than O(depth) checkpoints)
+                # which is O(1) in depth rather than O(depth) checkpoints).
+                # A linear-attention block keeps what its core names
+                # (``KDA_SAVED``): the recompute then runs none of the core
+                saved = ({"policy": KDA_SAVED}
+                         if self.attn_kinds[ind] == "kda" else {})
                 blk = nn.remat(_block_body, prevent_cse=False,
-                               static_argnums=(3, 4))
+                               static_argnums=(3, 4), **saved)
                 x, counters = blk(self, x, key_mask, ind, deterministic)
             else:
                 x, counters = _block_body(self, x, key_mask, ind,

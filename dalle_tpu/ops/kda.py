@@ -19,10 +19,12 @@ unit lower-triangular system:
     o  = (exp(G) q) S + Aqk u
     S' = Diag(exp(G_C)) S + (k exp(G_C - G))^T u
 
-``kda_chunked`` computes everything that does not depend on ``S`` for
-``GROUP`` chunks at a time, outside the recurrence (scope
-``attn/kda_chunk``), then scans the chunks carrying only ``S``
-(``attn/kda_state``): the scan saves the states alone.
+``kda_chunked`` is one scan over groups of ``GROUP`` chunks carrying only
+``S``: a group computes everything of its chunks that does not depend on
+``S`` (scope ``attn/kda_chunk``), then steps ``S`` through them (scope
+``attn/kda_state``), and its backward pass recomputes it once from the
+state at its entry. A layer rematerialised with ``KDA_SAVED`` keeps those
+entry states and the output, so its own recompute runs no group.
 
 **Never the exponential of a positive sum of gates.** ``exp(-G_j)`` over 64
 positions overflows float32 for gates the initialisation produces, so no
@@ -42,6 +44,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 CHUNK = 64      # positions a chunk
 # positions a sub-block of a chunk (CHUNK / SUB a power of 2): the sublanes
@@ -51,6 +54,11 @@ CHUNK = 64      # positions a chunk
 # 435 ms at 16 (PERF.md, PR 32)
 SUB = 8
 GROUP = 2       # chunks whose state-free work is alive at once
+
+# what a layer rematerialised around ``kda_chunked`` keeps for its backward
+# pass: the state at each group's entry and the core's output
+KDA_SAVED = jax.checkpoint_policies.save_only_these_names("kda_entry",
+                                                          "kda_out")
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -251,12 +259,15 @@ def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps,
     relayout from (b, n, h d), and every such tensor is a quarter of a GB at
     2 x 4352 x 64 x 128.
 
-    The work within chunks runs ``GROUP`` chunks at a time, each group
+    The chunks run ``GROUP`` at a time in one scan, each group
     rematerialised in the backward pass: the decays between the positions of
     a sub-block are (chunks, b, h, CHUNK, SUB, d_k) float32 numbers, 2.3 GB
     a layer at 2 x 4352 positions of 64 heads were all chunks alive at once,
-    and the terms they multiply twice that.
-    The scan over the states saves the states alone."""
+    and the terms they multiply twice that. The scan saves the state at each
+    group's entry (``kda_entry``) and the output (``kda_out``); a layer
+    rematerialised with ``KDA_SAVED`` keeps those two and nothing else of
+    the core, so that its backward pass runs a group's forward once, for
+    the group's own transpose, and not a second time to rebuild the layer."""
     b, n, h, dk = q.shape
     dtype = v.dtype
     nc = chunks_of(n)
@@ -271,31 +282,42 @@ def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps,
         x = jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
         return x.reshape((nc // group, group) + x.shape[1:])
 
+    def group_steps(state, x):
+        """One group: its chunks' state-free work, then its chunks' steps of
+        the state, in order."""
+        with jax.named_scope("attn/kda_chunk"):
+            xs, low = _within_chunks(*x, a_log, bias, lower_bound)
+        outs = []
+        with jax.named_scope("attn/kda_state"):
+            for u0, w, q_in, aqk, k_out, keep in zip(*xs):
+                u = u0 - _mm("bhtd,bhdv->bhtv", w, state, dtype)
+                o = (_mm("bhtd,bhdv->bhtv", q_in, state, dtype)
+                     + _mm("bhtj,bhjv->bhtv", aqk, u, dtype))
+                state = keep[..., None] * state + _mm(
+                    "bhtd,bhtv->bhdv", k_out, u, dtype)
+                o = (o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                                       + eps)
+                     * norm_scale.astype(jnp.float32))
+                outs.append(o.astype(dtype))
+        return state, (jnp.stack(outs), low)
+
+    def body(state, x):
+        # the two things a rematerialised layer keeps for its backward pass
+        # (``KDA_SAVED``): the state at a group's entry, from which the
+        # group's backward recomputes the group, and the group's output,
+        # which the layer's gate and output product read
+        state = checkpoint_name(state, "kda_entry")
+        state, (o, low) = jax.checkpoint(group_steps)(state, x)
+        return state, (checkpoint_name(o, "kda_out"), low)
+
     with jax.named_scope("attn/kda_chunk"):
-        xs, lows = jax.lax.map(
-            jax.checkpoint(lambda x: _within_chunks(*x, a_log, bias,
-                                                    lower_bound)),
-            # behind the end no gate: softplus (or the bounded form's
-            # sigmoid) of the least number is 0
-            (chunked(q), chunked(k), chunked(v),
-             chunked(f, jnp.finfo(f.dtype).min), chunked(beta)))
-        xs = jax.tree.map(lambda x: x.reshape((nc,) + x.shape[2:]), xs)
-
-    @jax.checkpoint
-    def step(state, x):
-        u0, w, q_in, aqk, k_out, keep = x
-        u = u0 - _mm("bhtd,bhdv->bhtv", w, state, dtype)
-        o = (_mm("bhtd,bhdv->bhtv", q_in, state, dtype)
-             + _mm("bhtj,bhjv->bhtv", aqk, u, dtype))
-        state = keep[..., None] * state + _mm("bhtd,bhtv->bhdv", k_out, u,
-                                              dtype)
-        o = (o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
-             * norm_scale.astype(jnp.float32))
-        return state, o.astype(dtype)
-
+        # behind the end no gate: softplus (or the bounded form's sigmoid)
+        # of the least number is 0
+        xs = (chunked(q), chunked(k), chunked(v),
+              chunked(f, jnp.finfo(f.dtype).min), chunked(beta))
     with jax.named_scope("attn/kda_state"):
-        _, o = jax.lax.scan(
-            step, jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32), xs)
-    # (chunks, b, h, CHUNK, d_v) -> (b, n, h, d_v)
-    o = jnp.moveaxis(jnp.moveaxis(o, 3, 2), 0, 1)
+        _, (o, lows) = jax.lax.scan(
+            body, jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32), xs)
+    # (groups, group, b, h, CHUNK, d_v) -> (b, n, h, d_v)
+    o = jnp.moveaxis(jnp.moveaxis(o.reshape((nc,) + o.shape[2:]), 3, 2), 0, 1)
     return o.reshape(b, nc * CHUNK, h, -1)[:, :n], jnp.min(lows)

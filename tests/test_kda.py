@@ -1,13 +1,16 @@
 """The chunked core of Kimi delta attention (ops/kda.py) against the delta
 rule as it is defined, one position at a time, on the CPU in float32."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dalle_tpu.ops.kda import (CHUNK, SUB, _merged, _unit_lower_inverse,
-                               chunks_of, kda_chunked)
+from dalle_tpu.obs.device import scope_layer
+from dalle_tpu.ops.kda import (CHUNK, KDA_SAVED, SUB, _merged,
+                               _unit_lower_inverse, chunks_of, kda_chunked)
 
 HI = jax.lax.Precision.HIGHEST
 EPS = 1e-5
@@ -169,3 +172,94 @@ def test_the_triangular_inverse_holds_where_powers_of_the_matrix_do_not():
     plain = jax.grad(lambda m: jnp.sum(weights * jnp.linalg.inv(
         jnp.eye(64) + jnp.tril(m, -1))))(b)
     np.testing.assert_allclose(ours, plain, atol=2e-3, rtol=1e-3)
+
+
+def layer_pair(remat):
+    """The gradient of two stacked cores, the first one's output the second
+    one's queries, each under ``jax.checkpoint(**remat)`` (``None``: none),
+    with respect to every input and leaf."""
+    def layer(q, k, v, f, beta, a_log, bias, scale):
+        return chunked(q, k, v, f, beta, a_log, bias, scale)[0]
+    if remat is not None:
+        layer = jax.checkpoint(layer, **remat)
+
+    def loss(q, *rest):
+        o = layer(layer(q, *rest).astype(q.dtype), *rest)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32)))
+    return jax.jit(jax.grad(loss, argnums=tuple(range(8))))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_layer_rematerialised_with_the_saved_names_is_the_plain_core(dtype):
+    """Four chunks in groups of two, the last padded: two layers under
+    ``jax.checkpoint`` with ``KDA_SAVED`` give the gradients of the same
+    layers not rematerialised, float32 to the bit, bfloat16 to its own
+    rounding (the same arithmetic in the same order either way)."""
+    args = core_inputs(250, 1.6, dv=8)
+    args = tuple(a.astype(dtype) for a in args[:4]) + args[4:]
+    plain = layer_pair(None)(*args)
+    saved = layer_pair({"policy": KDA_SAVED})(*args)
+    names = ("q", "k", "v", "f", "beta", "a_log", "bias", "scale")
+    for name, a, b in zip(names, saved, plain):
+        assert a.dtype == b.dtype, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        if dtype == jnp.float32:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        else:
+            np.testing.assert_allclose(
+                a, b, err_msg=name, rtol=0,
+                atol=float(jnp.finfo(dtype).eps) * float(jnp.abs(b).max()))
+
+
+def _outside_transpose(op_name: str) -> str:
+    """An ``op_name`` path with every ``transpose(...)`` cut out: what is
+    left names the scopes of a forward computation, the backward pass's
+    recomputes among them."""
+    while (i := op_name.find("transpose(")) >= 0:
+        depth, j = 0, i + len("transpose")
+        for j in range(j, len(op_name)):
+            depth += {"(": 1, ")": -1}.get(op_name[j], 0)
+            if depth == 0:
+                break
+        op_name = op_name[:i] + op_name[j + 1:]
+    return op_name
+
+
+def forward_chunk_loops(hlo_text: str) -> int:
+    """The ``while`` loops of an optimized module whose bodies reach, through
+    what they call, an instruction of ``attn/kda_chunk`` outside
+    ``transpose(``: the loops that run a group's within-chunk forward."""
+    computations, body = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            body = computations.setdefault(head[1], [])
+        elif body is not None:
+            body.append(line)
+
+    def reaches(name):
+        lines = computations.get(name, [])
+        return any(
+            scope_layer(_outside_transpose(path))[0] == "kda_chunk"
+            for line in lines for path in re.findall(r'op_name="([^"]*)"',
+                                                     line)) or any(
+            reaches(called) for line in lines
+            for called in re.findall(r"(?:calls|to_apply|body)=%?([\w.\-]+)",
+                                     line))
+    return sum(reaches(name) for lines in computations.values()
+               for line in lines
+               for name in re.findall(r" while\(.*body=%?([\w.\-]+)", line))
+
+
+@pytest.mark.parametrize("remat,per_layer", [({"policy": KDA_SAVED}, 2),
+                                              ({}, 3)])
+def test_a_rematerialised_layer_runs_its_within_chunk_forward_twice(
+        remat, per_layer):
+    """The compiled gradient of two rematerialised layers: with
+    ``KDA_SAVED`` each layer's groups run forward in the forward pass and
+    once more inside the loop that transposes them; without the names the
+    layer's recompute runs them a third time."""
+    args = core_inputs(250, 1.6, dv=8)
+    text = layer_pair(remat).lower(*args).compile().as_text()
+    assert forward_chunk_loops(text) == 2 * per_layer
