@@ -264,12 +264,25 @@ class BlockConfig(ConfigBase):
     expert's, and the scores select and weigh) or ``noaux_tc`` (DeepSeek-V3,
     arXiv:2412.19437: a per-expert bias, a leaf no gradient reaches, is
     added to the scores that select, a group's score is the sum of its two
-    best, and the unbiased scores weigh)."""
-    attention: str = "mha"             # mha | mla | gqa_gated | kda
+    best, and the unbiased scores weigh).
+
+    Olmo-Hybrid's layers (``model_type: olmo_hybrid``) add ``gdn`` (Gated
+    DeltaNet, arXiv:2412.06464: ``linear_num_heads`` heads whose keys are
+    ``linear_key_head_dim`` wide and values ``linear_value_head_dim``, one
+    decay a head ``g = -exp(A_h) softplus(W_a x + dt_bias_h)``, beta =
+    ``kda_beta_max * sigmoid(W_b x)``, 2 for the published
+    ``linear_allow_neg_eigval``, a SiLU output gate from a whole projection)
+    and ``gqa`` (grouped-query softmax attention without an output gate;
+    with ``qk_norm`` a learned RMSNorm over the whole query and key
+    projections, OLMo 2's). The two widths default to ``linear_head_dim``.
+    ``norm_position: "post"`` is OLMo 2's block, ``x + norm(f(x))`` with no
+    norm ahead of ``f``; ``"pre"`` (every other block) is ``x + f(norm(x))``."""
+    attention: str = "mha"             # mha | mla | gqa_gated | kda | gdn | gqa
     # cyclic per-layer attention kinds; () is ``attention`` in every layer
     attention_layers: Tuple[str, ...] = ()
     feed_forward: str = "geglu"        # geglu | swiglu | moe
     norm: str = "layernorm"            # layernorm | rmsnorm
+    norm_position: str = "pre"         # pre | post
     layerscale: bool = True
     positions: str = "dalle_axial"     # dalle_axial | seq_yarn | none
     first_dense_layers: int = 0
@@ -290,7 +303,10 @@ class BlockConfig(ConfigBase):
     short_conv_kernel_size: int = 4
     linear_gate_rank: int = 0          # 0: whole projections, no latent
     kda_lower_bound: float = 0.0       # < 0: the bounded decay
-    kda_beta_max: float = 2.0
+    kda_beta_max: float = 2.0          # beta's range, kda and gdn
+    # gdn (0: linear_head_dim)
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
     # seq_yarn (yarn_factor 1 is plain rotary)
     rope_theta: float = 10000.0
     yarn_factor: float = 1.0
@@ -312,9 +328,10 @@ class BlockConfig(ConfigBase):
     norm_topk_prob: bool = False
     topk_method: str = "group_limited_greedy"   # | noaux_tc
 
-    KINDS = {"attention": ("mha", "mla", "gqa_gated", "kda"),
+    KINDS = {"attention": ("mha", "mla", "gqa_gated", "kda", "gdn", "gqa"),
              "feed_forward": ("geglu", "swiglu", "moe"),
              "norm": ("layernorm", "rmsnorm"),
+             "norm_position": ("pre", "post"),
              "positions": ("dalle_axial", "seq_yarn", "none"),
              "scoring_func": ("softmax", "sigmoid"),
              "attention_gate": ("none", "head_wise"),
@@ -340,8 +357,15 @@ class BlockConfig(ConfigBase):
     @property
     def is_default(self) -> bool:
         return (self.attention_kinds, self.feed_forward, self.norm,
-                self.positions, self.layerscale) == (
-                    ("mha",), "geglu", "layernorm", "dalle_axial", True)
+                self.norm_position, self.positions, self.layerscale) == (
+                    ("mha",), "geglu", "layernorm", "pre", "dalle_axial",
+                    True)
+
+    @property
+    def linear_widths(self) -> Tuple[int, int]:
+        """(key, value) width of a linear-attention head."""
+        return (self.linear_key_head_dim or self.linear_head_dim,
+                self.linear_value_head_dim or self.linear_head_dim)
 
     @property
     def name(self) -> str:

@@ -42,20 +42,25 @@ from ..ops.kda import CHUNK, KDA_SAVED, chunks_of
 from ..ops.quantize_weights import QDense
 from ..ops.rotary import (apply_rotary, dalle_pos_emb, seq_yarn_table,
                           yarn_mscale)
-from .hybrid_attention import GatedGQAttention, KimiDeltaAttention
+from .hybrid_attention import (GatedDeltaNet, GatedGQAttention,
+                               KimiDeltaAttention)
 from .latent_moe import (MLAttention, MoEFeedForward, RMSNorm,
                          SwiGLUFeedForward)
 
 # how a counter of the layers that count is reduced over depth (the rest
-# are summed)
-_WORST_LAYER = {"moe_load_max_over_mean": jnp.max, "kda_logdecay_min": jnp.min}
+# are summed): the worst layer's, or a share's mean over layers of one size
+_OVER_DEPTH = {"moe_load_max_over_mean": jnp.max, "kda_logdecay_min": jnp.min,
+               "gdn_logdecay_min": jnp.min, "gdn_beta_over_one_pct": jnp.mean}
+# the softmax layers' kinds (a tier is chosen for them) and the recurrent ones
+SOFTMAX_KINDS = ("mha", "gqa_gated", "gqa", "mla")
+LINEAR_KINDS = ("kda", "gdn")
 
 
 def reduce_counters(counted) -> dict:
     """The counters of several layers (or stacks) as one dict: summed, but
-    for those of ``_WORST_LAYER``, which keep the worst."""
+    for those of ``_OVER_DEPTH``: the worst layer's, or a share's mean."""
     names = dict.fromkeys(k for layer in counted for k in layer)
-    return {k: _WORST_LAYER.get(k, jnp.sum)(
+    return {k: _OVER_DEPTH.get(k, jnp.sum)(
         jnp.stack([layer[k] for layer in counted if k in layer]))
         for k in names}
 
@@ -69,8 +74,8 @@ def _block_body(mdl, x, key_mask, ind: int, deterministic: bool):
                              np_mask=mdl.np_masks[t],
                              mask_spec=mdl.mask_specs[t],
                              deterministic=deterministic)
-    # a layer that counts (KimiDeltaAttention, MoEFeedForward) returns
-    # (output, counters)
+    # a layer that counts (KimiDeltaAttention, GatedDeltaNet,
+    # MoEFeedForward) returns (output, counters)
     y, counters = y if isinstance(y, tuple) else (y, {})
     x = x + y
     y = mdl.ff_layers[ind](x, deterministic=deterministic)
@@ -133,7 +138,8 @@ def stack_layers(c: TransformerConfig) -> dict:
     """What the stack is built from, from the configuration alone: every
     layer's attention kind, the tier chosen for the softmax layers, and per
     kind its heads (``kv_heads`` for grouped keys and values; ``chunk`` and
-    ``chunks`` a sequence for the chunked recurrence; ``qk_dim`` and
+    ``chunks`` a sequence for the chunked recurrence, ``key_dim`` and
+    ``value_dim`` for ``gdn``'s two head widths; ``qk_dim`` and
     ``v_dim`` for latent attention's two head widths). Under ``fused``, where
     that is the tier: how many score blocks of the square the kernel forms
     (``ops.fused_attention.block_plan`` of each distinct table of the stack,
@@ -145,7 +151,7 @@ def stack_layers(c: TransformerConfig) -> dict:
     kinds = list(islice(cycle(blk.attention_kinds), c.depth))
     out = {"kinds": kinds, "tier": (
         attention_tier(c.use_pallas, c.seq_len, c.heads, c.dim_head)
-        if {"mha", "gqa_gated", "mla"} & set(kinds) else "dense")}
+        if set(SOFTMAX_KINDS) & set(kinds) else "dense")}
     if out["tier"] == "fused" and "mha" not in kinds:
         out["tier"] = "dense"    # the fused kernel takes mha's merged qkv
 
@@ -154,7 +160,12 @@ def stack_layers(c: TransformerConfig) -> dict:
             out[kind] = {"heads": blk.linear_num_heads,
                          "head_dim": blk.linear_head_dim, "chunk": CHUNK,
                          "chunks": chunks_of(c.seq_len)}
-        elif kind == "gqa_gated":
+        elif kind == "gdn":
+            key_dim, value_dim = blk.linear_widths
+            out[kind] = {"heads": blk.linear_num_heads, "key_dim": key_dim,
+                         "value_dim": value_dim, "chunk": CHUNK,
+                         "chunks": chunks_of(c.seq_len)}
+        elif kind in ("gqa_gated", "gqa"):
             out[kind] = {"heads": c.heads, "head_dim": c.dim_head,
                          "kv_heads": blk.num_key_value_heads or c.heads}
         elif kind == "mla":
@@ -180,7 +191,7 @@ def stack_layers(c: TransformerConfig) -> dict:
         # kinds are plain causal. One count per distinct table
         tables = {i: keys[i] if kind == "mha" else None
                   for i, kind in enumerate(kinds)
-                  if kind in ("mha", "gqa_gated", "mla")}
+                  if kind in SOFTMAX_KINDS}
         counts = {key: flash_block_counts(
             c.seq_len, causal=c.causal, mask=masks.get(key),
             mask_spec=specs.get(key)) for key in set(tables.values())}
@@ -507,7 +518,9 @@ def shift_decode_step(x_t, state: ShiftState, offset, text_len: int,
 
 class TransformerLayer(nn.Module):
     """PreNorm(+sandwich) → optional token-shift → fn, scaled by LayerScale,
-    residual added by the caller. One instance each for attn and ff roles."""
+    residual added by the caller. One instance each for attn and ff roles.
+    ``norm_position`` "post" (OLMo 2's block) norms fn's output instead of
+    its input."""
     dim: int
     index: int                     # 1-based, for LayerScale init
     fn: nn.Module
@@ -518,6 +531,7 @@ class TransformerLayer(nn.Module):
     norm_kind: str = "layernorm"   # layernorm | rmsnorm (config.BlockConfig)
     norm_eps: float = 1e-6
     layerscale: bool = True
+    norm_position: str = "pre"     # pre | post (config.BlockConfig)
 
     def setup(self):
         self.norm = (RMSNorm(self.norm_eps, name="norm")
@@ -544,7 +558,8 @@ class TransformerLayer(nn.Module):
         return y if self.scale is None else y * self.scale
 
     def __call__(self, x, **kw):
-        y = self.norm(x)
+        post = self.norm_position == "post"
+        y = x if post else self.norm(x)
         if self.shift:
             y = shift_tokens_full(y, self.text_len, self.image_size)
         # the dense MLPs run under the scope ``ff``; every other layer kind
@@ -555,8 +570,8 @@ class TransformerLayer(nn.Module):
               else contextlib.nullcontext()):
             y = self.fn(y, **kw)
         if isinstance(y, tuple):       # (output, counters): see _block_body
-            return self._post(y[0]), y[1]
-        return self._post(y)
+            return self._post(self.norm(y[0]) if post else y[0]), y[1]
+        return self._post(self.norm(y) if post else y)
 
     def prefill(self, x, kv: Optional[KVCache], shift_state: Optional[ShiftState],
                 **kw):
@@ -667,7 +682,8 @@ class Transformer(nn.Module):
             layer_kw = dict(sandwich=c.sandwich_norm, shift=c.shift_tokens,
                             text_len=self.text_len, image_size=fmap,
                             norm_kind=blk.norm, norm_eps=blk.rms_norm_eps,
-                            layerscale=blk.layerscale)
+                            layerscale=blk.layerscale,
+                            norm_position=blk.norm_position)
             attn_layers.append(TransformerLayer(
                 c.dim, ind + 1, attn, name=f"layer_attn_{ind}", **layer_kw))
             ff_layers.append(TransformerLayer(
@@ -680,11 +696,12 @@ class Transformer(nn.Module):
     # -- the block's kinds (config.BlockConfig) -----------------------------
     def _make_attention(self, name: str, tier: str, kind: str):
         c, blk = self.cfg, self.cfg.block
-        if kind in ("gqa_gated", "kda"):
+        if kind in ("gqa_gated", "gqa") + LINEAR_KINDS:
             # a linear layer beside latent ones takes no notice of their
             # rotary table
             positions = {"none", "seq_yarn"} if (
-                kind == "kda" and "mla" in blk.attention_kinds) else {"none"}
+                kind in LINEAR_KINDS and "mla" in blk.attention_kinds
+            ) else {"none"}
             if blk.positions not in positions or not c.causal or self.sp_mesh:
                 raise ValueError(f"{kind} is causal, takes no positional "
                                  f"term (positions: none) and has no "
@@ -696,6 +713,18 @@ class Transformer(nn.Module):
                     gate_rank=blk.linear_gate_rank, eps=blk.rms_norm_eps,
                     lower_bound=blk.kda_lower_bound,
                     beta_max=blk.kda_beta_max, name=name)
+            if kind == "gdn":
+                return GatedDeltaNet(
+                    c.dim, blk.linear_num_heads, *blk.linear_widths,
+                    conv_size=blk.short_conv_kernel_size,
+                    eps=blk.rms_norm_eps, beta_max=blk.kda_beta_max,
+                    name=name)
+            if kind == "gqa":
+                return GatedGQAttention(
+                    c.dim, c.heads, blk.num_key_value_heads or c.heads,
+                    c.dim_head, tier=tier, softmax_f32=c.attn_softmax_f32,
+                    gate=False, qk_norm=blk.qk_norm, eps=blk.rms_norm_eps,
+                    name=name)
             return GatedGQAttention(
                 c.dim, c.heads, blk.num_key_value_heads or c.heads,
                 c.dim_head, tier=tier, softmax_f32=c.attn_softmax_f32,
@@ -744,13 +773,16 @@ class Transformer(nn.Module):
         ``KVCache`` / ``PagedKVCache``; the block kinds without that layout
         are refused by name, not run wrongly."""
         blk = self.cfg.block
-        if set(blk.attention_kinds) != {"mha"} or blk.feed_forward == "moe":
+        if (set(blk.attention_kinds) != {"mha"} or blk.feed_forward == "moe"
+                or blk.norm_position != "pre"):
             raise NotImplementedError(
                 f"{what}: the {blk.name} block has no cached decode path "
                 f"(latent keys and values, grouped key/value heads, a "
-                f"recurrent state and its convolution tail have no KVCache "
-                f"layout, and a routed layer returns counters); it trains "
-                f"through Transformer.__call__ only")
+                f"recurrent state with a decay per key channel (kda) or per "
+                f"head (gdn) and its convolution tail have no KVCache layout, "
+                f"a routed layer returns counters, and the cached layers norm "
+                f"their input, not their output); it trains through "
+                f"Transformer.__call__ only")
 
     def _dense_mask(self, t):
         m = self.np_masks[t]
@@ -784,7 +816,7 @@ class Transformer(nn.Module):
                 # A linear-attention block keeps what its core names
                 # (``KDA_SAVED``): the recompute then runs none of the core
                 saved = ({"policy": KDA_SAVED}
-                         if self.attn_kinds[ind] == "kda" else {})
+                         if self.attn_kinds[ind] in LINEAR_KINDS else {})
                 blk = nn.remat(_block_body, prevent_cse=False,
                                static_argnums=(3, 4), **saved)
                 x, counters = blk(self, x, key_mask, ind, deterministic)

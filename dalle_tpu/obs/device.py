@@ -324,9 +324,12 @@ _SCOPE_LAYERS = {
     **_rows("attn_core", "attn_core"),
     **_rows("attn_proj", "attn/qkv", "attn/out", "attn/gate", "attn/mla_q",
             "attn/mla_kv", "attn/mla_norm", "attn/gqa_qkv", "attn/kda_proj",
-            "attn/kda_conv", "attn/kda_gates"),
+            "attn/kda_conv", "attn/kda_gates", "attn/gdn_proj",
+            "attn/gdn_conv", "attn/gdn_gates"),
     **_rows("kda_chunk", "attn/kda_chunk"),
     **_rows("kda_state", "attn/kda_state"),
+    **_rows("gdn_chunk", "attn/gdn_chunk"),
+    **_rows("gdn_state", "attn/gdn_state"),
     **_rows("moe", "moe/router", "moe/dispatch", "moe/experts", "moe/combine",
             "moe/shared"),
     **_rows("ff", "ff"),

@@ -1,11 +1,16 @@
-"""Kimi delta attention's core (arXiv:2510.26692, section 3): the gated
-delta rule with a decay per key channel, in chunked (WY) form.
+"""The gated delta rule in chunked (WY) form, in two forms of its decay:
+Kimi delta attention's (arXiv:2510.26692, section 3), one decay per key
+channel, and Gated DeltaNet's (arXiv:2412.06464), one decay per head.
 
 Per head, with a state ``S`` of (d_k, d_v), ``S_0 = 0``, decay
-``a_t = exp(g_t)`` (``g_t <= 0``, one per key channel) and ``b_t`` in (0, 2):
+``a_t = exp(g_t)`` (``g_t <= 0``, one per key channel or one per head) and
+``b_t`` in (0, 2):
 
     S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T
     o_t = S_t^T q_t
+
+(with one decay a head ``Diag(a_t)`` is the scalar ``a_t`` and commutes with
+the rest: ``S_t = a_t (I - b_t k_t k_t^T) S_{t-1} + b_t k_t v_t^T``).
 
 **The chunked form.** Within a chunk whose state at entry is ``S``, with
 ``G_t`` the cumulative sum of ``g`` from the chunk's start, the update is
@@ -38,6 +43,15 @@ kept as those pieces and merged at the end (``_merged``), every square of
 one size in one product. Gates, cumulative sums, the triangular inverse and
 the state are float32; the operands of the matrix products take the inputs'
 type (bfloat16 in training), accumulated in float32.
+
+**One decay a head** (``f`` of shape (b, n, h)): the decay between two
+positions of a chunk is one number, ``Gamma[t, j] = exp(G_t - G_j)`` for
+``j <= t`` (at most 1), so the grams are two plain products under one
+(CHUNK, CHUNK) matrix, ``A = tril(diag(b) (K K^T) * Gamma, -1)``, ``Aqk =
+tril((Q K^T) * Gamma)``, with none of the halves and sub-blocks the
+channel-wise decay needs; the inverse, the state's step and the read-out
+are shared. That form runs under the scopes ``attn/gdn_chunk`` and
+``attn/gdn_state``.
 """
 
 from __future__ import annotations
@@ -194,8 +208,12 @@ def _log_decay(f, a_log, bias, lower_bound: float = 0.0):
     ``bias`` (h, d): g = -exp(A_h) softplus(f + b), or, with a
     ``lower_bound`` < 0, the public implementation's bounded gate
     g = lower_bound * sigmoid(exp(A_h) (f + b)), in (lower_bound, 0): a
-    chunk's cumulative sum is then at least ``CHUNK * lower_bound``."""
+    chunk's cumulative sum is then at least ``CHUNK * lower_bound``. One
+    decay a head: ``f`` (..., h) and ``bias`` (h,)."""
     f32 = jnp.float32
+    if bias.ndim == 1:
+        return (-jnp.exp(a_log.astype(f32))
+                * jax.nn.softplus(f.astype(f32) + bias.astype(f32)))
     if lower_bound < 0:
         return lower_bound * jax.nn.sigmoid(
             jnp.exp(a_log.astype(f32))[:, None]
@@ -237,6 +255,48 @@ def _within_chunks(q, k, v, f, beta, a_log, bias, lower_bound):
          jnp.exp(last[..., 0, :])), jnp.min(last)))
 
 
+def _lower_pieces(m):
+    """The pieces (``_merged``) of a lower-triangular (..., c, c): the
+    squares of ``SUB`` on its diagonal and, level by level, the squares
+    below them."""
+    c = m.shape[-1]
+    diag = jnp.stack([m[..., i:i + SUB, i:i + SUB]
+                      for i in range(0, c, SUB)], -3)
+    lows, s = [], SUB
+    while s < c:
+        lows.append(jnp.stack([m[..., i + s:i + 2 * s, i:i + s]
+                               for i in range(0, c, 2 * s)], -3))
+        s *= 2
+    return diag, lows
+
+
+def _within_chunks_per_head(q, k, v, f, beta, a_log, bias):
+    """``_within_chunks`` for one decay a head: ``f`` and ``beta`` (chunks,
+    b, h, CHUNK). The decay between two positions, ``Gamma[t, j] = exp(G_t -
+    G_j)`` for ``j <= t``, is formed directly and is at most 1."""
+    f32, dtype = jnp.float32, v.dtype
+    q, k, v, f, beta = jax.lax.optimization_barrier((q, k, v, f, beta))
+    q = _l2_normalise(q) * q.shape[-1] ** -0.5
+    k, beta = _l2_normalise(k), beta.astype(f32)[..., None]
+    # (CHUNK, h) behind the heads for the head's own A and bias
+    cum = jnp.cumsum(jnp.swapaxes(_log_decay(jnp.swapaxes(f, -2, -1), a_log,
+                                             bias), -2, -1), axis=-1)
+    last = cum[..., -1:]
+    causal = jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
+    gamma = jnp.exp(jnp.where(causal, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))
+    aqk = _mm("...td,...jd->...tj", q, k, dtype) * gamma
+    a = beta * jnp.tril(_mm("...td,...jd->...tj", k, k, dtype) * gamma, -1)
+    t = _unit_lower_inverse(*_lower_pieces(a))
+    decay = jnp.exp(cum)[..., None]
+    u0 = _mm("...tj,...jv->...tv", t, beta * v.astype(f32), dtype)
+    w = _mm("...tj,...jd->...td", t, beta * decay * k, dtype)
+    return jax.lax.optimization_barrier((
+        (u0.astype(dtype), w.astype(dtype), (decay * q).astype(dtype),
+         aqk.astype(dtype), (k * jnp.exp(last - cum)[..., None]).astype(dtype),
+         jnp.exp(last)), jnp.min(last)))
+
+
 def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps,
                 lower_bound: float = 0.0):
     """The layer's heads over whole sequences, from the projections' outputs
@@ -245,7 +305,10 @@ def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps,
     ``v``: (b, n, h, d_v); ``f``: (b, n, h, d_k), the decay's pre-activation
     (the log-decay is ``_log_decay(f, a_log, bias, lower_bound)``, ``a_log``
     (h,), ``bias`` (h, d_k), ``lower_bound`` the decay's form); ``beta``:
-    (b, n, h). Returns ``o`` (b, n, h, d_v) in ``v``'s
+    (b, n, h). One decay a head (Gated DeltaNet's form): ``f`` (b, n, h)
+    and ``bias`` (h,), the log-decay -exp(A_h) softplus(f + b), the work
+    under the scopes ``attn/gdn_chunk`` and ``attn/gdn_state``. Returns
+    ``o`` (b, n, h, d_v) in ``v``'s
     type, RMS-normalised over a head's width in float32 (``norm_scale``
     (d_v,), ``eps``), and the most negative cumulative log-decay over a
     chunk (a float32 scalar: how far the chunked form is from float32's
@@ -270,6 +333,8 @@ def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps,
     the group's own transpose, and not a second time to rebuild the layer."""
     b, n, h, dk = q.shape
     dtype = v.dtype
+    per_head = f.ndim == 3
+    name = "gdn" if per_head else "kda"
     nc = chunks_of(n)
     group = max(d for d in range(1, GROUP + 1) if nc % d == 0)
 
@@ -285,10 +350,11 @@ def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps,
     def group_steps(state, x):
         """One group: its chunks' state-free work, then its chunks' steps of
         the state, in order."""
-        with jax.named_scope("attn/kda_chunk"):
-            xs, low = _within_chunks(*x, a_log, bias, lower_bound)
+        with jax.named_scope(f"attn/{name}_chunk"):
+            xs, low = (_within_chunks_per_head(*x, a_log, bias) if per_head
+                       else _within_chunks(*x, a_log, bias, lower_bound))
         outs = []
-        with jax.named_scope("attn/kda_state"):
+        with jax.named_scope(f"attn/{name}_state"):
             for u0, w, q_in, aqk, k_out, keep in zip(*xs):
                 u = u0 - _mm("bhtd,bhdv->bhtv", w, state, dtype)
                 o = (_mm("bhtd,bhdv->bhtv", q_in, state, dtype)
@@ -310,12 +376,12 @@ def kda_chunked(q, k, v, f, beta, *, a_log, bias, norm_scale, eps,
         state, (o, low) = jax.checkpoint(group_steps)(state, x)
         return state, (checkpoint_name(o, "kda_out"), low)
 
-    with jax.named_scope("attn/kda_chunk"):
+    with jax.named_scope(f"attn/{name}_chunk"):
         # behind the end no gate: softplus (or the bounded form's sigmoid)
         # of the least number is 0
         xs = (chunked(q), chunked(k), chunked(v),
               chunked(f, jnp.finfo(f.dtype).min), chunked(beta))
-    with jax.named_scope("attn/kda_state"):
+    with jax.named_scope(f"attn/{name}_state"):
         _, (o, lows) = jax.lax.scan(
             body, jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32), xs)
     # (groups, group, b, h, CHUNK, d_v) -> (b, n, h, d_v)

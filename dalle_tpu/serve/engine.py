@@ -245,8 +245,10 @@ class DecodeEngine:
                 f"the serve engine decodes the default mha+geglu block only; "
                 f"the {c.block.name} block has no cached decode path yet "
                 f"(latent or grouped keys and values through KVCache / "
-                f"PagedKVCache and the decode kernels; a recurrent state and "
-                f"its convolution tail beside them)")
+                f"PagedKVCache and the decode kernels; a recurrent state, "
+                f"with a decay per key channel (kda) or per head (gdn), and "
+                f"its convolution tail beside them; a block that norms its "
+                f"layers' outputs)")
         attn_types = tuple(c.attn_types) or ("full",)
         if any(t != "full" for t in attn_types) or c.shift_tokens:
             # same constraint set as speculative decode: per-row windows

@@ -160,6 +160,16 @@ def test_flash_fwd_bwd_solar2_softmax_layer(one_chip):
         assert kernel in text, kernel
 
 
+def test_flash_fwd_bwd_olmo_hybrid_softmax_layer(one_chip):
+    """train_olmo_hybrid_pp8_fit's softmax layer (``gqa``) as
+    ``GatedGQAttention`` calls the flash tier: batch 2, 30 query and 30
+    key/value heads of 128 (no grouping), 4352 positions."""
+    qkv = [_sds(one_chip, (2, 30, 4352, 128), jnp.bfloat16)] * 3
+    text = _mosaic_text(_flash_loss(None), *qkv)
+    for kernel in ("flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"):
+        assert kernel in text, kernel
+
+
 def test_flash_fwd_bwd_ling3_latent_layer(one_chip):
     """train_ling3_ep32_fit's latent layers as ``MLAttention`` calls the
     flash tier: batch 2, 32 heads, queries and keys of 192 (128 + the rotary

@@ -443,7 +443,7 @@ def test_the_cached_paths_and_the_engine_refuse_the_block_by_name():
     with pytest.raises(ValueError, match="reversible"):
         DalleConfig(**{**MODEL, "reversible": True})
     with pytest.raises(ValueError, match="block.attention"):
-        BlockConfig(attention="gqa")
+        BlockConfig(attention="swa")      # "gqa" is a kind since PR 38
 
 
 def test_latent_attention_is_the_dense_tier_whatever_the_length(monkeypatch):
