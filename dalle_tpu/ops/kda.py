@@ -44,6 +44,18 @@ one size in one product. Gates, cumulative sums, the triangular inverse and
 the state are float32; the operands of the matrix products take the inputs'
 type (bfloat16 in training), accumulated in float32.
 
+**The diagonal sub-blocks' backward is written by hand**
+(``_diagonal_squares``' ``custom_vjp``). It keeps the squares' inputs
+alone, ``x``, ``ks`` and ``cs``, and forms the decays and terms of the
+sub-blocks again inside each fusion that sums them: for each key the sum
+over later rows, for each row and slot of ``x`` the sum over earlier keys.
+Those sums are float32 reductions of (t, j, c) terms over a vector
+register's rows or across registers (with keys of 128 a key's channels are
+one register's lanes), so no product with 0 / 1 columns is needed where the
+forward needs ``pick``, and no (t, j, c) tensor, 8 x the size of ``x``,
+reaches memory. The diagonal itself, exp(0), enters only as a cotangent's
+diagonal times a key or a row.
+
 **One decay a head** (``f`` of shape (b, n, h)): the decay between two
 positions of a chunk is one number, ``Gamma[t, j] = exp(G_t - G_j)`` for
 ``j <= t`` (at most 1), so the grams are two plain products under one
@@ -55,6 +67,8 @@ are shared. That form runs under the scopes ``attn/gdn_chunk`` and
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +126,7 @@ def _merged(diag, lows, corner=lambda ta, low, tb: low):
     return m[..., 0, :, :]
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _diagonal_squares(x, ks, cs, dtype):
     """sum_c x_tc k_jc exp(cs_tc - cs_jc) for j <= t within a sub-block, 0
     for j > t: (..., 2, ns, SUB, SUB) float32 from ``x`` (..., 2, ns, SUB,
@@ -130,6 +145,53 @@ def _diagonal_squares(x, ks, cs, dtype):
     terms = again(x) * (along(ks) * decay)[..., None, :, :, :]
     pick = jnp.repeat(jnp.eye(sub, dtype=dtype), d, axis=0)     # (j d + c, j)
     return _mm("...k,kj->...j", terms, pick, dtype)
+
+
+def _diagonal_squares_fwd(x, ks, cs, dtype):
+    return _diagonal_squares(x, ks, cs, dtype), (x, ks, cs)
+
+
+def _diagonal_squares_bwd(dtype, res, g):
+    """The squares' cotangent ``g`` (..., 2, ns, SUB, SUB) back to ``x``,
+    ``ks`` and ``cs``, float32. With D[t, j, c] = exp(cs_tc - cs_jc):
+
+        dx[t, c] = g[t, t] k_tc + sum_{j < t} g[t, j] k_jc D
+        dk[j, c] = sum_x g[j, j] x_jc + later[j, c]
+        later    = sum_x sum_{t > j} g[t, j] x_tc D
+        dcs      = sum_x x (dx - g[t, t] k) - k later
+
+    (cs enters as x e^cs and k e^-cs). Only the pairs j < t carry a decay:
+    the diagonal's exp(0) has no gradient. Sums that kept it would cancel
+    it between the two sides of dcs, and what rounding leaves of it reaches
+    the gates' gradient weighted by cumulative sums down to -400. Each sum
+    forms D inside its own fusion, and no (t, j, c) tensor, 8 x ``x``, is
+    written to memory (``tests/test_chip_compile.py``); one D shared by the
+    two slots of ``x`` through a broadcast is what XLA would write."""
+    del dtype           # no operand is rounded: every sum is float32
+    x, ks, cs = res
+    sub = ks.shape[-2]
+    slots = range(x.shape[-4])
+    below = jnp.tril(jnp.ones((sub, sub), bool), -1)[:, :, None]
+
+    def decay():        # (..., t, j, c)
+        return jnp.exp(jnp.where(below, cs[..., :, None, :]
+                                 - cs[..., None, :, :], -jnp.inf))
+
+    # for each slot of x and each row, the sum over the keys j < t
+    dx = jnp.stack([jnp.sum(g[..., i, :, :, :, None] * ks[..., None, :, :]
+                            * decay(), axis=-2) for i in slots], axis=-4)
+    # for each key, the sum over the rows t > j and the slots
+    later = jnp.sum(decay() * sum(g[..., i, :, :, :, None]
+                                  * x[..., i, :, :, None, :] for i in slots),
+                    axis=-3)
+    on_diagonal = jnp.diagonal(g, axis1=-2, axis2=-1)[..., None]
+    dcs = jnp.sum(x * dx, axis=-4) - ks * later
+    dx = dx + on_diagonal * ks[..., None, :, :, :]
+    dk = later + jnp.sum(on_diagonal * x, axis=-4)
+    return dx.astype(x.dtype), dk.astype(ks.dtype), dcs.astype(cs.dtype)
+
+
+_diagonal_squares.defvjp(_diagonal_squares_fwd, _diagonal_squares_bwd)
 
 
 def _decayed_grams(q, k, cum, dtype):

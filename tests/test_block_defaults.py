@@ -6,8 +6,10 @@ multi-token-prediction block) left the accepted configurations as they
 were: at the benchmark tests' tiny sizes, each one's parameter tree leaf for
 leaf and its lowered train step character for character are those of the
 commit before (the first three: 98e0bf9, before PR 32; ``tiny_solar2_config``:
-1b46002, before PR 34, its step since PR 37, whose linear layers run one scan
-and are rematerialised with ``KDA_SAVED``), by their digests. At the
+1b46002, its step since its channel-decay layers gave the diagonal
+sub-blocks' squares a hand-written backward; ``tiny_olmo_hybrid_config``:
+53d6561, from before that backward, which the one-decay-a-head form never
+calls), by their digests. At the
 configurations' own sizes the same comparison, parent against change, is in
 CHANGES.md.
 
@@ -30,12 +32,14 @@ from dalle_tpu.train.trainer_dalle import _dalle_step_body
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmarks",
                     "data")
-# configuration (its block), optimizer, (tree, lowered step) at 98e0bf9
+# configuration (its block), optimizer, (tree, lowered step)
 CASES = {
     "tiny_config": ("adam", ('613a4e4d4adc6518', '66773f640a24d88a')),
     "mid_config": ("adafactor", ('753ede710630dd0d', 'ea3b75f8da758146')),
     "tiny_dsv2_config": ("adafactor", ('227095e1baf655aa', '971e1eeab280ea8f')),
-    "tiny_solar2_config": ("adafactor", ('4bababd7339b4602', '3fa22daea60fafc8')),
+    "tiny_solar2_config": ("adafactor", ('4bababd7339b4602', '146f110e2266d559')),
+    "tiny_olmo_hybrid_config": ("adafactor",
+                                ('f4ad31420402d765', '6e8deff6fd175380')),
 }
 
 

@@ -16,6 +16,7 @@ described device cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -225,6 +226,59 @@ def test_grouped_product_fwd_bwd_dsv2_share(one_chip, k, n):
     for kernel in ("moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
         # jax.grad of a sum needs no forward output: XLA may drop that call
         assert kernel in text or kernel == "moe_gmm_fwd", kernel
+
+
+def _unfused_elements(hlo_text: str) -> dict:
+    """The most elements of any array that an instruction outside every
+    fusion of an optimized module holds (what reaches memory), by name."""
+    fused = {called for line in hlo_text.splitlines() if " fusion(" in line
+             for called in re.findall(r"calls=%?([\w.\-]+)", line)}
+    held, computation = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            computation = head[1]
+            continue
+        result = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) [a-z][\w\-]*\(",
+                          line)
+        if computation is None or computation in fused or not result:
+            continue
+        held[result[1]] = max(
+            (int(np.prod([int(n) for n in dims.split(",") if n]))
+             for dims in re.findall(r"\w+\[([\d,]*)\]", result[2])),
+            default=0)
+    return held
+
+
+def test_diagonal_squares_backward_keeps_its_terms_in_fusions(one_chip):
+    """The within-chunk grams of one group of train_solar2_ep32_fit (2
+    chunks, batch 2, 64 heads, 64 positions, keys of 128): their gradient
+    writes no (t, j d + c) or (t, j, c) tensor of the diagonal sub-blocks to
+    memory. Those are 8 x the stacked queries and keys ``x``, so no
+    instruction outside a fusion holds more elements than ``x``. The loss is
+    linear in the grams, with its weights as arguments, so what is compiled
+    is the backward pass alone."""
+    from dalle_tpu.ops.kda import CHUNK, _decayed_grams
+
+    def grams(q, k, cum):
+        return _decayed_grams(q, k, cum, jnp.bfloat16)
+
+    def loss(q, k, cum, w_diag, w_lows):
+        diag, lows = grams(q, k, cum)
+        return jnp.sum(w_diag * diag) + sum(
+            jnp.sum(w * low) for w, low in zip(w_lows, lows))
+
+    qkc = [_sds(one_chip, (2, 2, 64, CHUNK, 128), jnp.float32)] * 3
+    diag, lows = jax.eval_shape(grams, *qkc)
+    weights = (_sds(one_chip, diag.shape, jnp.float32),
+               [_sds(one_chip, low.shape, jnp.float32) for low in lows])
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *qkc, *weights).compile().as_text()
+    x_elements = 2 * int(np.prod(qkc[0].shape))
+    held = _unfused_elements(text)
+    assert held, "no instruction found in the optimized text"
+    assert max(held.values()) <= x_elements, {
+        name: n for name, n in held.items() if n > x_elements}
 
 
 @pytest.mark.parametrize("rows,ids", [(8192, (8, 1024)), (4608, (8, 257))],

@@ -9,8 +9,9 @@ import numpy as np
 import pytest
 
 from dalle_tpu.obs.device import scope_layer
-from dalle_tpu.ops.kda import (CHUNK, KDA_SAVED, SUB, _merged,
-                               _unit_lower_inverse, chunks_of, kda_chunked)
+from dalle_tpu.ops.kda import (CHUNK, KDA_SAVED, SUB, _diagonal_squares,
+                               _merged, _unit_lower_inverse, chunks_of,
+                               kda_chunked)
 
 HI = jax.lax.Precision.HIGHEST
 EPS = 1e-5
@@ -73,15 +74,21 @@ def core_inputs(n: int, gate: float, h: int = 3, d: int = 8, dv: int = 5):
     return q, k, v, f, beta, a_log, bias, scale
 
 
-@pytest.mark.parametrize("gate", [0.01, 1.6, 80.0])
-@pytest.mark.parametrize("n", [150, 37])
-def test_the_chunked_core_is_the_recurrent_form(n, gate):
+@pytest.mark.parametrize(
+    "n,gate,d",
+    [(n, gate, 8) for n in (150, 37) for gate in (0.01, 1.6, 80.0)]
+    + [(150, 80.0, 128)],
+    ids=[f"{n}-{gate}" for n in (150, 37) for gate in (0.01, 1.6, 80.0)]
+    + ["150-80.0-d128"])
+def test_the_chunked_core_is_the_recurrent_form(n, gate, d):
     """Lengths that are no multiple of the chunk (three chunks, and less
-    than one), gates from almost none to a trained model's most negative: no
-    inf, no nan, and the recurrence's numbers to 1e-5, forward and (of an
-    input's largest gradient) under ``jax.grad`` with respect to every input
-    and every leaf."""
-    args = core_inputs(n, gate)
+    than one), gates from almost none to a trained model's most negative,
+    keys of 8 and of the configurations' 128 (a key a vector register's
+    lanes): no inf, no nan, and the recurrence's numbers to 1e-5, forward
+    and (of an input's largest gradient) under ``jax.grad`` with respect to
+    every input and every leaf, through the diagonal squares' own
+    backward."""
+    args = core_inputs(n, gate, d=d)
     out, low = chunked(*args)
     want = recurrent(*args)
     assert bool(jnp.all(jnp.isfinite(out)))
@@ -95,7 +102,7 @@ def test_the_chunked_core_is_the_recurrent_form(n, gate):
     pad = chunks_of(n) * CHUNK - n
     g = log_decays(args[3], args[5], args[6])
     sums = jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
-        2, -1, CHUNK, 3, 8).sum(2)
+        2, -1, CHUNK, 3, d).sum(2)
     assert float(low) == pytest.approx(float(sums.min()), rel=1e-5)
     if gate > 1 and n > CHUNK:
         assert float(low) < -88.0     # exp(-low) is past float32's range
@@ -117,6 +124,37 @@ def test_the_chunked_core_is_the_recurrent_form(n, gate):
         np.testing.assert_allclose(
             a, b, err_msg=name,
             atol=within * max(1.0, float(jnp.abs(b).max())))
+
+
+@pytest.mark.parametrize("d,deepest", [(128, -400.0), (8, -400.0),
+                                        (128, -4.0)])
+def test_the_diagonal_squares_backward_is_their_autodiff(d, deepest):
+    """The hand-written backward of the diagonal sub-blocks' squares against
+    ``jax.vjp`` of the same forward without it, in float32: keys of the
+    configurations' 128 and of 8, cumulative decays down to ``deepest``
+    (-400: the least ``kda_logdecay_min`` reads in training, so that most
+    decays between two positions of a sub-block underflow; -4: none do), a
+    cotangent over the whole square (above the diagonal the squares are 0
+    whatever the inputs)."""
+    rng = np.random.default_rng(d)
+    ns = 4
+    x = jnp.asarray(rng.normal(size=(2, 2, ns, SUB, d)), jnp.float32)
+    ks = jnp.asarray(rng.normal(size=(2, ns, SUB, d)), jnp.float32)
+    gates = rng.uniform(0, 2 * -deepest / (ns * SUB), size=(2, ns * SUB, d))
+    cs = jnp.asarray(-np.cumsum(gates, axis=1).reshape(2, ns, SUB, d),
+                     jnp.float32)
+    g = jnp.asarray(rng.normal(size=(2, 2, ns, SUB, SUB)), jnp.float32)
+    assert float(cs.min()) < 0.75 * deepest
+    plain = jax.jit(lambda *a: _diagonal_squares.fun(*a, jnp.float32))
+    ours = jax.jit(lambda *a: _diagonal_squares(*a, jnp.float32))
+    np.testing.assert_array_equal(ours(x, ks, cs), plain(x, ks, cs))
+    got = jax.jit(lambda *a: jax.vjp(ours, *a)[1](g))(x, ks, cs)
+    want = jax.jit(lambda *a: jax.vjp(plain, *a)[1](g))(x, ks, cs)
+    for name, a, b in zip(("x", "ks", "cs"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(
+            a, b, err_msg=name, rtol=0,
+            atol=1e-5 * float(jnp.abs(b).max()))
 
 
 def test_the_core_in_bfloat16_stays_near_its_float32_self():
